@@ -137,7 +137,8 @@ def cost_report(step_fn: Any, args: Sequence[Any], *,
         _goodput.carve(_goodput.COMPILE, _time.perf_counter() - _t0)
 
     hlo = compiled.as_text()
-    report["fingerprint"] = hashlib.sha1(hlo.encode()).hexdigest()[:12]
+    report["fingerprint"] = hashlib.sha1(
+        rules_cost.strip_source_info(hlo).encode()).hexdigest()[:12]
     comps, entry = rules_cost.parse_computations(hlo)
 
     # ---- corrections: backend dtype legalization + loop trip counts -----

@@ -40,7 +40,9 @@ import re
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from horovod_tpu.analysis.engine import Rule
-from horovod_tpu.analysis.rules_ir import _HLO_DTYPE_BYTES, HLO_COLLECTIVES
+from horovod_tpu.analysis.rules_ir import (
+    _HLO_DTYPE_BYTES, HLO_COLLECTIVES, HLO_RESULT_TYPE, replica_group_size,
+)
 
 
 class CostRule(Rule):
@@ -178,11 +180,10 @@ def padded_bytes(dtype: str, dims: Tuple[int, ...]) -> int:
 _COMP_HEAD_RE = re.compile(
     r"^(ENTRY\s+)?%([\w.\-~]+)\s*\(.*\)\s*->\s*.*\{\s*$")
 _INSTR_RE = re.compile(
-    r"^\s*(ROOT\s+)?%([\w.\-~]+)\s+=\s+((?:\([^)]*\)|\S+))\s+"
+    r"^\s*(ROOT\s+)?%([\w.\-~]+)\s+=\s+(" + HLO_RESULT_TYPE + r")\s+"
     r"([a-z][a-z0-9\-]*)\(")
 _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
-_OPERAND_RE = re.compile(
-    r"([a-z][a-z0-9]*)\[([0-9,]*)\](?:\{[^}]*\})?\s+%([\w.\-~]+)")
+_OPERAND_RE = re.compile(r"%([\w.\-~]+)")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 
 # Result/operand shapes never touch HBM through these: they rename or
@@ -236,6 +237,20 @@ class Instr:
         return sum(padded_bytes(d, s) for d, s, _ in self.operands)
 
 
+_SOURCE_TABLE_RE = re.compile(
+    r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
+    r"(?:.+\n)*", re.M)
+_METADATA_RE = re.compile(r",? metadata=\{[^}]*\}")
+
+
+def strip_source_info(hlo_text: str) -> str:
+    """HLO text without the source-location tables and per-instruction
+    ``metadata={...}`` — what identifies the executable. The tables
+    record the Python call stack of the trace, so the same program built
+    from two call sites prints two different texts."""
+    return _METADATA_RE.sub("", _SOURCE_TABLE_RE.sub("", hlo_text))
+
+
 def _dims(s: str) -> Tuple[int, ...]:
     return tuple(int(x) for x in s.split(",") if x)
 
@@ -264,10 +279,15 @@ def parse_computations(hlo_text: str) -> Tuple[Dict[str, List[Instr]], str]:
     comps: Dict[str, List[Instr]] = {}
     entry = ""
     current: Optional[List[Instr]] = None
+    # Operands are printed as bare ``%name`` references; their shapes come
+    # from the producing instruction of the same computation (a scheduled
+    # module defines before it uses).
+    shapes: Dict[str, List[Tuple[str, Tuple[int, ...]]]] = {}
     for line in hlo_text.splitlines():
         head = _COMP_HEAD_RE.match(line)
         if head:
             current = comps.setdefault(head.group(2), [])
+            shapes = {}
             if head.group(1):
                 entry = head.group(2)
             continue
@@ -282,9 +302,13 @@ def parse_computations(hlo_text: str) -> Tuple[Dict[str, List[Instr]], str]:
         is_root, name, result, op = (bool(m.group(1)), m.group(2),
                                      m.group(3), m.group(4))
         out = [(d, _dims(s)) for d, s in _SHAPE_RE.findall(result)]
+        if not result.startswith("("):
+            # array-typed producers only: a tuple is consumed by alias
+            # and caller ops, which move no bytes of their own
+            shapes[name] = out
         opnd_text, attrs = _operand_span(line, m.end() - 1)
-        operands = [(d, _dims(s), n)
-                    for d, s, n in _OPERAND_RE.findall(opnd_text)]
+        operands = [shapes[n][0] + (n,)
+                    for n in _OPERAND_RE.findall(opnd_text) if n in shapes]
         om = _OPNAME_RE.search(attrs)
         current.append(Instr(name, op, len(current), out, operands,
                              attrs, om.group(1) if om else "", is_root))
@@ -366,13 +390,8 @@ def _conv_flops(ins: Instr) -> int:
 
 
 def _group_size(attrs: str) -> int:
-    m = re.search(r"replica_groups=\{\{([0-9,]+)\}", attrs)
-    if m:
-        return len(m.group(1).split(","))
-    m = re.search(r"replica_groups=\[(\d+),?\d*\]<=", attrs)
-    if m:
-        return int(m.group(1))
-    return 1
+    m = re.search(r"replica_groups=(\S+)", attrs)
+    return replica_group_size(m.group(1)) if m else 1
 
 
 def fusion_table(hlo_text: str,

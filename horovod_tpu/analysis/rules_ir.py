@@ -91,9 +91,9 @@ RULES_BY_CODE = {r.code: r for r in RULES}
 #
 # Inside a shard_map body every value carries a "taint": the set of mesh
 # axes along which its per-shard value may DIFFER. Inputs sharded over an
-# axis (in_names) seed taint; axis_index introduces taint; reduction
+# axis (in_specs) seed taint; axis_index introduces taint; reduction
 # collectives over an axis clear it; everything else unions its operands.
-# A body output whose out_names do NOT shard it over axis A claims it is
+# A body output whose out_specs do NOT shard it over axis A claims it is
 # replicated over A — if its taint still contains A, some data path from
 # A-sharded inputs reached it without a psum: on a gradient leaf that is
 # exactly the dropped allreduce.
@@ -243,10 +243,11 @@ def _taint_jaxpr(jaxpr: Any, in_taints: List[Taint]) -> List[Taint]:
     return [read(v) for v in jaxpr.outvars]
 
 
-def _names_axes(names: Any) -> Taint:
-    """{dim: (axes,)} -> the set of axes the value is sharded over."""
+def _spec_axes(spec: Any) -> Taint:
+    """PartitionSpec -> the set of axes the value is sharded over (an
+    entry is None, one axis name, or a tuple of names)."""
     out = set()
-    for axes in dict(names).values():
+    for axes in spec:
         for a in (axes if isinstance(axes, (tuple, list)) else (axes,)):
             if isinstance(a, str):
                 out.add(a)
@@ -284,16 +285,17 @@ def check_unreduced(jaxpr: Any) -> List[dict]:
             continue
         mesh = eqn.params.get("mesh")
         axis_names = tuple(getattr(mesh, "axis_names", ()) or ())
-        auto = set(eqn.params.get("auto", ()) or ())
-        in_names = eqn.params.get("in_names", ())
-        out_names = eqn.params.get("out_names", ())
+        # mesh axes the body does not handle manually stay the
+        # partitioner's business
+        auto = set(axis_names) - set(eqn.params["manual_axes"])
         body = _open(eqn.params.get("jaxpr"))
         if body is None or not axis_names:
             continue
-        in_taints = [_names_axes(n) for n in in_names]
+        in_taints = [_spec_axes(s) for s in eqn.params["in_specs"]]
         out_taints = _taint_jaxpr(body, in_taints)
-        for i, (names, taint) in enumerate(zip(out_names, out_taints)):
-            allowed = _names_axes(names) | auto
+        for i, (spec, taint) in enumerate(zip(eqn.params["out_specs"],
+                                              out_taints)):
+            allowed = _spec_axes(spec) | auto
             bad = sorted(taint & (set(axis_names) - allowed))
             if not bad:
                 continue
@@ -452,7 +454,10 @@ _HLO_DTYPE_BYTES = {
     "s8": 1, "u8": 1, "pred": 1, "c64": 8, "c128": 16,
 }
 _HLO_SHAPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
-_HLO_OP_RE = re.compile(r"=\s*((?:\([^)]*\)|\S+))\s+([a-z\-]+)\(")
+# An instruction's result type: one shape or a tuple of shapes. TPU layouts
+# nest one more level of parentheses inside it (``{1,0:T(8,128)S(1)}``).
+HLO_RESULT_TYPE = r"(?:\((?:[^()]|\([^()]*\))*\)|\S+)"
+_HLO_OP_RE = re.compile(r"=\s*(" + HLO_RESULT_TYPE + r")\s+([a-z\-]+)\(")
 
 HLO_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
                    "collective-permute", "all-to-all", "collective-broadcast")
@@ -516,6 +521,20 @@ def hlo_collectives(hlo_text: str) -> List[dict]:
             "hlo_line": lineno,
         })
     return entries
+
+
+def replica_group_size(replica_groups: str) -> int:
+    """Members per group of a ``replica_groups`` value (as
+    :func:`hlo_collectives` returns it), in either spelling: the list
+    ``{{0,1,2,3}}`` or the iota form ``[groups,size]<=[n]``; 1 when the
+    attribute is empty (a collective over one device)."""
+    m = re.match(r"\[\d+,(\d+)\]<=", replica_groups)
+    if m:
+        return int(m.group(1))
+    m = re.match(r"\{\{([\d,]*)\}", replica_groups)
+    if m:
+        return len([x for x in m.group(1).split(",") if x])
+    return 1
 
 
 _WIDE_HLO_DTYPES = ("f32", "f64")

@@ -43,23 +43,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# Replication of outputs (e.g. all_gather+prod for PRODUCT, masked-psum
-# broadcast) is guaranteed by construction here but not always provable by
-# shard_map's static variance analysis, so the check is disabled.
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.7 new API
-    def shard_map(f, mesh, in_specs, out_specs):
-        try:
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-        except TypeError:  # pragma: no cover - older kwarg name
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the variance check off: replication of
+    outputs (e.g. all_gather+prod for PRODUCT, masked-psum broadcast) is
+    guaranteed by construction here but not always provable by
+    shard_map's static analysis."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
 
 from horovod_tpu.ops import collectives as C
 from horovod_tpu.ops.fusion import fuse_apply

@@ -23,8 +23,9 @@ Record schema (``"schema": 1``)::
     }
 
 **Regression sentinel** (``bench.py --regression-report``) — compares
-the newest run against three histories: the committed ``BENCH_r0*.json``
-trajectory (throughput), this ledger (goodput fraction, numerics
+the newest run against three histories: the ``BENCH_r<N>.json`` rounds
+found beside ``bench.py`` (throughput; none is committed, so until a
+chip record exists this axis reports ``skipped``), this ledger (goodput fraction, numerics
 anomalies), and the serving axis — the committed ``BENCH_SERVE.json``
 (continuous tokens/s, p99 TTFT/TPOT) against prior serve-bench ledger
 records. A drop beyond ``HOROVOD_GOODPUT_REGRESSION_TOLERANCE``
@@ -226,9 +227,9 @@ def read_ledger(path: Optional[str] = None) -> List[Dict[str, Any]]:
 # ---------------------------------------------------------------------------
 
 def _bench_trajectory(repo_dir: str) -> List[Dict[str, Any]]:
-    """The committed BENCH_r0*.json trajectory, round order. Each file
-    is either the raw bench JSON line or the driver wrapper with a
-    ``parsed`` block."""
+    """The ``BENCH_r<N>.json`` rounds in ``repo_dir``, round order (none
+    is committed: there is no chip record yet). Each file is either the
+    raw bench JSON line or the driver wrapper with a ``parsed`` block."""
     rows: List[Dict[str, Any]] = []
     try:
         names = os.listdir(repo_dir)
@@ -527,7 +528,8 @@ def regression_report(repo_dir: str,
              "floor": round(floor, 3), "tolerance": tol}))
     else:
         checks.append({"check": "bench_throughput", "status": "skipped",
-                       "reason": f"{len(bench)} BENCH round(s) found; "
+                       "reason": f"no chip record: {len(bench)} "
+                                 f"BENCH_r<N>.json round(s) found; "
                                  f"need 2"})
 
     # (b) ledger history: goodput fraction + numerics cleanliness of the

@@ -29,7 +29,6 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax.numpy as jnp
 from jax import lax
-from horovod_tpu.utils.compat import lax_axis_size
 
 
 class FoldedBatchNorm(nn.Module):
@@ -86,7 +85,7 @@ class FoldedBatchNorm(nn.Module):
             if self.axis_name is not None:
                 sums = lax.psum(sums, self.axis_name)
                 sqs = lax.psum(sqs, self.axis_name)
-                n = n * lax_axis_size(self.axis_name)
+                n = n * lax.axis_size(self.axis_name)
             mean = sums / n
             var = jnp.maximum(sqs / n - jnp.square(mean), 0.0)
             # Running stats use the biased batch variance, matching

@@ -31,7 +31,6 @@ from jax import lax
 
 from horovod_tpu.ops.pallas import conv_bn
 from horovod_tpu.ops.pallas.conv_bn import conv1x1_bn_stats
-from horovod_tpu.utils.compat import lax_axis_size
 
 ModuleDef = Any
 _LANES = 128
@@ -106,7 +105,7 @@ class FusedBottleneckBlock(nn.Module):
         if axis_name is not None:
             s1 = lax.psum(s1, axis_name)
             s2 = lax.psum(s2, axis_name)
-            count = count * lax_axis_size(axis_name)
+            count = count * lax.axis_size(axis_name)
         mean = s1 / count
         var = jnp.maximum(s2 / count - jnp.square(mean), 0.0)
         if not self.is_initializing():

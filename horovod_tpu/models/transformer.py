@@ -35,7 +35,6 @@ from horovod_tpu.parallel import moe as moe_lib
 from horovod_tpu.parallel import pipeline as pp_lib
 from horovod_tpu.parallel import sequence as sp_lib
 from horovod_tpu.parallel import tensor_parallel as tp_lib
-from horovod_tpu.utils.compat import lax_axis_size
 
 Params = Dict[str, Any]
 
@@ -317,7 +316,7 @@ def forward(cfg: TransformerConfig, params: Params, tokens: jax.Array
     """
     seq_total = tokens.shape[1]
     if cfg.sp_axis:
-        seq_total *= lax_axis_size(cfg.sp_axis)  # tokens arrive seq-sharded
+        seq_total *= lax.axis_size(cfg.sp_axis)  # tokens arrive seq-sharded
     if seq_total > cfg.max_seq:
         raise ValueError(
             f"sequence length {seq_total} exceeds cfg.max_seq={cfg.max_seq}")
@@ -375,7 +374,7 @@ def loss_fn(cfg: TransformerConfig, params: Params, tokens: jax.Array,
         # once by masking all but the last stage, then summing over pp too.
         # This also zeroes head/final_norm cotangents off the last stage so
         # the uniform psum-over-replicated-axes grad sync stays exact.
-        last = lax.axis_index(cfg.pp_axis) == lax_axis_size(cfg.pp_axis) - 1
+        last = lax.axis_index(cfg.pp_axis) == lax.axis_size(cfg.pp_axis) - 1
         total = jnp.where(last, total, 0.0)
         count = jnp.where(last, count, 0.0)
         data_axes.append(cfg.pp_axis)

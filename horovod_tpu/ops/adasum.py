@@ -26,7 +26,6 @@ import numpy as np
 from jax import lax
 
 from horovod_tpu.runtime.topology import HVD_AXIS
-from horovod_tpu.utils.compat import lax_axis_size
 
 
 def _pairwise_adasum(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -85,7 +84,7 @@ def adasum_allreduce(
             # the MPI path's all-world pow2 restriction to
             # local x (pow2 cross) worlds (e.g. 3x2 = 6 chips).
             cross_axis, local_axis = axis
-            nc = lax_axis_size(cross_axis)
+            nc = lax.axis_size(cross_axis)
             if nc & (nc - 1) != 0:
                 raise ValueError(
                     f"hierarchical Adasum requires a power-of-2 CROSS axis, "
@@ -98,7 +97,7 @@ def adasum_allreduce(
                 # the ranks that actually supplied data). Ranks linearize
                 # row-major (cross, local), so rank r belongs to local
                 # group r // n_local.
-                nl = lax_axis_size(local_axis)
+                nl = lax.axis_size(local_axis)
                 counts = np.full((nc,), nl, np.int64)
                 for r in joined_ranks:
                     g = int(r) // nl
@@ -119,7 +118,7 @@ def adasum_allreduce(
         else:
             raise ValueError("adasum_allreduce takes one mesh axis or a "
                              "(cross, local) pair")
-    n = lax_axis_size(axis)
+    n = lax.axis_size(axis)
     if n & (n - 1) != 0:
         raise ValueError(
             f"Adasum requires a power-of-2 world size, got {n} "

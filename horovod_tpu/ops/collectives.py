@@ -38,7 +38,6 @@ from jax import lax
 from horovod_tpu.ops.reduce_ops import ReduceOp, check_supported
 from horovod_tpu.runtime.topology import CROSS_AXIS, DCN_AXIS, HVD_AXIS, \
     LOCAL_AXIS
-from horovod_tpu.utils.compat import lax_axis_size
 
 AxisSpec = Union[str, Tuple[str, ...]]
 
@@ -52,12 +51,12 @@ def axis_rank(axis: AxisSpec = HVD_AXIS):
     axes = _axes_tuple(axis)
     r = lax.axis_index(axes[0])
     for a in axes[1:]:
-        r = r * lax_axis_size(a) + lax.axis_index(a)
+        r = r * lax.axis_size(a) + lax.axis_index(a)
     return r
 
 
 def axis_size(axis: AxisSpec = HVD_AXIS) -> int:
-    return int(np.prod([lax_axis_size(a) for a in _axes_tuple(axis)]))
+    return int(np.prod([lax.axis_size(a) for a in _axes_tuple(axis)]))
 
 
 def _resolve_groups(process_set, axis: AxisSpec):
@@ -469,9 +468,9 @@ def hierarchical_allreduce(
     shard = lax.psum(shard, cross_axes)
     out = lax.all_gather(shard, local_axis, axis=0, tiled=True)
     if op == ReduceOp.AVERAGE:
-        n = lax_axis_size(local_axis)
+        n = lax.axis_size(local_axis)
         for a in cross_axes:
-            n *= lax_axis_size(a)
+            n *= lax.axis_size(a)
         out = out / jnp.asarray(n, out.dtype)
     return out
 
@@ -525,7 +524,7 @@ def two_level_allreduce(
     if not ici:
         raise ValueError("two_level_allreduce needs >= 1 ICI axis")
     n_ici = axis_size(ici)
-    n_dcn = lax_axis_size(dcn_axis)
+    n_dcn = lax.axis_size(dcn_axis)
     world = n_ici * n_dcn
     x = _apply_scale(x, prescale_factor)
     orig = x.shape[0]

@@ -41,11 +41,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:                                  # CPU wheels lack the TPU backend
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:                   # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 
@@ -80,7 +76,7 @@ def _fwd_kernel(x_ref, w_ref, inv_ref, shift_ref, y_ref, s1_ref, s2_ref,
                 bm: int, bn: int):
     m = pl.program_id(0)
     n = pl.program_id(1)
-    if prologue and scratch:
+    if prologue:
         xh_scr, = scratch
         # The A tile is loaded once per m-step and reused across the whole
         # n loop; compute the normalized activation once into scratch.
@@ -90,12 +86,6 @@ def _fwd_kernel(x_ref, w_ref, inv_ref, shift_ref, y_ref, s1_ref, s2_ref,
                    + shift_ref[...])
             xh_scr[...] = jnp.maximum(pre, 0.0).astype(xh_scr.dtype)
         xh = xh_scr[...]
-    elif prologue:
-        # No VMEM scratch available (pltpu missing: interpret mode on a
-        # CPU wheel) — recompute the normalized tile per n-step instead.
-        pre = (x_ref[...].astype(jnp.float32) * inv_ref[...]
-               + shift_ref[...])
-        xh = jnp.maximum(pre, 0.0).astype(x_ref.dtype)
     else:
         xh = x_ref[...]
     off = pl.multiple_of(n * bn, bn)
@@ -188,13 +178,10 @@ def _fwd_call(cfg, x, w, inv, shift):
     np_ = w.shape[1]
     grid = (mp // bm, np_ // bn)
     kwargs = {}
-    if pltpu is not None and not interpret:
+    if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"))
-    # Scratch needs pltpu's VMEM spec; without it (interpret mode on a CPU
-    # wheel) the kernel recomputes the prologue tile inline instead.
-    scratch = [pltpu.VMEM((bm, kp), x.dtype)] \
-        if prologue and pltpu is not None else []
+    scratch = [pltpu.VMEM((bm, kp), x.dtype)] if prologue else []
     kernel = functools.partial(
         _fwd_kernel, prologue=prologue, m_valid=m_valid, bm=bm, bn=bn)
     return pl.pallas_call(
@@ -231,7 +218,7 @@ def _bwd_call(cfg, x, w, inv, shift, y, dy, ds1, ds2):
     np_ = w.shape[1]
     grid = (mp // bm,)
     kwargs = {}
-    if pltpu is not None and not interpret:
+    if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",))
     kernel = functools.partial(
@@ -362,4 +349,4 @@ def supports(cin: int, cout: int) -> bool:
     W (bf16) + the f32 dW accumulator resident in VMEM, so cin*cout must
     stay <= 1M elements (6 MB resident) — covers every ResNet 1x1 except
     the stage-4 1024->2048 projection, which falls back to XLA."""
-    return pltpu is not None and cin * cout <= 1024 * 1024
+    return cin * cout <= 1024 * 1024

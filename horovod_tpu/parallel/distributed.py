@@ -352,10 +352,9 @@ def _plan_sync_buckets(gs, axes, world: int):
 def _axes_world(axes) -> int:
     """Total rank count across the named axes, INSIDE a traced mesh
     context."""
-    from horovod_tpu.utils.compat import lax_axis_size
     world = 1
     for ax in axes:
-        world *= int(lax_axis_size(ax))
+        world *= int(lax.axis_size(ax))
     return world
 
 

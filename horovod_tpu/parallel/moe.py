@@ -21,7 +21,6 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from horovod_tpu.utils.compat import lax_axis_size
 
 
 class MoEMetrics(NamedTuple):
@@ -77,7 +76,7 @@ def moe_ffn(
     tokens = x.reshape(-1, d_model)                       # [T, D]
     t_count = tokens.shape[0]
     e_local = w_in.shape[0]
-    ep = lax_axis_size(ep_axis) if ep_axis else 1
+    ep = lax.axis_size(ep_axis) if ep_axis else 1
     e_total = e_local * ep
     if router_w.shape[-1] != e_total:
         raise ValueError(

@@ -20,7 +20,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from horovod_tpu.utils.compat import lax_axis_size
 
 
 def pipeline_apply(
@@ -44,7 +43,7 @@ def pipeline_apply(
     Schedule: at step t, stage s processes microbatch (t - s); stage 0 feeds
     fresh microbatches, stage pp-1 collects. T = M + pp - 1 steps.
     """
-    pp = lax_axis_size(pp_axis)
+    pp = lax.axis_size(pp_axis)
     s_idx = lax.axis_index(pp_axis)
     n_micro = x_microbatches.shape[0]
     total_steps = n_micro + pp - 1

@@ -27,7 +27,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from horovod_tpu.utils.compat import lax_axis_size
 
 NEG_INF = -1e30
 
@@ -113,7 +112,7 @@ def _ring_flash_mode(q, k, v, scale):
 def _ring_fwd_scan(q, k, v, axis_name, causal, scale):
     """The forward ring; returns (out [B,Sq,H,D] in q.dtype,
     lse [B,H,Sq] f32 — the global logsumexp needed by the backward)."""
-    n = lax_axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     s_local = q.shape[1]
     use_flash, interpret = _ring_flash_mode(q, k, v, scale)
@@ -203,7 +202,7 @@ def _bwd_block_jnp(q, k, v, do, lse, dD, qoff, koff, causal, scale):
 
 def _ring_attention_cvjp_bwd(axis_name, causal, scale, res, dout):
     q, k, v, o, lse = res
-    n = lax_axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     s_local = q.shape[1]
     use_flash, interpret = _ring_flash_mode(q, k, v, scale)
@@ -282,7 +281,7 @@ def ulysses_attention(
     the local heads, all-to-all back. The axis size must divide the head
     count.
     """
-    n = lax_axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if q.shape[2] % n != 0:
         raise ValueError(f"ulysses: heads {q.shape[2]} not divisible by {n}")
 
@@ -304,7 +303,7 @@ def sequence_shard(x: jax.Array, axis_name: str, seq_dim: int = 1):
     the entry reshard for SP regions (reducescatter/allgather pairs at region
     boundaries are the reference-primitive way, SURVEY §5; here a static
     slice since the input is replicated)."""
-    n = lax_axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     i = lax.axis_index(axis_name)
     s = x.shape[seq_dim]
     if s % n != 0:

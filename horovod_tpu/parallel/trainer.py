@@ -474,8 +474,7 @@ def _verify_train_step(train_step, state, batches, *, strict: bool):
     # wrap_compiled: signature rejection (shapes/shardings moved away
     # from the verified ones — raised BEFORE execution/donation) falls
     # back to the jit permanently; genuine runtime failures propagate
-    # unmasked; a store-served executable gets the first-dispatch
-    # donation guard (store.artifact_store.donation_guard docstring).
+    # unmasked.
     from horovod_tpu.store.artifact_store import wrap_compiled
     return wrap_compiled(compiled, train_step,
                          label="verified step"), batches, True

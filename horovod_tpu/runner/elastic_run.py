@@ -462,7 +462,6 @@ def launch_elastic(args, extra_env: Dict[str, str]) -> int:
                 (flags + " --xla_force_host_platform_device_count=1")
                 .strip(),
             "JAX_PLATFORMS": "cpu",
-            "HVD_TPU_FORCE_CPU": "1",
         }
     launcher = ElasticLauncher(
         cmd, discovery,

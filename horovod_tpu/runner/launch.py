@@ -280,9 +280,6 @@ def _launch_local(args, extra_env: dict) -> int:
         env["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count={np_}").strip()
         env["JAX_PLATFORMS"] = "cpu"
-        # sitecustomize-style early importers may pin another platform;
-        # jax.config reads this one at import in the child.
-        env["HVD_TPU_FORCE_CPU"] = "1"
     elif args.num_proc is not None:
         env["HVD_TPU_EXPECT_NP"] = str(args.num_proc)
     if args.verbose:

@@ -116,8 +116,6 @@ def init(
         from horovod_tpu.goodput import accountant as _goodput
         _goodput.init_begin()
         # Environment wiring from the hvdrun launcher (runner/launch.py).
-        if os.environ.get("HVD_TPU_FORCE_CPU"):
-            jax.config.update("jax_platforms", "cpu")
         if coordinator_address is None and os.environ.get(
                 "HVD_TPU_COORDINATOR"):
             coordinator_address = os.environ["HVD_TPU_COORDINATOR"]

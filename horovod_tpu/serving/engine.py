@@ -346,7 +346,10 @@ class ServeEngine:
         self.slot_skip: List[int] = [0] * self.slots
         self.cow_copies = 0
 
-        # device placement: pages sharded over KV heads under TP
+        # device placement: on the mesh when one is given — pages sharded
+        # over KV heads under TP, otherwise params and pages replicated
+        # over it (a one-device mesh pins the replica to that chip);
+        # without a mesh, wherever the caller's params already live.
         if tp and mesh is not None:
             kv_spec = P(None, None, None, tp, None)
             self._kv_sharding = NamedSharding(mesh, kv_spec)
@@ -354,6 +357,10 @@ class ServeEngine:
             self.params = jax.device_put(params, jax.tree.map(
                 lambda s: NamedSharding(mesh, s), pspecs,
                 is_leaf=lambda x: isinstance(x, P)))
+        elif mesh is not None:
+            kv_spec = None
+            self._kv_sharding = NamedSharding(mesh, P())
+            self.params = jax.device_put(params, self._kv_sharding)
         else:
             kv_spec = None
             self._kv_sharding = None
@@ -407,6 +414,7 @@ class ServeEngine:
         # included.
         self.builds = 0
         self.store_outcomes: Dict[str, str] = {}
+        self._dispatch: Dict[str, Callable] = {}
         self._decode = self._adopt(
             self._decode_jit, self._decode_args(), "serve_decode")
         self._prefill: Dict[int, Callable] = {}
@@ -473,16 +481,24 @@ class ServeEngine:
         if store_mod.enabled():
             wrapped, outcome = store_mod.adopt_step(
                 fn, args, label=label, kind="serve")
-            self.store_outcomes[label] = outcome
             if outcome != "hit":
                 self.builds += 1
-            return wrapped
-        compiled, dt = store_mod.aot_compile(fn, args)
-        self.builds += 1
-        self.store_outcomes[label] = "disabled"
-        logger.debug("serve: %s compiled in %.2fs (no artifact store)",
-                     label, dt)
-        return store_mod.wrap_compiled(compiled, fn, label)
+        else:
+            compiled, dt = store_mod.aot_compile(fn, args)
+            self.builds += 1
+            outcome = "disabled"
+            logger.debug("serve: %s compiled in %.2fs (no artifact "
+                         "store)", label, dt)
+            wrapped = store_mod.wrap_compiled(compiled, fn, label)
+        self.store_outcomes[label] = outcome
+        self._dispatch[label] = wrapped
+        return wrapped
+
+    def executable_text(self, label: str = "serve_decode") -> str:
+        """Compiled HLO text of one of the engine's AOT executables (the
+        labels of ``store_outcomes``) — what a caller inspects to see
+        which kernels the step it dispatches really contains."""
+        return self._dispatch[label].hvd_store_compiled.as_text()
 
     # -- slot API (driven by the scheduler at step boundaries) ---------------
     def reserve(self, n_tokens_worst_case: int,
@@ -638,8 +654,13 @@ class ServeEngine:
             self.params, self.k_pages, self.v_pages,
             jnp.asarray(bt_np), jnp.asarray(ln_np),
             jnp.asarray(np.asarray(tokens, np.int32)))
+        # Read the result back BEFORE touching the host tables: the
+        # dispatch is asynchronous and jnp.asarray may alias the NumPy
+        # buffers it was given (zero-copy on the CPU backend), so
+        # advancing the lengths first races the step that is reading them.
+        nxt = np.asarray(nxt)
         self.tables.lengths[active] += 1
-        return np.asarray(nxt)
+        return nxt
 
     # -- speculative decode (draft K, verify all K in one step) --------------
     def propose_drafts(self, tokens: np.ndarray,
@@ -758,6 +779,11 @@ class ServeEngine:
             "spec_k": self.spec_k,
             "builds": self.builds,
             "store_outcomes": dict(self.store_outcomes),
+            # executables that rejected their inputs and now dispatch
+            # through the jit fall-back (wrap_compiled); empty is healthy
+            "store_rejected": sorted(
+                label for label, fn in self._dispatch.items()
+                if getattr(fn, "hvd_store_rejected", None)),
             "tp": self._tp_size,
         }
 

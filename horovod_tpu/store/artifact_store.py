@@ -349,21 +349,21 @@ class ArtifactStore:
                            f"!= verified {live} for tag {order_tag}")
                 return None
         try:
-            from jax.experimental import serialize_executable as se
-            serialized, in_tree, out_tree = pickle.loads(payload)
-            compiled = se.deserialize_and_load(serialized, in_tree,
-                                               out_tree)
+            import jax
+            by_id = {d.id: d for d in jax.devices()}
+            devices = [by_id[i] for i in header["device_ids"]]
+            if not reloadable(devices[0].platform, len(devices),
+                              len(by_id)):
+                self._miss("unloadable", path,
+                           f"a {len(devices)}-of-{len(by_id)}-chip program "
+                           f"cannot be reloaded on this TPU runtime")
+                return None
+            compiled = _deserialize_onto(devices, *pickle.loads(payload))
         except Exception as e:
             self._tally("errors")
             self._miss("corrupt", path,
                        f"deserialize failed ({type(e).__name__}: {e})")
             return None
-        try:
-            # Marks the executable as deserialized so dispatchers apply
-            # the first-call donation_guard (see its docstring).
-            compiled._hvd_store_loaded = True
-        except Exception:
-            pass
         self._hit(key, header)
         return compiled
 
@@ -458,7 +458,8 @@ class ArtifactStore:
                         "not persisted", key, type(e).__name__, e)
             return False
         meta: Dict[str, Any] = {"compile_seconds":
-                                round(float(compile_seconds), 6)}
+                                round(float(compile_seconds), 6),
+                                "device_ids": device_ids(compiled)}
         if extra_meta:
             meta.update(extra_meta)
         if order_tag:
@@ -637,6 +638,70 @@ def reset_for_tests() -> None:
 # step-level consumers: key material + AOT adopt helpers
 # ---------------------------------------------------------------------------
 
+def device_ids(stage: Any) -> List[int]:
+    """Ids of the devices a ``jax.stages.Lowered`` or ``Compiled`` is
+    assigned to, in assignment order — key material (two placements of
+    one program are two executables) and what a load must hand back to
+    ``deserialize_and_load``. jax 0.9 exposes the assignment only on the
+    stage's internals."""
+    lowering = getattr(stage, "_lowering", None)
+    if lowering is not None:
+        return [int(d.id) for d in lowering._device_list]
+    return [int(d.id) for d in
+            stage._executable._unloaded_executable.device_list]
+
+
+def reloadable(platform: str, n_devices: int, n_backend: int) -> bool:
+    """Whether a serialized program over ``n_devices`` of the backend's
+    ``n_backend`` may be loaded back. Measured on a 4-chip v5e host
+    (PERF.md, PR 21): one chip or all four reload and run; a program
+    spanning SOME of the chips halts the core at its first collective,
+    with or without re-targeting — so on TPU that one recompiles."""
+    return platform != "tpu" or n_devices in (1, n_backend)
+
+
+def _deserialize_onto(devices: List[Any], serialized: bytes, in_tree: Any,
+                      out_tree: Any) -> Any:
+    """``serialize_executable.deserialize_and_load`` onto ``devices`` (the
+    assignment the program was compiled for, in order).
+
+    The library call cannot do it: its default is EVERY device of the
+    backend — a one-chip program on a four-chip host then demands four
+    shards — and even with ``execution_devices`` the TPU runtime assigns a
+    reloaded program to the FIRST n devices unless the load carries
+    compile options naming the assignment (measured, PERF.md PR 21; JAX's
+    own compilation cache passes them for the same reason). So this is
+    that function's body with the options added."""
+    import io
+
+    import jax
+    import numpy as np
+    from jax._src import compiler, dispatch
+    from jax.experimental import serialize_executable as se
+
+    backend = devices[0].client
+    options = compiler.get_compile_options(
+        num_replicas=1, num_partitions=len(devices),
+        device_assignment=np.array(devices, dtype=object).reshape(1, -1),
+        use_spmd_partitioning=True, backend=backend)
+    options.parameter_is_tupled_arguments = dispatch.should_tuple_args(
+        in_tree.num_leaves, backend.platform)
+
+    class Unpickler(se._JaxPjrtUnpickler):
+        def persistent_load(self, pid):
+            if pid[0] == "exec":
+                return self.backend.deserialize_executable(
+                    pid[1], executable_devices=self.execution_devices,
+                    compile_options=options)
+            return super().persistent_load(pid)
+
+    unloaded, args_info_flat, no_kwargs = Unpickler(
+        io.BytesIO(serialized), backend, devices).load()
+    return jax.stages.Compiled(
+        unloaded.load(), [], in_tree.unflatten(args_info_flat), out_tree,
+        no_kwargs=no_kwargs)
+
+
 def aot_compile(jitted: Any, args: Tuple[Any, ...]) -> Tuple[Any, float]:
     """(compiled, seconds): explicit AOT lower+compile of a jitted
     callable with the run's concrete (or abstract) args."""
@@ -660,71 +725,21 @@ def program_text_hash(lowered: Any) -> Optional[str]:
         return None
 
 
-def _copy_donated_args(compiled: Any, args: Tuple[Any, ...]
-                       ) -> Tuple[Any, ...]:
-    """Fresh XLA-owned copies of the donated arg leaves (all jax.Array
-    leaves when the donation flags are unreadable). Sharding is
-    preserved (jnp.copy of a committed array keeps its layout)."""
-    import jax
-    import jax.numpy as jnp
-    leaves, treedef = jax.tree_util.tree_flatten(args)
-    flags: Optional[List[bool]]
-    try:
-        flags = [bool(getattr(i, "donated", False))
-                 for i in jax.tree_util.tree_leaves(compiled.args_info)]
-        if len(flags) != len(leaves):
-            flags = None
-    except Exception:
-        flags = None
-    out = [jnp.copy(leaf)
-           if isinstance(leaf, jax.Array) and (flags is None or flags[i])
-           else leaf
-           for i, leaf in enumerate(leaves)]
-    return tuple(treedef.unflatten(out))
-
-
-def donation_guard(compiled: Any) -> Callable:
-    """Dispatch wrapper for STORE-LOADED executables only (marked by
-    :meth:`ArtifactStore.load_executable`): the first call copies the
-    donated input leaves onto fresh XLA-owned buffers.
-
-    Why: on jaxlib 0.4.37, dispatching a DESERIALIZED executable whose
-    donated inputs alias externally-owned memory — exactly what an
-    orbax-restored TrainState is on the resume path — segfaults the
-    process (a fresh AOT compile of the same program is fine; verified
-    empirically, see tests). Later calls pass through untouched: their
-    donated inputs are the executable's own outputs. Unmarked
-    executables are returned unchanged."""
-    if not getattr(compiled, "_hvd_store_loaded", False):
-        return compiled
-    first: List[bool] = [True]
-
-    def guarded(*a):
-        if first:
-            first.clear()
-            a = _copy_donated_args(compiled, a)
-        return compiled(*a)
-
-    guarded.args_info = getattr(compiled, "args_info", None)
-    return guarded
-
-
 def wrap_compiled(compiled: Any, fallback: Callable,
                   label: str = "step") -> Callable:
     """Dispatch through a (possibly store-loaded) AOT executable with a
     permanent fall-back to the original jitted callable on signature
     rejection (shapes/shardings moved away from the compiled ones —
     raised BEFORE execution/donation, so the retry is safe). Genuine
-    runtime failures propagate unmasked. Store-loaded executables
-    additionally get the first-dispatch :func:`donation_guard`."""
+    runtime failures propagate unmasked. ``dispatch.hvd_store_rejected``
+    is non-empty once the fall-back was taken."""
     rejected: List[bool] = []
-    target = donation_guard(compiled)
 
     def dispatch(*a):
         if rejected:
             return fallback(*a)
         try:
-            return target(*a)
+            return compiled(*a)
         except (TypeError, ValueError) as e:
             logger.warning(
                 "artifact store: cached %s executable rejected the "
@@ -734,6 +749,7 @@ def wrap_compiled(compiled: Any, fallback: Callable,
             return fallback(*a)
 
     dispatch.hvd_store_compiled = compiled      # tests / introspection
+    dispatch.hvd_store_rejected = rejected
     return dispatch
 
 
@@ -742,8 +758,9 @@ def step_key_components(step_fn: Any, args: Tuple[Any, ...], *,
     """Composite key material for a train/verify step executable: the
     step's symbol + input signature, the LOWERED program's content hash
     (``lowered`` — a code-only edit to the step/loss must miss; callers
-    on the adopt/verify paths always have one in hand), the mesh
-    fingerprint, the resolved program-keying knobs, and — when the
+    on the adopt/verify paths always have one in hand) and its device
+    assignment (the same program placed on another chip is another
+    executable), the mesh fingerprint, the resolved program-keying knobs, and — when the
     state arg carries params — the gradient payload signature with the
     bucket size 'auto' actually resolves to for it (autotune sweep
     cache)."""
@@ -762,6 +779,7 @@ def step_key_components(step_fn: Any, args: Tuple[Any, ...], *,
     }
     if lowered is not None:
         comps["program"] = program_text_hash(lowered)
+        comps["devices"] = device_ids(lowered)
     params = getattr(args[0], "params", None) if args else None
     if params is not None:
         try:

@@ -132,6 +132,8 @@ def distributed_kv(site: str = "kv"):
     if injected is not None:
         return RetryingKV(DistributedKV(injected), site=site)
     try:
+        # No public accessor for the coordination-service client exists
+        # in jax 0.9.0; this private one is still present there.
         from jax._src.distributed import global_state
         client = global_state.client
     except Exception:       # pragma: no cover - jax internals moved

@@ -203,7 +203,6 @@ class ProcessWorld:
             env.update(self.extra_env)
             env.update({
                 "JAX_PLATFORMS": "cpu",
-                "HVD_TPU_FORCE_CPU": "1",
                 "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
                 "HVD_TPU_COORDINATOR": self.coordinator,
                 "HVD_TPU_NUM_PROCESSES": str(self.nproc),

@@ -60,6 +60,48 @@ def test_executable_round_trip(store):
     assert s["compile_seconds_saved"] > 0      # publish-time measured cost
 
 
+@pytest.mark.parametrize("device_ids", [[5], [2, 3]])
+def test_sub_mesh_executable_loads_onto_its_own_devices(store, device_ids):
+    """A program compiled for ONE device, or a 2-device sub-mesh, of the
+    8-device process: the store-loaded executable runs on exactly those
+    devices (not on every device of the backend) and is bitwise the
+    fresh compile. Two placements of one program are two entries."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    def place(ids):
+        mesh = Mesh(np.array([jax.devices()[i] for i in ids]), ("x",))
+        return jax.device_put(jnp.arange(64.0).reshape(8, 8),
+                              NamedSharding(mesh, P("x")))
+
+    f = jax.jit(lambda x: jnp.tanh(x @ x.T) * 3, donate_argnums=0)
+    want = np.asarray(f.lower(place(device_ids)).compile()(place(device_ids)))
+    cold, outcome = st.adopt_step(f, (place(device_ids),), kind="serve")
+    assert outcome == "miss"
+    warm, outcome = st.adopt_step(f, (place(device_ids),), kind="serve")
+    assert outcome == "hit"
+    out = warm(place(device_ids))
+    assert sorted(d.id for d in out.sharding.device_set) == device_ids
+    np.testing.assert_array_equal(np.asarray(out), want)
+    assert not warm.hvd_store_rejected      # no jit fall-back was taken
+    # the same program on other devices is another executable
+    other = [i + 1 for i in device_ids]
+    moved, outcome = st.adopt_step(f, (place(other),), kind="serve")
+    assert outcome == "miss"
+    out = moved(place(other))
+    assert sorted(d.id for d in out.sharding.device_set) == other
+    np.testing.assert_array_equal(np.asarray(out), want)
+
+
+def test_tpu_sub_mesh_programs_are_not_reloaded():
+    """One chip or the whole backend reload on TPU; a program over some
+    of the chips halted the core when reloaded (chip run, PR 21), so it
+    recompiles. Other platforms reload anything."""
+    assert st.reloadable("tpu", 1, 4) and st.reloadable("tpu", 4, 4)
+    assert not st.reloadable("tpu", 2, 4)
+    assert not st.reloadable("tpu", 3, 4)
+    assert st.reloadable("cpu", 2, 8)
+
+
 def test_blob_round_trip(store):
     key = store.key("bucket_auto_sweep", grad_signature="g", workload="w")
     obj = {"winner_bucket_bytes": 123, "candidates": {"1": {"s": 0.5}}}

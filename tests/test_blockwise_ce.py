@@ -166,17 +166,18 @@ def test_transformer_loss_fn_blockwise_equals_unfused():
     cfg = tfm.TransformerConfig(
         vocab_size=101, d_model=32, n_heads=2, head_dim=16, n_layers=2,
         d_ff=128, max_seq=64, dtype=jnp.float32, dp_axis=None, remat=False)
-    params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+    params = jax.jit(lambda r: tfm.init_params(cfg, r))(jax.random.PRNGKey(0))
     rng = np.random.RandomState(0)
     tok = jnp.asarray(rng.randint(0, 101, (2, 16)), jnp.int32)
     lab = jnp.asarray(rng.randint(0, 101, (2, 16)), jnp.int32)
     cfg0 = dataclasses.replace(cfg, ce_block_vocab=0, mlp_recompute=False)
     cfgb = dataclasses.replace(cfg, ce_block_vocab=16)
-    np.testing.assert_allclose(
-        float(tfm.loss_fn(cfg0, params, tok, lab)),
-        float(tfm.loss_fn(cfgb, params, tok, lab)), rtol=1e-6)
-    g0 = jax.grad(lambda p: tfm.loss_fn(cfg0, p, tok, lab))(params)
-    gb = jax.grad(lambda p: tfm.loss_fn(cfgb, p, tok, lab))(params)
+    # jitted: one compile per config instead of op-by-op eager dispatch
+    (l0, g0), (lb, gb) = (
+        jax.jit(jax.value_and_grad(
+            lambda p, c=c: tfm.loss_fn(c, p, tok, lab)))(params)
+        for c in (cfg0, cfgb))
+    np.testing.assert_allclose(float(l0), float(lb), rtol=1e-6)
     for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(gb)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-6)

@@ -94,8 +94,7 @@ def test_known_failures_manifest_is_well_formed():
         from tests.check_known_failures import DEFAULT_KNOWN, load_known
     except ImportError:
         from check_known_failures import DEFAULT_KNOWN, load_known
-    known = load_known(DEFAULT_KNOWN)
-    assert known, "manifest exists and is non-empty"
+    known = load_known(DEFAULT_KNOWN)      # the file exists; it may be empty
     for nid in known:
         path = nid.split("::", 1)[0]
         assert "::" in nid, nid

@@ -83,12 +83,13 @@ _HLO = """\
 HloModule synthetic, is_scheduled=true
 
 ENTRY %main (p0: f32[4096,1024], p1: f32[1024,4096]) -> f32[] {
-  %p0 = f32[4096,1024] parameter(0)
-  %p1 = f32[1024,4096] parameter(1)
-  %dot.1 = f32[4096,4096] dot(f32[4096,1024] %p0, f32[1024,4096] %p1), lhs_contracting_dims={1}, rhs_contracting_dims={0}
-  %reduce.1 = f32[] reduce(f32[4096,4096] %dot.1, f32[] %p0), dimensions={0,1}
-  %reduce.2 = f32[] reduce(f32[4096,4096] %dot.1, f32[] %p0), dimensions={0,1}
-  ROOT %reduce.3 = f32[] reduce(f32[4096,4096] %dot.1, f32[] %p0), dimensions={0,1}
+  %p0 = f32[4096,1024]{1,0} parameter(0)
+  %p1 = f32[1024,4096]{1,0} parameter(1)
+  %c0 = f32[] constant(0)
+  %dot.1 = f32[4096,4096]{1,0} dot(%p0, %p1), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+  %reduce.1 = f32[] reduce(%dot.1, %c0), dimensions={0,1}
+  %reduce.2 = f32[] reduce(%dot.1, %c0), dimensions={0,1}
+  ROOT %reduce.3 = f32[] reduce(%dot.1, %c0), dimensions={0,1}
 }
 """
 
@@ -98,8 +99,11 @@ class TestSyntheticHlo:
         comps, entry = rules_cost.parse_computations(_HLO)
         assert entry == "main"
         assert [i.op for i in comps["main"]] == \
-            ["parameter", "parameter", "dot", "reduce", "reduce",
-             "reduce"]
+            ["parameter", "parameter", "constant", "dot", "reduce",
+             "reduce", "reduce"]
+        # operands are bare references, resolved to their producer's shape
+        assert comps["main"][3].operands == [
+            ("f32", (4096, 1024), "p0"), ("f32", (1024, 4096), "p1")]
 
     def test_liveness_peak_is_the_dot_result(self):
         comps, entry = rules_cost.parse_computations(_HLO)
@@ -119,7 +123,7 @@ class TestSyntheticHlo:
 
     def test_dot_flops_use_contracting_dim(self):
         comps, entry = rules_cost.parse_computations(_HLO)
-        dot = comps[entry][2]
+        dot = comps[entry][3]
         assert rules_cost._dot_flops(dot) == 2 * 4096 * 4096 * 1024
 
 

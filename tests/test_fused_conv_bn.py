@@ -105,12 +105,21 @@ def test_fused_resnet_matches_plain_train_mode():
     plain, fused = _models()
     x = jnp.asarray(np.random.RandomState(0).randn(2, 32, 32, 3),
                     jnp.float32)
-    vp = plain.init(jax.random.PRNGKey(0), x, train=True)
-    vf_tmpl = fused.init(jax.random.PRNGKey(0), x, train=True)
-    vf = _map_tree(vf_tmpl, vp)
+    # jitted throughout: one compile per call instead of op-by-op eager
+    # dispatch (each interpreted pallas_call is a program of its own)
+    def init(m):
+        return jax.jit(lambda r, a: m.init(r, a, train=True))(
+            jax.random.PRNGKey(0), x)
 
-    op, msp = plain.apply(vp, x, train=True, mutable=["batch_stats"])
-    of, msf = fused.apply(vf, x, train=True, mutable=["batch_stats"])
+    def apply(m, v):
+        return jax.jit(lambda v, a: m.apply(
+            v, a, train=True, mutable=["batch_stats"]))(v, x)
+
+    vp = init(plain)
+    vf = _map_tree(init(fused), vp)
+
+    op, msp = apply(plain, vp)
+    of, msf = apply(fused, vf)
     np.testing.assert_allclose(np.asarray(of), np.asarray(op),
                                rtol=5e-4, atol=5e-4)
     # running statistics advanced identically
@@ -133,8 +142,8 @@ def test_fused_resnet_matches_plain_train_mode():
                 -jax.nn.log_softmax(logits)[jnp.arange(2), y])
         return go
 
-    gp = jax.grad(loss(plain, vp))(vp["params"])
-    gf = jax.grad(loss(fused, vf))(vf["params"])
+    gp = jax.jit(jax.grad(loss(plain, vp)))(vp["params"])
+    gf = jax.jit(jax.grad(loss(fused, vf)))(vf["params"])
     fgp = flatten_dict(unfreeze(gp))
     fgf = flatten_dict(unfreeze(gf))
     for k, v in fgf.items():
@@ -147,10 +156,14 @@ def test_fused_resnet_matches_plain_eval_mode():
     plain, fused = _models()
     x = jnp.asarray(np.random.RandomState(2).randn(2, 32, 32, 3),
                     jnp.float32)
-    vp = plain.init(jax.random.PRNGKey(0), x, train=True)
-    vf = _map_tree(fused.init(jax.random.PRNGKey(0), x, train=True), vp)
-    op = plain.apply(vp, x, train=False)
-    of = fused.apply(vf, x, train=False)
+    def init(m):
+        return jax.jit(lambda r, a: m.init(r, a, train=True))(
+            jax.random.PRNGKey(0), x)
+
+    vp = init(plain)
+    vf = _map_tree(init(fused), vp)
+    op = jax.jit(lambda v, a: plain.apply(v, a, train=False))(vp, x)
+    of = jax.jit(lambda v, a: fused.apply(v, a, train=False))(vf, x)
     np.testing.assert_allclose(np.asarray(of), np.asarray(op),
                                rtol=5e-4, atol=5e-4)
 
@@ -186,33 +199,14 @@ def test_non_relu_act_rejected():
                    jnp.zeros((1, 32, 32, 3), jnp.float32), train=True)
 
 
-def test_interpret_without_pltpu(monkeypatch):
-    """Interpret mode must work on wheels lacking the Pallas TPU backend
-    (pltpu=None): the prologue falls back to inline recompute instead of
-    VMEM scratch (advisor round-4 finding)."""
-    from horovod_tpu.ops.pallas import conv_bn as m
-    monkeypatch.setattr(m, "pltpu", None)
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(2, 8, 8, 16), jnp.float32)
-    wt = jnp.asarray(rng.randn(16, 32) * 0.1, jnp.float32)
-    inv = jnp.asarray(rng.rand(16) + 0.5, jnp.float32)
-    shift = jnp.asarray(rng.randn(16) * 0.1, jnp.float32)
-    y, s1, s2 = conv1x1_bn_stats(x, wt, inv, shift, interpret=True)
-    ry, rs1, rs2 = _ref(x, wt, inv, shift)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(ry),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(s1), np.asarray(rs1),
-                               rtol=1e-4, atol=1e-4)
-
-
 def test_checkpoint_conversion_round_trips():
     """plain -> fused -> plain must reproduce the plain checkpoint
     exactly (the public converter pair documents/fixes the layout break
     the fused_conv_bn flag introduces)."""
     plain, fused = _models()
     x = jnp.zeros((1, 16, 16, 3), jnp.float32)
-    pv = plain.init(jax.random.PRNGKey(0), x)
-    fv_tmpl = fused.init(jax.random.PRNGKey(1), x)
+    pv = jax.jit(plain.init)(jax.random.PRNGKey(0), x)
+    fv_tmpl = jax.jit(fused.init)(jax.random.PRNGKey(1), x)
     fv = plain_to_fused_variables(fv_tmpl, pv)
     back = fused_to_plain_variables(pv, fv)
     for (ka, a), (kb, b) in zip(

@@ -424,14 +424,28 @@ class TestLedger:
         gp = [c for c in r["checks"] if c["check"] == "goodput_fraction"][0]
         assert gp["status"] == "regress"
 
-    def test_regression_report_against_committed_history(self):
-        """The acceptance check: a verdict against BENCH_r01-r05."""
-        r = ledger.regression_report(REPO, path="/nonexistent.jsonl")
+    def test_regression_report_five_round_history(self, tmp_path):
+        """A verdict over a five-round trajectory whose best round is
+        not the newest."""
+        d = self._bench_dir(tmp_path, [2151.12, 2516.48, 2523.94,
+                                       2495.87, 2519.41])
+        r = ledger.regression_report(d, path="/nonexistent.jsonl")
         assert r["bench_rounds"] == [1, 2, 3, 4, 5]
         bench = [c for c in r["checks"]
                  if c["check"] == "bench_throughput"][0]
         assert bench["status"] == "pass"
+        assert bench["best_prior_round"] == 3
         assert r["verdict"] == "pass"
+
+    def test_regression_report_without_chip_record_skips(self):
+        """The repo commits no BENCH round: the throughput axis says so
+        instead of judging."""
+        r = ledger.regression_report(REPO, path="/nonexistent.jsonl")
+        assert r["bench_rounds"] == []
+        bench = [c for c in r["checks"]
+                 if c["check"] == "bench_throughput"][0]
+        assert bench["status"] == "skipped"
+        assert "no chip record" in bench["reason"]
 
     # ---- the serving axis (BENCH_SERVE.json vs serve-bench records) ----
 

@@ -30,8 +30,10 @@ def test_mnist_cnn_forward():
 
 def test_resnet50_forward_shapes():
     m = ResNet50(num_classes=10, dtype=jnp.float32)
-    vars_ = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)))
-    out = m.apply(vars_, jnp.zeros((2, 32, 32, 3)), train=False)
+    # jitted: one compile each instead of an op-by-op eager trace
+    vars_ = jax.jit(m.init)(jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)))
+    out = jax.jit(lambda v, x: m.apply(v, x, train=False))(
+        vars_, jnp.zeros((2, 32, 32, 3)))
     assert out.shape == (2, 10)
     assert out.dtype == jnp.float32
     # bottleneck expansion: last stage has 512*4 channels
@@ -41,10 +43,10 @@ def test_resnet50_forward_shapes():
 
 def test_resnet18_train_mode_updates_batch_stats():
     m = ResNet18(num_classes=10, dtype=jnp.float32)
-    vars_ = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)))
-    out, new_state = m.apply(
-        vars_, jnp.ones((2, 32, 32, 3)), train=True,
-        mutable=["batch_stats"])
+    vars_ = jax.jit(m.init)(jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)))
+    out, new_state = jax.jit(lambda v, x: m.apply(
+        v, x, train=True, mutable=["batch_stats"]))(
+        vars_, jnp.ones((2, 32, 32, 3)))
     assert out.shape == (2, 10)
     old = jax.tree.leaves(vars_["batch_stats"])
     new = jax.tree.leaves(new_state["batch_stats"])
@@ -134,8 +136,8 @@ def test_resnet_space_to_depth_stem(hvd_ctx):
     x = jnp.ones((2, 64, 64, 3), jnp.float32)
     for s2d in (False, True):
         model = ResNet18(num_classes=10, space_to_depth=s2d)
-        variables = model.init(jax.random.PRNGKey(0), x)
-        out = model.apply(variables, x)
+        variables = jax.jit(model.init)(jax.random.PRNGKey(0), x)
+        out = jax.jit(model.apply)(variables, x)
         assert out.shape == (2, 10)
         stem = [k for k in variables["params"] if k.startswith("conv_init")]
         assert stem == (["conv_init_s2d"] if s2d else ["conv_init"])
@@ -239,9 +241,9 @@ def test_resnet_folded_bn_option():
     for folded in (False, True):
         model = ResNet18(num_classes=10, dtype=jnp.float32,
                          folded_bn=folded)
-        variables = model.init(jax.random.PRNGKey(0), x)
-        logits, _ = model.apply(variables, x, train=True,
-                                mutable=["batch_stats"])
+        variables = jax.jit(model.init)(jax.random.PRNGKey(0), x)
+        logits, _ = jax.jit(lambda v, a: model.apply(
+            v, a, train=True, mutable=["batch_stats"]))(variables, x)
         assert logits.shape == (2, 10)
         assert np.isfinite(np.asarray(logits)).all()
 
@@ -259,15 +261,15 @@ def test_vgg16_forward_and_grad():
     model = VGG16(num_classes=10, dtype=jnp.float32, classifier_width=64)
     x = jnp.asarray(np.random.RandomState(0).randn(2, 32, 32, 3),
                     jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x)
-    out = model.apply(params, x)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x)
+    out = jax.jit(model.apply)(params, x)
     assert out.shape == (2, 10)
 
     def loss(p):
         return optax.softmax_cross_entropy_with_integer_labels(
             model.apply(p, x), jnp.asarray([1, 2])).mean()
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     assert all(np.isfinite(np.asarray(v)).all()
                for v in jax.tree.leaves(g))
 

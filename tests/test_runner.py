@@ -44,7 +44,6 @@ def test_local_launch_virtual_sets_device_count():
     env = call.call_args.kwargs["env"]
     assert "--xla_force_host_platform_device_count=4" in env["XLA_FLAGS"]
     assert env["JAX_PLATFORMS"] == "cpu"
-    assert env["HVD_TPU_FORCE_CPU"] == "1"
 
 
 def test_local_launch_no_command_errors():

@@ -80,6 +80,7 @@ def _rand_paged(rng, b, h, kvh, d, page, n_max, n_pages):
     (2, 4, 4, 128, 128, 3),       # lane-aligned page, MHA
     (3, 4, 2, 64, 128, 2),        # GQA grouping, short head dim
     (1, 2, 2, 128, 256, 2),       # multi-lane page
+    (4, 8, 4, 16, 32, 3),         # small page and head dim, GQA
 ])
 def test_paged_kernel_matches_reference(b, h, kvh, d, page, n_max):
     """The interpret-mode kernel is pinned against the jnp paged
@@ -131,10 +132,9 @@ def test_paged_kernel_empty_slot_returns_zeros():
 def test_paged_decode_supports_gates_non_dividing_shapes():
     q = jnp.zeros((2, 4, 128))
     ok = jnp.zeros((8, 128, 4, 128))
-    if fa.pltpu is None:
-        pytest.skip("pallas TPU frontend unavailable")
     assert fa.paged_decode_supports(q, ok)
-    assert not fa.paged_decode_supports(q, jnp.zeros((8, 16, 4, 128)))
+    # a block is a whole page: the page size is free
+    assert fa.paged_decode_supports(q, jnp.zeros((8, 16, 4, 128)))
     assert not fa.paged_decode_supports(q, jnp.zeros((8, 128, 3, 128)))
     assert not fa.paged_decode_supports(q, jnp.zeros((8, 128, 4, 96)))
     assert not fa.paged_decode_supports(
@@ -158,17 +158,22 @@ def test_engine_matches_training_model_teacher_forced():
     slot = eng.reserve(len(prompt) + 8)
     tok = eng.prefill(slot, prompt)
     seq = list(prompt)
-    full = np.asarray(tfm.logits_fn(cfg, params,
-                                    jnp.asarray(np.array(seq))[None]))[0]
-    assert tok == int(np.argmax(full[-1]))
+    # One compiled program for every length: the model is causal, so the
+    # logits at the last real position ignore the zero padding after it.
+    logits = jax.jit(lambda t: tfm.logits_fn(cfg, params, t))
+
+    def last_logits(seq):
+        padded = np.zeros((1, len(prompt) + 8), np.int32)
+        padded[0, :len(seq)] = seq
+        return np.asarray(logits(jnp.asarray(padded)))[0, len(seq) - 1]
+
+    assert tok == int(np.argmax(last_logits(seq)))
     seq.append(tok)
     for _ in range(6):
         tokens = np.zeros((eng.slots,), np.int32)
         tokens[slot] = seq[-1]
         nxt = eng.decode_step(tokens)
-        full = np.asarray(tfm.logits_fn(
-            cfg, params, jnp.asarray(np.array(seq))[None]))[0]
-        assert int(nxt[slot]) == int(np.argmax(full[-1]))
+        assert int(nxt[slot]) == int(np.argmax(last_logits(seq)))
         seq.append(int(nxt[slot]))
 
 
@@ -350,6 +355,42 @@ def test_request_larger_than_pool_rejected_not_livelocked():
     by_rid = {r.rid: r for r in done}
     assert "HOROVOD_SERVE_PAGES" in by_rid[0].error
     assert by_rid[1].error is None and len(by_rid[1].tokens) == 4
+
+
+def test_decode_step_advances_lengths_only_after_the_result_is_read():
+    """The dispatch is asynchronous and may alias the host tables it was
+    handed (zero-copy jnp.asarray on the CPU backend, every slot active):
+    the lengths must not move until the step's result has been read
+    back, or the step races its own inputs."""
+    eng, _ = _engine(slots=2)
+    rng = np.random.default_rng(21)
+    tokens = np.zeros((eng.slots,), np.int32)
+    for _ in range(eng.slots):                  # every slot active
+        prompt = rng.integers(0, 256, 9).astype(np.int32)
+        slot = eng.reserve(len(prompt) + 4)
+        tokens[slot] = eng.prefill(slot, prompt)
+    seen = {}
+
+    class Readback:
+        def __init__(self, arr):
+            self.arr = arr
+
+        def __array__(self, dtype=None, copy=None):
+            seen["at_readback"] = eng.tables.lengths.copy()
+            return np.asarray(self.arr)
+
+    real = eng._decode
+
+    def spy(*args):
+        k, v, nxt, logits = real(*args)
+        seen["at_dispatch"] = eng.tables.lengths.copy()
+        return k, v, Readback(nxt), logits
+
+    eng._decode = spy
+    eng.decode_step(tokens)
+    np.testing.assert_array_equal(seen["at_readback"], seen["at_dispatch"])
+    np.testing.assert_array_equal(eng.tables.lengths,
+                                  seen["at_dispatch"] + 1)
 
 
 def test_decode_step_default_mask_protects_mid_prefill_slots():
@@ -781,11 +822,13 @@ def test_spec_eos_and_cap_truncate_accepted_run():
     sched = ServeScheduler(spec_eng, queue_deadline=0.0)
     done = sched.run([Request(rid=0, prompt=prompt, max_new_tokens=3)])
     assert done[0].tokens == seq[:3]
-    # EOS mid-run
+    # EOS mid-run: a token whose first occurrence is not at position 0
+    # (sequential decode stops at the FIRST occurrence of the EOS token)
+    i = next(j for j in range(1, len(seq)) if seq[j] not in seq[:j])
     sched2 = ServeScheduler(spec_eng, queue_deadline=0.0)
     done2 = sched2.run([Request(rid=0, prompt=prompt, max_new_tokens=8,
-                                eos_token=int(seq[2]))])
-    assert done2[0].tokens == seq[:3]
+                                eos_token=int(seq[i]))])
+    assert done2[0].tokens == seq[:i + 1]
 
 
 def test_warm_boot_compile_free_with_spec_and_prefix(tmp_path, monkeypatch):

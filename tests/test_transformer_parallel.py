@@ -34,15 +34,16 @@ def make_batch(seed=0):
 
 def reference_loss_and_grads(cfg_kwargs):
     cfg = tfm.TransformerConfig(dp_axis=None, **cfg_kwargs)
-    params = tfm.init_params(cfg, jax.random.PRNGKey(7))
+    # jitted: one compile each instead of op-by-op eager dispatch
+    params = jax.jit(lambda r: tfm.init_params(cfg, r))(jax.random.PRNGKey(7))
     tokens, labels = make_batch()
-    loss, grads = jax.value_and_grad(
-        lambda p: tfm.loss_fn(cfg, p, tokens, labels))(params)
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: tfm.loss_fn(cfg, p, tokens, labels)))(params)
     return params, loss, grads
 
 
 def sharded_loss_and_grads(cfg, mesh):
-    params = tfm.init_params(cfg, jax.random.PRNGKey(7))
+    params = jax.jit(lambda r: tfm.init_params(cfg, r))(jax.random.PRNGKey(7))
     tokens, labels = make_batch()
     pspecs = tfm.param_specs(cfg)
     bspec = tfm.batch_spec(cfg)
@@ -61,9 +62,7 @@ def sharded_loss_and_grads(cfg, mesh):
 
 
 def assert_grads_close(ref, got, atol=2e-4, rtol=2e-3):
-    # jax.tree.leaves_with_path is absent on jax 0.4.37; the tree_util
-    # spelling is available on every supported version.
-    flat_ref = jax.tree_util.tree_leaves_with_path(ref)
+    flat_ref = jax.tree.leaves_with_path(ref)
     flat_got = jax.tree.leaves(got)
     for (path, r), g in zip(flat_ref, flat_got):
         np.testing.assert_allclose(

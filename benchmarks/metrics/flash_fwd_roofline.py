@@ -1,0 +1,7 @@
+"""hvd_flash_fwd: least time the chip could take for its calls over their device time."""
+from benchmarks.lib import readers
+from benchmarks.roofline import flash_attention
+
+
+def read(run):
+    return readers.flash_roofline(run, "hvd_flash_fwd", flash_attention.forward)

@@ -601,16 +601,20 @@ knobs.register("HOROVOD_FAULT_WORKER_DEADLINE", 10.0, float,
 # attribution, flight recorder — docs/tracing.md).
 knobs.register("HOROVOD_TRACE", False, bool,
                help="Enable the span-based distributed tracer at "
-                    "hvd.init(): trace.span(...) context managers across "
+                    "hvd.init() (or where a ServeEngine is built): "
+                    "trace.span(...) context managers across "
                     "the coordinator cycle, eager handle waits, "
-                    "checkpoint/preemption/elastic/data paths record into "
+                    "checkpoint/preemption/elastic/data paths and the "
+                    "serve loop record into "
                     "a per-process ring buffer, exported as a Perfetto-"
                     "loadable Chrome trace at shutdown (multi-controller "
                     "runs merge every host's spans onto the leader's "
                     "timeline over the jax.distributed KV store). OFF "
                     "(the default) costs nothing on the step path: "
                     "span() returns a shared no-op context manager — no "
-                    "allocation (benchmarked in tests/test_tracing.py).")
+                    "allocation (benchmarked in tests/test_tracing.py) — "
+                    "unless a JAX profiler session is active, which gets "
+                    "each span as an hvd.<name> annotation.")
 knobs.register("HOROVOD_TRACE_BUFFER_SPANS", 16384, int,
                help="Capacity of the tracing ring buffer, in spans. The "
                     "oldest spans fall off at capacity, so a week-long "

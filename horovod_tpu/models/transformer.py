@@ -251,21 +251,27 @@ def _layer(cfg: TransformerConfig, lp: Params, x: jax.Array,
     q, kk, vv = (t.reshape(shp) for t in (q, kk, vv))
     q = _rope(q, pos)
     kk = _rope(kk, pos)
-    if sp and cfg.attention == "ring":
-        o = sp_lib.ring_attention(q, kk, vv, sp, causal=True)
-    elif sp and cfg.attention == "ulysses":
-        o = sp_lib.ulysses_attention(q, kk, vv, sp, causal=True)
-    else:
-        o = sp_lib.local_attention(q, kk, vv, causal=True)
+    # hvd_attention / hvd_mlp / hvd_loss: names on the device side of the
+    # step (HLO metadata op_name; the backward's operations read
+    # transpose(jvp(hvd_attention))). They change nothing computed.
+    with jax.named_scope("hvd_attention"):
+        if sp and cfg.attention == "ring":
+            o = sp_lib.ring_attention(q, kk, vv, sp, causal=True)
+        elif sp and cfg.attention == "ulysses":
+            o = sp_lib.ulysses_attention(q, kk, vv, sp, causal=True)
+        else:
+            o = sp_lib.local_attention(q, kk, vv, causal=True)
     o = o.reshape(x.shape[0], s_local, -1)
     attn_out = tp_lib.row_parallel(o, lp["wo"].astype(dt), cfg.tp_axis)
     x = x + attn_out.astype(x.dtype)
 
     h = _rmsnorm(x, lp["mlp_norm"])
     if cfg.num_experts:
-        mlp_out, metrics = moe_lib.moe_ffn(
-            h, lp["router"], lp["w_in"].astype(dt), lp["w_out"].astype(dt),
-            ep_axis=cfg.ep_axis, capacity_factor=cfg.capacity_factor)
+        with jax.named_scope("hvd_mlp"):
+            mlp_out, metrics = moe_lib.moe_ffn(
+                h, lp["router"], lp["w_in"].astype(dt),
+                lp["w_out"].astype(dt), ep_axis=cfg.ep_axis,
+                capacity_factor=cfg.capacity_factor)
         aux_acc = aux_acc + metrics.aux_loss
     else:
         mlp_fn = _dense_mlp
@@ -283,8 +289,9 @@ def _layer(cfg: TransformerConfig, lp: Params, x: jax.Array,
             mlp_fn = jax.checkpoint(
                 _dense_mlp, static_argnums=(0,),
                 policy=jax.checkpoint_policies.nothing_saveable)
-        mlp_out = mlp_fn(cfg, h, lp["w_in"].astype(dt),
-                         lp["w_out"].astype(dt))
+        with jax.named_scope("hvd_mlp"):
+            mlp_out = mlp_fn(cfg, h, lp["w_in"].astype(dt),
+                             lp["w_out"].astype(dt))
     x = x + mlp_out.astype(x.dtype)
     return x, aux_acc
 
@@ -363,9 +370,10 @@ def loss_fn(cfg: TransformerConfig, params: Params, tokens: jax.Array,
     dp/sp so the returned scalar is identical on every chip.
     """
     x, aux = forward(cfg, params, tokens)
-    per_tok = tp_lib.vocab_parallel_cross_entropy(
-        x, params["head"].astype(cfg.dtype), labels, cfg.tp_axis,
-        block=cfg.ce_block_vocab)
+    with jax.named_scope("hvd_loss"):
+        per_tok = tp_lib.vocab_parallel_cross_entropy(
+            x, params["head"].astype(cfg.dtype), labels, cfg.tp_axis,
+            block=cfg.ce_block_vocab)
     total = jnp.sum(per_tok)
     count = jnp.full((), per_tok.size, jnp.float32)
     data_axes = [a for a in (cfg.dp_axis, cfg.ep_axis, cfg.sp_axis) if a]

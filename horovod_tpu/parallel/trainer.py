@@ -78,7 +78,8 @@ def sync_gradients(grads: Any, sync_axes: Any, world: int) -> Any:
             return buf * inv.astype(buf.dtype) if world != 1 else buf
         return one
 
-    return fused_group_apply(grads, sync_axes, make_fn)
+    with jax.named_scope("hvd_grad_sync"):
+        return fused_group_apply(grads, sync_axes, make_fn)
 
 
 def make_transformer_train_step(
@@ -116,7 +117,8 @@ def make_transformer_train_step(
         # tagged so the bucketed-apply variant's structural test can
         # assert ITS HLO carries no such pass (the update runs in the
         # bucket epilogues instead, make_transformer_train_step_fused).
-        with jax.named_scope("hvd_unfused_apply"):
+        with jax.named_scope("hvd_optimizer"), \
+                jax.named_scope("hvd_unfused_apply"):
             updates, opt_state = optimizer.update(grads, state.opt_state,
                                                   state.params)
             params = optax.apply_updates(state.params, updates)
@@ -521,7 +523,8 @@ def data_parallel_train_step(
     @jit_step
     def train_step(state: TrainState, batch):
         loss, grads = value_and_grads(state.params, batch)
-        with jax.named_scope("hvd_unfused_apply"):
+        with jax.named_scope("hvd_optimizer"), \
+                jax.named_scope("hvd_unfused_apply"):
             updates, opt_state = optimizer.update(grads, state.opt_state,
                                                   state.params)
             params = optax.apply_updates(state.params, updates)

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from horovod_tpu import tracing as trace
 from horovod_tpu.config import knobs
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel import tensor_parallel as tp_lib
@@ -196,14 +197,17 @@ def _decode_body(cfg: tfm.TransformerConfig, params: Any,
         q, k, v = _qkv(cfg, lp, h)                       # [S, Hl, Dh]
         q = _rope_rows(q, lengths)
         k = _rope_rows(k, lengths)
-        kp, vp = kvc.write_token_kv(kp, vp, k, v, block_tables, lengths,
-                                    valid=valid)
-        o = kvc.paged_decode_attention(
-            q, kp, vp, block_tables, lengths + 1, scale)
+        with jax.named_scope("hvd_kv_write"):
+            kp, vp = kvc.write_token_kv(kp, vp, k, v, block_tables,
+                                        lengths, valid=valid)
+        with jax.named_scope("hvd_attention"):
+            o = kvc.paged_decode_attention(
+                q, kp, vp, block_tables, lengths + 1, scale)
         o = o.astype(x.dtype).reshape(x.shape[0], -1)
         x = x + tp_lib.row_parallel(o, lp["wo"].astype(cfg.dtype),
                                     cfg.tp_axis).astype(x.dtype)
-        x = x + _mlp(cfg, lp, x).astype(x.dtype)
+        with jax.named_scope("hvd_mlp"):
+            x = x + _mlp(cfg, lp, x).astype(x.dtype)
         return x, (kp, vp)
 
     (x), (k_new, v_new) = lax.scan(layer, x, (layers, kp_in, vp_in))
@@ -240,23 +244,27 @@ def _prefill_body(cfg: tfm.TransformerConfig, params: Any,
         q, k, v = _qkv(cfg, lp, h)                       # [C, Hl, Dh]
         q = _rope_rows(q, pos)
         k = _rope_rows(k, pos)
-        kp, vp = kvc.write_chunk_kv(kp, vp, k, v, block_table, start,
-                                    n_real)
-        kg = kvc.gather_pages(kp, block_table).astype(jnp.float32)
-        vg = kvc.gather_pages(vp, block_table).astype(jnp.float32)
-        s = jnp.einsum("chd,shd->chs", q.astype(jnp.float32), kg) * scale
-        ctx = jnp.arange(n_ctx, dtype=jnp.int32)
-        visible = ctx[None, :] <= pos[:, None]           # causal + prefix
-        s = jnp.where(visible[:, None, :], s, -jnp.inf)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        m = jnp.where(jnp.isfinite(m), m, 0.0)
-        p = jnp.where(visible[:, None, :], jnp.exp(s - m), 0.0)
-        l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-        o = jnp.einsum("chs,shd->chd", p / l, vg)
+        with jax.named_scope("hvd_kv_write"):
+            kp, vp = kvc.write_chunk_kv(kp, vp, k, v, block_table, start,
+                                        n_real)
+        with jax.named_scope("hvd_attention"):
+            kg = kvc.gather_pages(kp, block_table).astype(jnp.float32)
+            vg = kvc.gather_pages(vp, block_table).astype(jnp.float32)
+            s = jnp.einsum("chd,shd->chs", q.astype(jnp.float32),
+                           kg) * scale
+            ctx = jnp.arange(n_ctx, dtype=jnp.int32)
+            visible = ctx[None, :] <= pos[:, None]       # causal + prefix
+            s = jnp.where(visible[:, None, :], s, -jnp.inf)
+            m = jnp.max(s, axis=-1, keepdims=True)
+            m = jnp.where(jnp.isfinite(m), m, 0.0)
+            p = jnp.where(visible[:, None, :], jnp.exp(s - m), 0.0)
+            l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+            o = jnp.einsum("chs,shd->chd", p / l, vg)
         o = o.astype(x.dtype).reshape(c, -1)
         x = x + tp_lib.row_parallel(o, lp["wo"].astype(cfg.dtype),
                                     cfg.tp_axis).astype(x.dtype)
-        x = x + _mlp(cfg, lp, x).astype(x.dtype)
+        with jax.named_scope("hvd_mlp"):
+            x = x + _mlp(cfg, lp, x).astype(x.dtype)
         return x, (kp, vp)
 
     x, (k_new, v_new) = lax.scan(
@@ -271,6 +279,18 @@ def _prefill_body(cfg: tfm.TransformerConfig, params: Any,
 # ---------------------------------------------------------------------------
 # engine
 # ---------------------------------------------------------------------------
+
+def _named_jit(fn: Callable, name: str, donate: Tuple[int, ...]):
+    """``jax.jit`` of a step function under a name of its own: the
+    compiled module, and so the ``XLA Modules`` line of a device trace,
+    reads ``jit_<name>`` where a ``functools.partial`` or a ``shard_map``
+    wrapper would read ``jit__unknown`` — what a trace reader tells the
+    engine's programs apart by."""
+    def named(*args):
+        return fn(*args)
+    named.__name__ = named.__qualname__ = name
+    return jax.jit(named, donate_argnums=donate)
+
 
 class ServeEngine:
     """Paged-cache inference engine over a (possibly TP-sharded) mesh.
@@ -293,6 +313,9 @@ class ServeEngine:
                  draft: Optional[str] = None,
                  spec_k: Optional[int] = None):
         _check_cfg(cfg)
+        # a replica need not go through hvd.init(): HOROVOD_TRACE=1 turns
+        # the recorder on here too (a no-op when it is on already)
+        trace.init_from_env()
         self.cfg = cfg
         self.mesh = mesh
         self.slots = int(slots or knobs.get("HOROVOD_SERVE_SLOTS"))
@@ -399,11 +422,12 @@ class ServeEngine:
                 cow_fn, mesh,
                 in_specs=(kv_spec, kv_spec, rep, rep),
                 out_specs=(kv_spec, kv_spec))
-        self._decode_jit = jax.jit(decode_fn, donate_argnums=(1, 2))
-        self._prefill_jit = jax.jit(prefill_fn, donate_argnums=(1, 2))
-        self._draft_jit = (jax.jit(draft_fn, donate_argnums=(1, 2))
+        self._decode_jit = _named_jit(decode_fn, "hvd_serve_decode", (1, 2))
+        self._prefill_jit = _named_jit(prefill_fn, "hvd_serve_prefill",
+                                       (1, 2))
+        self._draft_jit = (_named_jit(draft_fn, "hvd_serve_draft", (1, 2))
                            if draft_fn is not None else None)
-        self._cow_jit = jax.jit(cow_fn, donate_argnums=(0, 1))
+        self._cow_jit = _named_jit(cow_fn, "hvd_serve_cow", (0, 1))
 
         # AOT build (store-served): one decode executable + one prefill
         # executable per bucket — plus, when the knobs switch them on,
@@ -597,16 +621,22 @@ class ServeEngine:
                 f"prompt of {prompt.size} tokens exceeds the serving "
                 f"context ceiling {self.max_seq} "
                 f"({self.ceiling_hint})")
-        bt_row = jnp.asarray(self.tables.tables[slot])
         n_real = min(prompt.size - start,
                      self.bucket_for(prompt.size - start))
         bucket = self.bucket_for(n_real)
-        chunk = np.zeros((bucket,), np.int32)
-        chunk[:n_real] = prompt[start:start + n_real]
-        self.k_pages, self.v_pages, tok, _ = self._prefill[bucket](
-            self.params, self.k_pages, self.v_pages, bt_row,
-            jnp.asarray(start, jnp.int32),
-            jnp.asarray(n_real, jnp.int32), jnp.asarray(chunk))
+        # dispatch returns before the device is done; the wait is the
+        # readback of a prompt's last chunk, below
+        with trace.span(
+                "engine.prefill.dispatch", cat=trace.CAT_SERVE,
+                attrs=({"slot": slot, "start": start, "tokens": n_real,
+                        "bucket": bucket} if trace.enabled() else None)):
+            bt_row = jnp.asarray(self.tables.tables[slot])
+            chunk = np.zeros((bucket,), np.int32)
+            chunk[:n_real] = prompt[start:start + n_real]
+            self.k_pages, self.v_pages, tok, _ = self._prefill[bucket](
+                self.params, self.k_pages, self.v_pages, bt_row,
+                jnp.asarray(start, jnp.int32),
+                jnp.asarray(n_real, jnp.int32), jnp.asarray(chunk))
         start += n_real
         if start < prompt.size:
             return start, None
@@ -616,7 +646,11 @@ class ServeEngine:
             # the next matching prompt adopts these pages (the index
             # takes its own ref — the pages outlive this request)
             self.prefix.register(prompt, self.slot_pages[slot] or [])
-        return start, int(tok)
+        with trace.span(
+                "engine.prefill.wait", cat=trace.CAT_SERVE,
+                attrs={"slot": slot} if trace.enabled() else None):
+            first = int(tok)
+        return start, first
 
     def prefill(self, slot: int, prompt: np.ndarray) -> int:
         """Run the whole prompt through prefill chunks back-to-back;
@@ -643,22 +677,27 @@ class ServeEngine:
             # the default excludes them too, not just empty slots.
             active = (np.array([p is not None for p in self.slot_pages])
                       & (self.tables.lengths > 0))
-        bt_np = self.tables.tables
-        ln_np = self.tables.lengths
-        if not active.all():
-            bt_np = bt_np.copy()
-            ln_np = ln_np.copy()
-            bt_np[~active] = self.pool.scratch_page
-            ln_np[~active] = 0
-        self.k_pages, self.v_pages, nxt, _ = self._decode(
-            self.params, self.k_pages, self.v_pages,
-            jnp.asarray(bt_np), jnp.asarray(ln_np),
-            jnp.asarray(np.asarray(tokens, np.int32)))
+        with trace.span(
+                "engine.decode.dispatch", cat=trace.CAT_SERVE,
+                attrs=({"active": int(active.sum())} if trace.enabled()
+                       else None)):
+            bt_np = self.tables.tables
+            ln_np = self.tables.lengths
+            if not active.all():
+                bt_np = bt_np.copy()
+                ln_np = ln_np.copy()
+                bt_np[~active] = self.pool.scratch_page
+                ln_np[~active] = 0
+            self.k_pages, self.v_pages, nxt, _ = self._decode(
+                self.params, self.k_pages, self.v_pages,
+                jnp.asarray(bt_np), jnp.asarray(ln_np),
+                jnp.asarray(np.asarray(tokens, np.int32)))
         # Read the result back BEFORE touching the host tables: the
         # dispatch is asynchronous and jnp.asarray may alias the NumPy
         # buffers it was given (zero-copy on the CPU backend), so
         # advancing the lengths first races the step that is reading them.
-        nxt = np.asarray(nxt)
+        with trace.span("engine.decode.wait", cat=trace.CAT_SERVE):
+            nxt = np.asarray(nxt)
         self.tables.lengths[active] += 1
         return nxt
 
@@ -684,11 +723,13 @@ class ServeEngine:
         toks = np.asarray(tokens, np.int32).copy()
         toks[~active] = 0
         for i in range(k):
-            self.k_pages, self.v_pages, nxt, _ = self._draft(
-                self.params, self.k_pages, self.v_pages,
-                jnp.asarray(bt_np), jnp.asarray(ln_np),
-                jnp.asarray(toks))
-            nxt = np.asarray(nxt)
+            with trace.span("engine.draft.dispatch", cat=trace.CAT_SERVE):
+                self.k_pages, self.v_pages, nxt, _ = self._draft(
+                    self.params, self.k_pages, self.v_pages,
+                    jnp.asarray(bt_np), jnp.asarray(ln_np),
+                    jnp.asarray(toks))
+            with trace.span("engine.draft.wait", cat=trace.CAT_SERVE):
+                nxt = np.asarray(nxt)
             drafts[:, i] = nxt
             toks = np.where(active, nxt, 0).astype(np.int32)
             ln_np = ln_np + active.astype(np.int32)
@@ -716,24 +757,29 @@ class ServeEngine:
         if active is None:
             active = (np.array([p is not None for p in self.slot_pages])
                       & (self.tables.lengths > 0))
-        rows = self.slots * (k + 1)
-        bt = np.repeat(self.tables.tables, k + 1, axis=0)
-        ln = (np.repeat(self.tables.lengths, k + 1)
-              + np.tile(np.arange(k + 1, dtype=np.int32), self.slots))
-        toks = np.concatenate(
-            [np.asarray(tokens, np.int32).reshape(-1, 1),
-             np.asarray(drafts, np.int32).reshape(self.slots, k)],
-            axis=1).reshape(rows)
-        row_active = np.repeat(active, k + 1)
-        bt[~row_active] = self.pool.scratch_page
-        ln[~row_active] = 0
-        toks[~row_active] = 0
-        self.k_pages, self.v_pages, nxt, _ = self._verify(
-            self.params, self.k_pages, self.v_pages,
-            jnp.asarray(bt), jnp.asarray(ln.astype(np.int32)),
-            jnp.asarray(toks))
+        with trace.span(
+                "engine.verify.dispatch", cat=trace.CAT_SERVE,
+                attrs=({"active": int(active.sum()), "k": k}
+                       if trace.enabled() else None)):
+            rows = self.slots * (k + 1)
+            bt = np.repeat(self.tables.tables, k + 1, axis=0)
+            ln = (np.repeat(self.tables.lengths, k + 1)
+                  + np.tile(np.arange(k + 1, dtype=np.int32), self.slots))
+            toks = np.concatenate(
+                [np.asarray(tokens, np.int32).reshape(-1, 1),
+                 np.asarray(drafts, np.int32).reshape(self.slots, k)],
+                axis=1).reshape(rows)
+            row_active = np.repeat(active, k + 1)
+            bt[~row_active] = self.pool.scratch_page
+            ln[~row_active] = 0
+            toks[~row_active] = 0
+            self.k_pages, self.v_pages, nxt, _ = self._verify(
+                self.params, self.k_pages, self.v_pages,
+                jnp.asarray(bt), jnp.asarray(ln.astype(np.int32)),
+                jnp.asarray(toks))
         self.tables.lengths[active] += k + 1
-        return np.asarray(nxt).reshape(self.slots, k + 1)
+        with trace.span("engine.verify.wait", cat=trace.CAT_SERVE):
+            return np.asarray(nxt).reshape(self.slots, k + 1)
 
     def rollback(self, slot: int, n_rejected: int) -> None:
         """Accept-prefix rollback: drop the rejected speculative suffix

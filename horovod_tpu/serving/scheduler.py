@@ -46,10 +46,11 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Any, Deque, Dict, List, Optional, Sequence
+from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from horovod_tpu import tracing as trace
 from horovod_tpu.config import knobs
 from horovod_tpu.serving.engine import ServeEngine
 from horovod_tpu.utils.logging import get_logger
@@ -70,6 +71,8 @@ class Request:
     eos_token: Optional[int] = None
     arrival: Optional[float] = None
     # -- filled by the scheduler --
+    admitted_at: Optional[float] = None     # left the queue for a slot
+    cached_tokens: int = 0                  # prompt tokens the prefix index held
     tokens: List[int] = dataclasses.field(default_factory=list)
     ttft: Optional[float] = None            # arrival -> first token
     tpot: List[float] = dataclasses.field(default_factory=list)
@@ -82,6 +85,12 @@ class Request:
     @property
     def done(self) -> bool:
         return self.finished_at is not None
+
+
+def _span_attrs() -> Optional[Dict[str, Any]]:
+    """A dict for a span's counts, filled before the span closes — or
+    None while the recorder is off, so the off path builds nothing."""
+    return {} if trace.enabled() else None
 
 
 def _metrics():
@@ -112,6 +121,35 @@ def _metrics():
             "Time per output token during decode (inter-token "
             "interval)", buckets=M.LATENCY_BUCKETS),
     }
+
+
+def _record_request(req: "Request") -> None:
+    """A retired request's life as spans, from the timestamps it holds:
+    ``serve.request`` (arrival -> finish) and under it
+    ``serve.request.queued`` (arrival -> admitted),
+    ``serve.request.prefill`` (admitted -> first token) and
+    ``serve.request.decode`` (first token -> finish). All carry the same
+    attrs, ``rid`` among them; a rejected request has the first two
+    stamps only, so it gets the parent alone. Ring only: a span that is
+    already over cannot be put on the profiler's clock."""
+    if req.arrival is None or req.finished_at is None:
+        return
+    attrs = {"rid": req.rid, "prompt_tokens": int(req.prompt.size),
+             "cached_tokens": req.cached_tokens,
+             "output_tokens": len(req.tokens), "slot": req.slot}
+    if req.error is not None:
+        attrs["error"] = req.error
+    first = req.arrival + req.ttft if req.ttft is not None else None
+    parent = trace.record_interval(
+        "serve.request", trace.CAT_SERVE, req.arrival, req.finished_at,
+        attrs=attrs)
+    for name, t0, t1 in (("queued", req.arrival, req.admitted_at),
+                         ("prefill", req.admitted_at, first),
+                         ("decode", first, req.finished_at)):
+        if t0 is not None and t1 is not None:
+            trace.record_interval(
+                "serve.request." + name, trace.CAT_SERVE, t0, t1,
+                attrs=attrs, parent_id=parent)
 
 
 class NGramDrafter:
@@ -166,6 +204,7 @@ class ServeScheduler:
         self.completed: List[Request] = []
         self._m = _metrics()
         self._decode_steps = 0
+        self._cycles = 0
         self._occ_sum = 0.0
         self.queue_peak = 0
         # hvdspec tallies (prefix-hit-rate / acceptance-rate sweeps)
@@ -191,19 +230,38 @@ class ServeScheduler:
 
     # -- scheduling points ---------------------------------------------------
     def _retire(self, now: float) -> None:
-        for slot, req in list(self.active.items()):
-            hit_eos = (req.eos_token is not None and req.tokens
-                       and req.tokens[-1] == req.eos_token)
-            if len(req.tokens) >= req.max_new_tokens or hit_eos:
-                req.finished_at = now
-                self.engine.release(slot)       # eviction-on-finish
-                del self.active[slot]
-                self.completed.append(req)
-                self._m["requests"].labels(event="completed").inc()
+        attrs = _span_attrs()
+        with trace.span("serve.retire", cat=trace.CAT_SERVE, attrs=attrs):
+            retired = 0
+            for slot, req in list(self.active.items()):
+                hit_eos = (req.eos_token is not None and req.tokens
+                           and req.tokens[-1] == req.eos_token)
+                if len(req.tokens) >= req.max_new_tokens or hit_eos:
+                    req.finished_at = now
+                    self.engine.release(slot)   # eviction-on-finish
+                    del self.active[slot]
+                    self.completed.append(req)
+                    self._m["requests"].labels(event="completed").inc()
+                    retired += 1
+                    if attrs is not None:
+                        _record_request(req)
+            if attrs is not None:
+                attrs["retired"] = retired
 
     def _admit(self, now: float) -> None:
         if self.mode == "static" and (self.active or self.prefilling):
             return                  # static baseline: whole-batch cycles
+        attrs = _span_attrs()
+        with trace.span("serve.admit", cat=trace.CAT_SERVE, attrs=attrs):
+            admitted, rejected = self._admit_queue(now)
+            if attrs is not None:
+                attrs.update(admitted=admitted, rejected=rejected,
+                             queued=len(self.queue))
+
+    def _admit_queue(self, now: float) -> Tuple[int, int]:
+        """Admit from the head of the queue while a slot and the pages
+        for the worst case are free; returns (admitted, rejected)."""
+        admitted = rejected = 0
         while self.queue:
             req = self.queue[0]
             reject = None
@@ -240,6 +298,9 @@ class ServeScheduler:
                 self.completed.append(req)
                 self._m["requests"].labels(event="rejected").inc()
                 self._m["queue"].set(len(self.queue))
+                rejected += 1
+                if trace.enabled():
+                    _record_request(req)
                 continue
             slot = self.engine.reserve(worst, prompt=req.prompt)
             if slot is None:
@@ -247,41 +308,61 @@ class ServeScheduler:
             self.queue.popleft()
             self._m["queue"].set(len(self.queue))
             req.slot = slot
+            req.admitted_at = now
             # shared-prefix reuse: tokens the prefix index already
             # covers are skipped — prefill starts at the divergence
             req._prefill_pos = int(self.engine.slot_skip[slot])
+            req.cached_tokens = req._prefill_pos
             self.prompt_tokens += int(req.prompt.size)
             self.cached_tokens += req._prefill_pos
             self.prefilling[slot] = req
             self._m["requests"].labels(event="admitted").inc()
+            admitted += 1
+        return admitted, rejected
 
     def _prefill_cycle(self) -> None:
         """Advance every admitted-but-unprefilled request by exactly ONE
         chunk — the chunked-prefill interleave: a decode step runs
         between consecutive chunks, so in-flight TPOT stalls at most one
         chunk at a time, never a whole long prompt."""
-        for slot, req in list(self.prefilling.items()):
-            old = req._prefill_pos
-            pos, first = self.engine.prefill_chunk(slot, req.prompt, old)
-            req._prefill_pos = pos
-            self._m["tokens"].labels(kind="prefill").inc(pos - old)
-            if first is None:
-                continue
-            del self.prefilling[slot]
-            req.tokens.append(first)
-            t = time.perf_counter()
-            req.ttft = t - req.arrival if req.arrival is not None else 0.0
-            req._last_token_t = t
-            self.active[slot] = req
-            self._m["ttft"].observe(max(req.ttft, 0.0))
-            self._m["tokens"].labels(kind="decode").inc()
+        if not self.prefilling:
+            return
+        attrs = _span_attrs()
+        with trace.span("serve.prefill", cat=trace.CAT_SERVE, attrs=attrs):
+            chunks = prefilled = 0
+            for slot, req in list(self.prefilling.items()):
+                old = req._prefill_pos
+                pos, first = self.engine.prefill_chunk(slot, req.prompt,
+                                                       old)
+                req._prefill_pos = pos
+                chunks += 1
+                prefilled += pos - old
+                self._m["tokens"].labels(kind="prefill").inc(pos - old)
+                if first is None:
+                    continue
+                del self.prefilling[slot]
+                req.tokens.append(first)
+                t = time.perf_counter()
+                req.ttft = (t - req.arrival if req.arrival is not None
+                            else 0.0)
+                req._last_token_t = t
+                self.active[slot] = req
+                self._m["ttft"].observe(max(req.ttft, 0.0))
+                self._m["tokens"].labels(kind="decode").inc()
+            if attrs is not None:
+                attrs.update(chunks=chunks, prompt_tokens=prefilled)
 
     def _decode(self) -> None:
         if not self.active:
             return
-        if self._spec:
-            self._decode_spec()
-            return
+        attrs = {"active": len(self.active)} if trace.enabled() else None
+        with trace.span("serve.decode", cat=trace.CAT_SERVE, attrs=attrs):
+            if self._spec:
+                self._decode_spec(attrs)
+            else:
+                self._decode_plain(attrs)
+
+    def _decode_plain(self, attrs: Optional[Dict[str, Any]]) -> None:
         tokens = np.zeros((self.engine.slots,), np.int32)
         active = np.zeros((self.engine.slots,), bool)
         for slot, req in self.active.items():
@@ -300,8 +381,10 @@ class ServeScheduler:
             req._last_token_t = t
             self._m["tpot"].observe(dt)
             self._m["tokens"].labels(kind="decode").inc()
+        if attrs is not None:
+            attrs["tokens"] = len(self.active)
 
-    def _decode_spec(self) -> None:
+    def _decode_spec(self, attrs: Optional[Dict[str, Any]]) -> None:
         """Draft-then-verify decode point. Accept-prefix per slot:
         draft i is confirmed while it equals the verify step's own
         emission one position back, so the appended run is bitwise the
@@ -329,6 +412,7 @@ class ServeScheduler:
         occ = eng.occupancy()
         self._occ_sum += occ
         self._m["occupancy"].set(occ)
+        appended = accepted_drafts = 0
         for slot, req in self.active.items():
             g = 0
             while g < k and int(drafts[slot, g]) == int(out[slot, g]):
@@ -348,6 +432,11 @@ class ServeScheduler:
             req._last_token_t = t
             self._m["tpot"].observe(dt / n_new)
             self._m["tokens"].labels(kind="decode").inc(n_new)
+            appended += n_new
+            accepted_drafts += g
+        if attrs is not None:
+            attrs.update(tokens=appended, proposed=k * len(self.active),
+                         accepted=accepted_drafts)
 
     def step(self, now: Optional[float] = None) -> None:
         """One scheduling cycle: retire -> admit -> one prefill chunk
@@ -356,12 +445,18 @@ class ServeScheduler:
         already met by its PREFILL token must not decode one token past
         it."""
         now = time.perf_counter() if now is None else now
-        self._retire(now)
-        self._admit(now)
-        self._prefill_cycle()
-        self._retire(time.perf_counter())
-        self._decode()
-        self._retire(time.perf_counter())
+        self._cycles += 1
+        attrs = ({"cycle": self._cycles, "queued": len(self.queue),
+                  "prefilling": len(self.prefilling),
+                  "active": len(self.active)}
+                 if trace.enabled() else None)
+        with trace.span("serve.cycle", cat=trace.CAT_SERVE, attrs=attrs):
+            self._retire(now)
+            self._admit(now)
+            self._prefill_cycle()
+            self._retire(time.perf_counter())
+            self._decode()
+            self._retire(time.perf_counter())
 
     def run(self, traffic=None) -> List[Request]:
         """Drive cycles until ``traffic`` is exhausted and every request

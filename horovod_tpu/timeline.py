@@ -8,9 +8,10 @@ and ``horovod_start/stop_timeline`` (operations.cc:1073-1105).
 
 TPU translation: host-side phases (queue, fusion planning, dispatch, handle
 wait) are recorded here in the same Chrome trace format; device-side spans
-come from XLA via ``jax.profiler`` — every span is mirrored as a
-``jax.profiler.TraceAnnotation`` so the xplane trace and this host trace
-align by name. A dedicated writer thread drains a queue, as in the reference.
+come from XLA via ``jax.profiler`` — every span is also an ``hvd.<name>``
+annotation on the profiler's host plane (written by tracing/spans.py) so
+the xplane trace and this host trace align by name. A dedicated writer
+thread drains a queue, as in the reference.
 
 Rebuilt on the tracing subsystem (horovod_tpu/tracing/): timeline events
 mirror into the span ring buffer by default, so Horovod-style
@@ -264,24 +265,23 @@ class Timeline:
         coordinator's solo dispatch reaches the eager sync path, whose
         own DISPATCH span would otherwise double-represent the interval
         the coordinator already declared natively spanned."""
-        import jax
         t0 = self._now_us()
         if self._native is not None:
             self.begin(name, phase, tid, mirror=False)
         mirror_here = mirror and not getattr(_mirror_tls, "suppress", 0)
-        sp = _spans().span(name, cat=phase) if mirror_here else None
-        if sp is not None:
-            sp.__enter__()
+        # one naming on the profiler's host plane ("hvd.<name>"), written
+        # by the recorder: the mirrored span carries it; a natively
+        # covered interval gets the annotation alone
+        sp = (_spans().span(name, cat=phase) if mirror_here
+              else _spans().annotation(name))
         if not mirror:
             _mirror_tls.suppress = getattr(_mirror_tls, "suppress", 0) + 1
         try:
-            with jax.profiler.TraceAnnotation(f"hvd:{phase}:{name}"):
+            with sp:
                 yield
         finally:
             if not mirror:
                 _mirror_tls.suppress -= 1
-            if sp is not None:
-                sp.__exit__(None, None, None)
             if self._native is not None:
                 self.end(name, phase, tid, mirror=False)
             else:

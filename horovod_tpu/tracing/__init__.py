@@ -36,6 +36,7 @@ from horovod_tpu.tracing.spans import (  # noqa: F401
     CAT_DATA,
     CAT_ELASTIC,
     CAT_PREEMPTION,
+    CAT_SERVE,
     CAT_TIMELINE,
     CAT_TRAIN,
     CAT_WAIT,
@@ -50,6 +51,7 @@ from horovod_tpu.tracing.spans import (  # noqa: F401
     init_from_env,
     instant,
     record,
+    record_interval,
     reset,
     snapshot,
     span,

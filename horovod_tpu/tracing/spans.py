@@ -8,12 +8,21 @@ and the id of the span that was open on the same thread when it started
 (parent links — the causal chain negotiate → fuse → dispatch → wait is a
 tree, not a flat list).
 
-The OFF path is the contract: ``span()`` with ``HOROVOD_TRACE=0`` returns
-a module-level no-op context-manager singleton — no object, dict, or
-tuple is allocated, and the only cost is one attribute read and one
-``is-falsy`` branch (benchmarked in tests/test_tracing.py). Call sites on
-per-entry hot paths should guard attribute-dict construction with
-``enabled()``.
+Two sinks, one recorder. A recorded span is also a
+``jax.profiler.TraceAnnotation`` named ``"hvd." + name``, so it lands on
+the host plane of any profiler trace on the profiler's clock, beside the
+device's ``XLA Ops``. With the recorder off but a JAX profiler session
+active, ``span()`` hands back that bare annotation: no ring, no state,
+nothing exported at shutdown. This module is the one place that talks
+to the profiler.
+
+The OFF path is the contract: ``span()`` with ``HOROVOD_TRACE=0`` and no
+profiler session returns a module-level no-op context-manager singleton
+— no object, dict, or tuple is allocated, and the only cost is one
+attribute read, one ``is-falsy`` branch and one call of the profiler's
+static ``is_enabled()`` (benchmarked in tests/test_tracing.py). Call
+sites on per-entry hot paths should guard attribute-dict construction
+with ``enabled()``.
 
 Timestamps are ``time.perf_counter()`` microseconds relative to a
 process epoch captured at ``enable()``; the epoch's wall-clock value
@@ -32,6 +41,8 @@ import time
 from collections import Counter, deque
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from horovod_tpu.config import knobs
 from horovod_tpu.utils.logging import get_logger
 
@@ -47,6 +58,11 @@ CAT_ELASTIC = "elastic"
 CAT_DATA = "data"
 CAT_TRAIN = "train"
 CAT_TIMELINE = "timeline"
+CAT_SERVE = "serve"
+
+# Prefix of every span's name on the profiler's host plane.
+PROFILER_PREFIX = "hvd."
+_profiling = TraceAnnotation.is_enabled
 
 
 class _State:
@@ -133,7 +149,8 @@ def reset() -> None:
 
 
 def init_from_env() -> None:
-    """HOROVOD_TRACE=1 enables the recorder at hvd.init(). HVD_TRACE_ID
+    """HOROVOD_TRACE=1 enables the recorder at hvd.init(), and where a
+    ServeEngine is built in a process that never calls it. HVD_TRACE_ID
     (minted by `hvdrun --trace`) joins every host's spans into one
     logical trace."""
     if knobs.get("HOROVOD_TRACE"):
@@ -166,9 +183,10 @@ _NOOP = _NoopSpan()
 
 class _Span:
     """A live span: records (start, duration, parent) into the ring
-    buffer at exit. Allocated only when tracing is enabled."""
+    buffer at exit, and brackets the same interval on the profiler's
+    host plane. Allocated only when tracing is enabled."""
 
-    __slots__ = ("name", "cat", "attrs", "_t0", "_id", "_parent")
+    __slots__ = ("name", "cat", "attrs", "_t0", "_id", "_parent", "_ann")
 
     def __init__(self, name: str, cat: str, attrs: Optional[Dict]):
         self.name = name
@@ -176,6 +194,8 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self):
+        self._ann = annotation(self.name)
+        self._ann.__enter__()
         self._t0 = _now_us()
         self._id = next(_span_ids)
         self._parent = getattr(_tls, "span_id", 0)
@@ -190,39 +210,68 @@ class _Span:
         _state.open_spans.pop(self._id, None)
         record(self.name, self.cat, self._t0, _now_us() - self._t0,
                attrs=self.attrs, span_id=self._id, parent_id=self._parent)
+        self._ann.__exit__(exc_type, exc, tb)
         return False
+
+
+def annotation(name: str):
+    """``"hvd." + name`` on the profiler's host plane while a JAX
+    profiler session is active, the shared no-op otherwise. For an
+    interval that is to show in a device trace and not in the ring (the
+    timeline's natively covered spans); everything else uses
+    :func:`span`."""
+    if not _profiling():
+        return _NOOP
+    return TraceAnnotation(PROFILER_PREFIX + name)
 
 
 def span(name: str, cat: str = "runtime",
          attrs: Optional[Dict] = None):
     """``with trace.span("coordinator.cycle", cat=..., attrs={...}):`` —
-    the instrumentation primitive. Returns the shared no-op when tracing
-    is off (zero allocation; see module docstring). NEVER use inside a
-    jit/pjit/shard_map-traced body — it would measure trace time, not
-    run time (hvdlint HVD206); label device ops with ``jax.named_scope``
-    there instead."""
-    if not _state.enabled:
-        return _NOOP
-    return _Span(name, cat, attrs)
+    the instrumentation primitive. Recorder on: ring buffer + profiler
+    annotation. Recorder off but a JAX profiler session active: the
+    bare annotation (so any ``jax.profiler`` trace holds the program's
+    spans with no knob set). Neither: the shared no-op (zero allocation;
+    see module docstring). NEVER use inside a jit/pjit/shard_map-traced
+    body — it would measure trace time, not run time (hvdlint HVD206);
+    label device ops with ``jax.named_scope`` there instead."""
+    if _state.enabled:
+        return _Span(name, cat, attrs)
+    return annotation(name)
 
 
 def record(name: str, cat: str, start_us: float, dur_us: float,
            attrs: Optional[Dict] = None, span_id: Optional[int] = None,
-           parent_id: int = 0, tid: Optional[int] = None) -> None:
+           parent_id: int = 0, tid: Optional[int] = None) -> int:
     """Append one completed span (used by _Span and by adapters that
-    already measured elsewhere — e.g. the timeline mirror)."""
+    already measured elsewhere — e.g. the timeline mirror). Returns the
+    span's id (0 while the recorder is off), for children recorded the
+    same way to name as their parent."""
     if not _state.enabled:
-        return
+        return 0
     buf = _state.buffer
     if len(buf) >= _state.capacity:
         # maxlen discards the oldest silently; count it so summary()'s
         # `dropped` is honest (racy += may undercount — diagnostic only).
         _state.dropped += 1
+    sid = span_id if span_id is not None else next(_span_ids)
     buf.append((
         name, cat, float(start_us), float(dur_us),
         tid if tid is not None else threading.get_ident(),
-        span_id if span_id is not None else next(_span_ids),
-        parent_id, attrs or None))
+        sid, parent_id, attrs or None))
+    return sid
+
+
+def record_interval(name: str, cat: str, t0: float, t1: float,
+                    attrs: Optional[Dict] = None,
+                    parent_id: int = 0) -> int:
+    """Append a span for an interval that is already over, given as two
+    ``time.perf_counter()`` readings (a request's arrival and finish);
+    returns its id like :func:`record`. Ring only: the profiler takes
+    no span after the fact."""
+    return record(name, cat, (t0 - _state.epoch_perf) * 1e6,
+                  max(t1 - t0, 0.0) * 1e6, attrs=attrs,
+                  parent_id=parent_id)
 
 
 def instant(name: str, cat: str = "runtime",

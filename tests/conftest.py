@@ -55,3 +55,10 @@ def _clean_state():
         hvd.shutdown()
     from horovod_tpu.stall_inspector import get_stall_inspector
     get_stall_inspector().reset()
+    # Knob overrides are process-wide: an autotune ParameterManager that
+    # applied its dims leaves HOROVOD_TORUS_ALLREDUCE & co. overridden,
+    # and the next test FILE on the same xdist worker then reads them
+    # (test_process_sets' torus test failed after test_dcn_tier or
+    # test_faults, depending on how the files fell to the workers).
+    from horovod_tpu.config import knobs
+    knobs.clear_all_overrides()

@@ -377,7 +377,12 @@ def _serve_once(cfg, params, mesh, sizes: Sizes, *, warm: bool,
     boot_s = time.perf_counter() - t0
     st = engine.stats()
     outcomes = set(st["store_outcomes"].values())
-    if warm:
+    if not engine.reload_keeps_layout:
+        # A reloaded program would hand the KV pool back re-laid
+        # (docs/serving.md, "Pool layout"): every boot compiles in
+        # process, and the second boot is held to the first's tokens only.
+        assert st["builds"] > 0 and outcomes == {"unsupported"}, st
+    elif warm:
         assert st["builds"] == 0 and outcomes == {"hit"}, (
             f"warm boot compiled: {st['builds']} builds, "
             f"{st['store_outcomes']}")

@@ -29,6 +29,7 @@ pipeline parallelism are training-side concerns and are rejected here.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -37,13 +38,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.experimental.layout import Format
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
 
 from horovod_tpu import tracing as trace
 from horovod_tpu.config import knobs
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel import tensor_parallel as tp_lib
 from horovod_tpu.serving import kv_cache as kvc
+from horovod_tpu.utils import compile_cache
 from horovod_tpu.utils.logging import get_logger
 
 logger = get_logger("horovod_tpu.serving")
@@ -151,6 +155,26 @@ def _gather_logits(cfg, x, head):
     return logits
 
 
+def _flat_pool(k_pages: jax.Array, v_pages: jax.Array):
+    """The 5-D pool ``[L, P+1, page, KVH, D]`` as one run of pages
+    ``[L*(P+1), page, KVH, D]`` (a bitcast in the pool's row-major
+    layout) and the stride ``P+1`` between layers: layer ``l``'s page
+    ``p`` is flat page ``l*stride + p``, its scratch page
+    ``l*stride + stride - 1``. The step bodies carry this whole through
+    the layer scan and offset the block tables, so no instruction
+    slices a layer's pool out or stacks it back."""
+    stride = k_pages.shape[1]
+    flat = (-1,) + k_pages.shape[2:]
+    return k_pages.reshape(flat), v_pages.reshape(flat), stride
+
+
+def _with_index(layers: Any):
+    """Scan operand: the stacked layer parameters beside each layer's
+    index (the pool offset is computed from it)."""
+    n = jax.tree.leaves(layers)[0].shape[0]
+    return layers, jnp.arange(n, dtype=jnp.int32)
+
+
 def _decode_body(cfg: tfm.TransformerConfig, params: Any,
                  k_pages: jax.Array, v_pages: jax.Array,
                  block_tables: jax.Array, lengths: jax.Array,
@@ -171,53 +195,54 @@ def _decode_body(cfg: tfm.TransformerConfig, params: Any,
 
     ``n_layers`` (static) truncates the stack: layers ``0..n-1`` of
     the target plus the shared final norm/head — the self-drafting
-    model of the ``truncate:N`` speculative mode. Its K/V writes land
-    in the shared pool; verify recomputes those layers' identical
-    values over the same positions and overwrites them, so no reader
-    ever observes a draft-only value."""
+    model of the ``truncate:N`` speculative mode. It scans fewer layers
+    over the same pool, so layers ``>= n`` are not touched. Its K/V
+    writes land in the shared pool; verify recomputes those layers'
+    identical values over the same positions and overwrites them, so no
+    reader ever observes a draft-only value."""
     scale = cfg.head_dim ** -0.5
     x = tp_lib.vocab_parallel_embed(
         tokens, params["embed"].astype(cfg.dtype), cfg.tp_axis)   # [S, D]
     layers = params["layers"]
-    kp_in, vp_in = k_pages, v_pages
     if n_layers is not None:
         layers = jax.tree.map(lambda a: a[:n_layers], layers)
-        kp_in, vp_in = k_pages[:n_layers], v_pages[:n_layers]
     # Speculative rows near the context ceiling can carry positions past
     # the last block-table column; the gather would clamp them INTO the
     # request's own last page and corrupt it. Route them to scratch —
     # accepted lengths never reach them, so the value is never read.
     n_ctx = block_tables.shape[1] * k_pages.shape[2]
     valid = lengths < n_ctx
+    kp, vp, stride = _flat_pool(k_pages, v_pages)
 
     def layer(carry, xs):
-        x = carry
-        lp, kp, vp = xs
+        x, kp, vp = carry
+        lp, li = xs
+        base = li * stride
+        bt = block_tables + base
         h = tfm._rmsnorm(x, lp["attn_norm"])
         q, k, v = _qkv(cfg, lp, h)                       # [S, Hl, Dh]
         q = _rope_rows(q, lengths)
         k = _rope_rows(k, lengths)
         with jax.named_scope("hvd_kv_write"):
-            kp, vp = kvc.write_token_kv(kp, vp, k, v, block_tables,
-                                        lengths, valid=valid)
+            kp, vp = kvc.write_token_kv(
+                kp, vp, k, v, bt, lengths, valid=valid,
+                scratch=base + stride - 1)
         with jax.named_scope("hvd_attention"):
             o = kvc.paged_decode_attention(
-                q, kp, vp, block_tables, lengths + 1, scale)
+                q, kp, vp, bt, lengths + 1, scale)
         o = o.astype(x.dtype).reshape(x.shape[0], -1)
         x = x + tp_lib.row_parallel(o, lp["wo"].astype(cfg.dtype),
                                     cfg.tp_axis).astype(x.dtype)
         with jax.named_scope("hvd_mlp"):
             x = x + _mlp(cfg, lp, x).astype(x.dtype)
-        return x, (kp, vp)
+        return (x, kp, vp), None
 
-    (x), (k_new, v_new) = lax.scan(layer, x, (layers, kp_in, vp_in))
-    if n_layers is not None:
-        k_new = k_pages.at[:n_layers].set(k_new)
-        v_new = v_pages.at[:n_layers].set(v_new)
+    (x, kp, vp), _ = lax.scan(layer, (x, kp, vp), _with_index(layers))
     x = tfm._rmsnorm(x, params["final_norm"])
     logits = _gather_logits(cfg, x, params["head"])       # [S, V] f32
     next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return k_new, v_new, next_tokens, logits
+    return (kp.reshape(k_pages.shape), vp.reshape(v_pages.shape),
+            next_tokens, logits)
 
 
 def _prefill_body(cfg: tfm.TransformerConfig, params: Any,
@@ -236,20 +261,23 @@ def _prefill_body(cfg: tfm.TransformerConfig, params: Any,
         tokens, params["embed"].astype(cfg.dtype), cfg.tp_axis)   # [C, D]
     page = k_pages.shape[2]
     n_ctx = block_table.shape[0] * page
+    kp, vp, stride = _flat_pool(k_pages, v_pages)
 
     def layer(carry, xs):
-        x = carry
-        lp, kp, vp = xs
+        x, kp, vp = carry
+        lp, li = xs
+        base = li * stride
+        bt = block_table + base
         h = tfm._rmsnorm(x, lp["attn_norm"])
         q, k, v = _qkv(cfg, lp, h)                       # [C, Hl, Dh]
         q = _rope_rows(q, pos)
         k = _rope_rows(k, pos)
         with jax.named_scope("hvd_kv_write"):
-            kp, vp = kvc.write_chunk_kv(kp, vp, k, v, block_table, start,
-                                        n_real)
+            kp, vp = kvc.write_chunk_kv(kp, vp, k, v, bt, start, n_real,
+                                        scratch=base + stride - 1)
         with jax.named_scope("hvd_attention"):
-            kg = kvc.gather_pages(kp, block_table).astype(jnp.float32)
-            vg = kvc.gather_pages(vp, block_table).astype(jnp.float32)
+            kg = kvc.gather_pages(kp, bt).astype(jnp.float32)
+            vg = kvc.gather_pages(vp, bt).astype(jnp.float32)
             s = jnp.einsum("chd,shd->chs", q.astype(jnp.float32),
                            kg) * scale
             ctx = jnp.arange(n_ctx, dtype=jnp.int32)
@@ -265,31 +293,84 @@ def _prefill_body(cfg: tfm.TransformerConfig, params: Any,
                                     cfg.tp_axis).astype(x.dtype)
         with jax.named_scope("hvd_mlp"):
             x = x + _mlp(cfg, lp, x).astype(x.dtype)
-        return x, (kp, vp)
+        return (x, kp, vp), None
 
-    x, (k_new, v_new) = lax.scan(
-        layer, x, (params["layers"], k_pages, v_pages))
+    (x, kp, vp), _ = lax.scan(layer, (x, kp, vp),
+                              _with_index(params["layers"]))
     x = tfm._rmsnorm(x, params["final_norm"])
     last = jnp.take(x, jnp.maximum(n_real - 1, 0), axis=0)     # [D]
     logits = _gather_logits(cfg, x=last, head=params["head"])  # [V] f32
     next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return k_new, v_new, next_token, logits
+    return (kp.reshape(k_pages.shape), vp.reshape(v_pages.shape),
+            next_token, logits)
 
 
 # ---------------------------------------------------------------------------
 # engine
 # ---------------------------------------------------------------------------
 
-def _named_jit(fn: Callable, name: str, donate: Tuple[int, ...]):
+def _named_jit(fn: Callable, name: str, pool_args: Tuple[int, ...],
+               n_args: int, n_out: int, fmt: Format):
     """``jax.jit`` of a step function under a name of its own: the
     compiled module, and so the ``XLA Modules`` line of a device trace,
     reads ``jit_<name>`` where a ``functools.partial`` or a ``shard_map``
     wrapper would read ``jit__unknown`` — what a trace reader tells the
-    engine's programs apart by."""
+    engine's programs apart by.
+
+    Every engine program takes the pool (K, V) at ``pool_args``, donated,
+    and returns it first; both sides are pinned to the pool's one
+    ``fmt`` so no program converts the pool's layout on its way in or
+    out. The other arguments and results are left to the compiler."""
     def named(*args):
         return fn(*args)
     named.__name__ = named.__qualname__ = name
-    return jax.jit(named, donate_argnums=donate)
+    return jax.jit(
+        named, donate_argnums=pool_args,
+        in_shardings=tuple(fmt if i in pool_args else None
+                           for i in range(n_args)),
+        out_shardings=(fmt, fmt) + (None,) * (n_out - 2))
+
+
+def serve_programs(cfg: tfm.TransformerConfig, fmt: Format,
+                   mesh: Optional[Mesh] = None,
+                   draft_layers: Optional[int] = None
+                   ) -> Dict[str, Callable]:
+    """The engine's jitted program families over a pool held in ``fmt``:
+    ``decode`` (also the verify step, at another batch), ``prefill``
+    (every bucket), ``cow`` and, with ``draft_layers``, ``draft``.
+    Shard_map'd over ``mesh`` when ``cfg.tp_axis`` is set, plain
+    otherwise."""
+    # name -> (function, where K stands among its arguments (V follows;
+    # the parameters lead when that is 1), arguments, results)
+    table = {"decode": (functools.partial(_decode_body, cfg), 1, 6, 4),
+             "prefill": (functools.partial(_prefill_body, cfg), 1, 7, 4),
+             "cow": (kvc.copy_page, 0, 4, 2)}
+    if draft_layers:
+        table["draft"] = (functools.partial(
+            _decode_body, cfg, n_layers=draft_layers), 1, 6, 4)
+    programs = {}
+    for name, (fn, k_at, n_args, n_out) in table.items():
+        if cfg.tp_axis and mesh is not None:
+            from horovod_tpu.eager import shard_map
+            kv = fmt.sharding.spec
+            in_specs = [P()] * n_args
+            in_specs[k_at] = in_specs[k_at + 1] = kv
+            if k_at:
+                in_specs[0] = tfm.param_specs(cfg)
+            fn = shard_map(fn, mesh, in_specs=tuple(in_specs),
+                           out_specs=(kv, kv) + (P(),) * (n_out - 2))
+        programs[name] = _named_jit(fn, f"hvd_serve_{name}",
+                                    (k_at, k_at + 1), n_args, n_out, fmt)
+    return programs
+
+
+def _abstract(x: Any) -> Any:
+    """Shape, dtype and placement of a live array, for lowering."""
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+
+
+def _i32(*shape: int) -> jax.ShapeDtypeStruct:
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
 
 
 class ServeEngine:
@@ -372,73 +453,74 @@ class ServeEngine:
         # device placement: on the mesh when one is given — pages sharded
         # over KV heads under TP, otherwise params and pages replicated
         # over it (a one-device mesh pins the replica to that chip);
-        # without a mesh, wherever the caller's params already live.
+        # without a mesh, on the device the caller's params already live.
         if tp and mesh is not None:
-            kv_spec = P(None, None, None, tp, None)
-            self._kv_sharding = NamedSharding(mesh, kv_spec)
+            kv_sharding = NamedSharding(mesh, P(None, None, None, tp, None))
             pspecs = tfm.param_specs(cfg)
             self.params = jax.device_put(params, jax.tree.map(
                 lambda s: NamedSharding(mesh, s), pspecs,
                 is_leaf=lambda x: isinstance(x, P)))
         elif mesh is not None:
-            kv_spec = None
-            self._kv_sharding = NamedSharding(mesh, P())
-            self.params = jax.device_put(params, self._kv_sharding)
+            kv_sharding = NamedSharding(mesh, P())
+            self.params = jax.device_put(params, kv_sharding)
         else:
-            kv_spec = None
-            self._kv_sharding = None
-            self.params = params
-        k_pages, v_pages = self.pool.alloc_arrays()
-        if self._kv_sharding is not None:
-            k_pages = jax.device_put(k_pages, self._kv_sharding)
-            v_pages = jax.device_put(v_pages, self._kv_sharding)
-        self.k_pages, self.v_pages = k_pages, v_pages
-
-        # step functions (shard_map'd under TP, plain otherwise)
-        decode_fn = functools.partial(_decode_body, cfg)
-        prefill_fn = functools.partial(_prefill_body, cfg)
-        draft_fn = (functools.partial(_decode_body, cfg,
-                                      n_layers=self.draft_n)
-                    if self.draft_mode == "truncate" else None)
-        cow_fn = kvc.copy_page
-        if tp and mesh is not None:
-            from horovod_tpu.eager import shard_map
-            pspecs = tfm.param_specs(cfg)
-            rep = P()
-            decode_fn = shard_map(
-                decode_fn, mesh,
-                in_specs=(pspecs, kv_spec, kv_spec, rep, rep, rep),
-                out_specs=(kv_spec, kv_spec, rep, rep))
-            prefill_fn = shard_map(
-                prefill_fn, mesh,
-                in_specs=(pspecs, kv_spec, kv_spec, rep, rep, rep, rep),
-                out_specs=(kv_spec, kv_spec, rep, rep))
-            if draft_fn is not None:
-                draft_fn = shard_map(
-                    draft_fn, mesh,
-                    in_specs=(pspecs, kv_spec, kv_spec, rep, rep, rep),
-                    out_specs=(kv_spec, kv_spec, rep, rep))
-            cow_fn = shard_map(
-                cow_fn, mesh,
-                in_specs=(kv_spec, kv_spec, rep, rep),
-                out_specs=(kv_spec, kv_spec))
-        self._decode_jit = _named_jit(decode_fn, "hvd_serve_decode", (1, 2))
-        self._prefill_jit = _named_jit(prefill_fn, "hvd_serve_prefill",
-                                       (1, 2))
-        self._draft_jit = (_named_jit(draft_fn, "hvd_serve_draft", (1, 2))
-                           if draft_fn is not None else None)
-        self._cow_jit = _named_jit(cow_fn, "hvd_serve_cow", (0, 1))
-
-        # AOT build (store-served): one decode executable + one prefill
-        # executable per bucket — plus, when the knobs switch them on,
-        # the speculative verify step (the decode body at batch
-        # slots*(K+1)), the truncated-layer draft step, and the COW
-        # page copy. `builds` counts actual compiles — the warm-boot
-        # gate asserts it stays 0 on a warm store, new executables
-        # included.
+            self.params = params = jax.tree.map(jnp.asarray, params)
+            kv_sharding = SingleDeviceSharding(
+                next(iter(jax.tree.leaves(params)[0].devices())))
+        # the pool's one layout and placement: allocated in it, and
+        # pinned on every program that takes or returns the pool
+        self.pool_format = kvc.pool_format(kv_sharding)
+        # Where a reloaded executable loses the pinned layout of its
+        # results, every program that makes or returns the pool is
+        # compiled in this process: past JAX's persistent cache and
+        # past the artifact store.
+        from horovod_tpu.store import artifact_store as store_mod
+        self.reload_keeps_layout = store_mod.reload_keeps_layout(
+            self.pool_format,
+            (1, 1, self.page, cfg.n_heads // self._tp_size, cfg.head_dim),
+            cfg.dtype)
+        if not self.reload_keeps_layout:
+            logger.warning(
+                "serve: a reloaded executable does not keep the KV "
+                "pool's pinned layout on this backend; compiling the "
+                "engine's programs in process (no persistent compile "
+                "cache, no artifact store) at every engine build")
+        programs = serve_programs(
+            cfg, self.pool_format, mesh,
+            self.draft_n if self.draft_mode == "truncate" else None)
+        self._decode_jit = programs["decode"]
+        self._prefill_jit = programs["prefill"]
+        self._draft_jit = programs.get("draft")
+        self._cow_jit = programs["cow"]
         self.builds = 0
         self.store_outcomes: Dict[str, str] = {}
+        # temporaries of each compiled program (memory_analysis): a
+        # program that copied or re-laid the pool would read pool-sized
+        self.program_temp_bytes: Dict[str, int] = {}
         self._dispatch: Dict[str, Callable] = {}
+        with (contextlib.nullcontext() if self.reload_keeps_layout
+              else compile_cache.uncached()):
+            self._build()
+        _register_engine(self)
+        logger.info(
+            "serve engine up: %d slots, %d+1 pages x %d tokens "
+            "(%.1f MiB KV pool), prefill buckets %s, tp=%d, builds=%d, "
+            "decode temporaries %.1f MiB",
+            self.slots, pool_pages, self.page,
+            self.pool.nbytes() / 2 ** 20, self.buckets, self._tp_size,
+            self.builds,
+            self.program_temp_bytes.get("serve_decode", 0) / 2 ** 20)
+
+    # -- AOT/store plumbing --------------------------------------------------
+    def _build(self) -> None:
+        """Allocate the pool and AOT-build (store-served) one decode
+        executable + one prefill executable per bucket — plus, when the
+        knobs switch them on, the speculative verify step, the
+        truncated-layer draft step, and the COW page copy. `builds`
+        counts actual compiles — the warm-boot gate asserts it stays 0
+        on a warm store, new executables included."""
+        self.k_pages, self.v_pages = self.pool.alloc_arrays(
+            self.pool_format)
         self._decode = self._adopt(
             self._decode_jit, self._decode_args(), "serve_decode")
         self._prefill: Dict[int, Callable] = {}
@@ -448,8 +530,11 @@ class ServeEngine:
                 f"serve_prefill_{b}")
         self._verify = self._draft = self._cow = None
         if self.spec_k:
+            # the decode body at batch slots*(K+1): each slot's
+            # block-table row repeated K+1 times
             self._verify = self._adopt(
-                self._decode_jit, self._verify_args(),
+                self._decode_jit,
+                self._decode_args(self.slots * (self.spec_k + 1)),
                 f"serve_verify_k{self.spec_k}")
             if self._draft_jit is not None:
                 self._draft = self._adopt(
@@ -458,51 +543,33 @@ class ServeEngine:
         if self.prefix is not None:
             self._cow = self._adopt(
                 self._cow_jit, self._cow_args(), "serve_cow_copy")
-        _register_engine(self)
-        logger.info(
-            "serve engine up: %d slots, %d+1 pages x %d tokens "
-            "(%.1f MiB KV pool), prefill buckets %s, tp=%d, builds=%d",
-            self.slots, pool_pages, self.page,
-            self.pool.nbytes() / 2 ** 20, self.buckets, self._tp_size,
-            self.builds)
 
-    # -- AOT/store plumbing --------------------------------------------------
-    def _decode_args(self) -> Tuple:
-        bt, ln = self.tables.device_views()
-        return (self.params, self.k_pages, self.v_pages, bt, ln,
-                jnp.zeros((self.slots,), jnp.int32))
+    # Programs are lowered from shapes: a concrete donated example would
+    # have to be a second pool.
+    def _pool_args(self) -> Tuple:
+        return (_abstract(self.k_pages), _abstract(self.v_pages))
+
+    def _decode_args(self, rows: Optional[int] = None) -> Tuple:
+        rows = rows or self.slots
+        return (jax.tree.map(_abstract, self.params), *self._pool_args(),
+                _i32(rows, self.n_max_pages), _i32(rows), _i32(rows))
 
     def _prefill_args(self, bucket: int) -> Tuple:
-        bt = jnp.full((self.n_max_pages,), self.pool.scratch_page,
-                      jnp.int32)
-        return (self.params, self.k_pages, self.v_pages, bt,
-                jnp.zeros((), jnp.int32), jnp.ones((), jnp.int32),
-                jnp.zeros((bucket,), jnp.int32))
-
-    def _verify_args(self) -> Tuple:
-        """The decode body at batch slots*(K+1): each slot's block-table
-        row repeated K+1 times (the speculative verify shape)."""
-        rows = self.slots * (self.spec_k + 1)
-        bt = jnp.full((rows, self.n_max_pages), self.pool.scratch_page,
-                      jnp.int32)
-        return (self.params, self.k_pages, self.v_pages, bt,
-                jnp.zeros((rows,), jnp.int32),
-                jnp.zeros((rows,), jnp.int32))
+        return (jax.tree.map(_abstract, self.params), *self._pool_args(),
+                _i32(self.n_max_pages), _i32(), _i32(), _i32(bucket))
 
     def _cow_args(self) -> Tuple:
-        return (self.k_pages, self.v_pages,
-                jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
+        return (*self._pool_args(), _i32(), _i32())
 
     def _adopt(self, fn: Callable, args: Tuple, label: str) -> Callable:
-        """AOT-compile `fn` for `args`, served from the artifact store
-        (kind 'serve') when one is configured; counts real compiles in
-        ``self.builds``. Donated example args are copied first — the
-        engine's live pool buffers must survive the lowering."""
+        """AOT-compile `fn` for the abstract `args`, served from the
+        artifact store (kind 'serve') when one is configured and a
+        reloaded executable is whole (``reload_keeps_layout``; where
+        not, outcome 'unsupported', and ``_build`` runs past JAX's
+        persistent cache too); counts real compiles in
+        ``self.builds``."""
         from horovod_tpu.store import artifact_store as store_mod
-        args = jax.tree.map(
-            lambda x: jnp.copy(x) if isinstance(x, jax.Array) else x,
-            args)
-        if store_mod.enabled():
+        if store_mod.enabled() and self.reload_keeps_layout:
             wrapped, outcome = store_mod.adopt_step(
                 fn, args, label=label, kind="serve")
             if outcome != "hit":
@@ -510,11 +577,15 @@ class ServeEngine:
         else:
             compiled, dt = store_mod.aot_compile(fn, args)
             self.builds += 1
-            outcome = "disabled"
-            logger.debug("serve: %s compiled in %.2fs (no artifact "
-                         "store)", label, dt)
+            outcome = "unsupported" if store_mod.enabled() else "disabled"
+            logger.debug("serve: %s compiled in %.2fs (artifact store "
+                         "%s)", label, dt, outcome)
             wrapped = store_mod.wrap_compiled(compiled, fn, label)
         self.store_outcomes[label] = outcome
+        compiled = getattr(wrapped, "hvd_store_compiled", None)
+        if compiled is not None:
+            self.program_temp_bytes[label] = int(
+                compiled.memory_analysis().temp_size_in_bytes)
         self._dispatch[label] = wrapped
         return wrapped
 
@@ -825,6 +896,7 @@ class ServeEngine:
             "spec_k": self.spec_k,
             "builds": self.builds,
             "store_outcomes": dict(self.store_outcomes),
+            "program_temp_bytes": dict(self.program_temp_bytes),
             # executables that rejected their inputs and now dispatch
             # through the jit fall-back (wrap_compiled); empty is healthy
             "store_rejected": sorted(

@@ -13,10 +13,15 @@ the fragmentation that caps batch size in the contiguous layout is gone.
 Split of responsibilities:
 
 - **Device state** (inside the AOT-compiled steps): the page pool
-  arrays, written functionally with donated buffers so XLA updates in
-  place. One extra *scratch page* (physical id ``n_pages``) absorbs the
-  writes of padded positions and empty slots — every store the compiled
-  step issues targets a valid physical page, no predication needed.
+  arrays, ``[n_layers, n_pages + 1, page, n_kv_heads, head_dim]``,
+  donated to every step. The engine keeps them in ONE row-major layout
+  (:func:`pool_format`) and its step bodies address them as one flat
+  run of pages, layer ``l``'s page ``p`` at ``l * (n_pages + 1) + p``,
+  so a step's only pool-shaped instructions are in-place scatters
+  (docs/serving.md, "Pool layout"). One extra *scratch page* per layer
+  (physical id ``n_pages``) absorbs the writes of padded positions and
+  empty slots — every store the compiled step issues targets a valid
+  physical page, no predication needed.
 - **Host state** (:class:`PageAllocator`, :class:`BlockTables`): the
   free list, per-slot tables and lengths as numpy arrays the scheduler
   mutates between steps and ships to the device per step (a few hundred
@@ -46,6 +51,17 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
+
+
+def pool_format(sharding: jax.sharding.Sharding) -> Format:
+    """The one layout the 5-D page pool lives in, from allocation to the
+    decode kernel's DMA: row-major over ``sharding``. A Pallas operand
+    and a scatter are row-major on TPU, while the compiler's own choice
+    for the pool's shape puts a page's tokens on the lanes — left free,
+    every step converts each layer's pool there and back. The CPU
+    backend is row-major anyway."""
+    return Format(Layout(major_to_minor=(0, 1, 2, 3, 4)), sharding)
 
 
 class PagePool:
@@ -70,14 +86,21 @@ class PagePool:
         """Physical id of the write sink for padded/empty positions."""
         return self.n_pages
 
-    def alloc_arrays(self) -> Tuple[jax.Array, jax.Array]:
+    def alloc_arrays(self, fmt: Optional[Format] = None
+                     ) -> Tuple[jax.Array, jax.Array]:
         """Zeroed (k_pages, v_pages), each
         ``[n_layers, n_pages + 1, page, n_kv_heads, head_dim]`` (the +1
-        is the scratch page). Under tensor parallelism the caller
-        device_puts these with the KV-head axis sharded."""
+        is the scratch page), made in place in ``fmt`` (layout and
+        sharding — the engine's :func:`pool_format`; under tensor
+        parallelism its sharding splits the KV-head axis) so no second
+        pool-sized buffer exists even while allocating."""
         shape = (self.n_layers, self.n_pages + 1, self.page,
                  self.n_kv_heads, self.head_dim)
-        return jnp.zeros(shape, self.dtype), jnp.zeros(shape, self.dtype)
+        if fmt is None:
+            return jnp.zeros(shape, self.dtype), jnp.zeros(shape, self.dtype)
+        zeros = jax.jit(lambda: jnp.zeros(shape, self.dtype),
+                        out_shardings=fmt)
+        return zeros(), zeros()
 
     def pages_for(self, n_tokens: int) -> int:
         return -(-max(int(n_tokens), 0) // self.page)
@@ -390,17 +413,22 @@ class BlockTables:
 def write_token_kv(k_pages: jax.Array, v_pages: jax.Array,
                    k_new: jax.Array, v_new: jax.Array,
                    block_tables: jax.Array, positions: jax.Array,
-                   valid: Optional[jax.Array] = None
+                   valid: Optional[jax.Array] = None,
+                   scratch: Optional[jax.Array] = None
                    ) -> Tuple[jax.Array, jax.Array]:
     """Scatter one token's K/V per sequence into its page.
 
-    k_pages/v_pages ``[n_phys, page, KVH, D]`` (single layer),
-    k_new/v_new ``[B, KVH, D]``, positions ``[B]`` (global token index
-    the write lands at), valid ``[B]`` bool — invalid writes are routed
-    to the scratch page (last physical page) instead of being dropped,
-    which keeps the op a plain scatter."""
+    k_pages/v_pages ``[n_phys, page, KVH, D]``, k_new/v_new
+    ``[B, KVH, D]``, positions ``[B]`` (global token index the write
+    lands at), valid ``[B]`` bool — invalid writes are routed to the
+    scratch page instead of being dropped, which keeps the op a plain
+    scatter. ``scratch`` is that page's physical id: the last page of a
+    single layer's pool by default; over the engine's flat pool the
+    block tables carry the layer's offset and the caller names the
+    layer's own scratch page."""
     page = k_pages.shape[1]
-    scratch = k_pages.shape[0] - 1
+    if scratch is None:
+        scratch = k_pages.shape[0] - 1
     logical = positions // page
     phys = jnp.take_along_axis(block_tables, logical[:, None],
                                axis=1)[:, 0]
@@ -415,14 +443,17 @@ def write_token_kv(k_pages: jax.Array, v_pages: jax.Array,
 def write_chunk_kv(k_pages: jax.Array, v_pages: jax.Array,
                    k_new: jax.Array, v_new: jax.Array,
                    block_table: jax.Array, start: jax.Array,
-                   n_real: jax.Array) -> Tuple[jax.Array, jax.Array]:
+                   n_real: jax.Array, scratch: Optional[jax.Array] = None
+                   ) -> Tuple[jax.Array, jax.Array]:
     """Scatter a prefill chunk's K/V (one sequence) into its pages.
 
     k_new/v_new ``[C, KVH, D]`` for chunk positions
     ``start .. start + C``; positions at or past ``start + n_real`` are
-    padding and land on the scratch page. block_table ``[n_max]``."""
+    padding and land on the scratch page (``scratch``, as in
+    :func:`write_token_kv`). block_table ``[n_max]``."""
     page = k_pages.shape[1]
-    scratch = k_pages.shape[0] - 1
+    if scratch is None:
+        scratch = k_pages.shape[0] - 1
     c = k_new.shape[0]
     pos = start + jnp.arange(c, dtype=jnp.int32)
     phys = jnp.take(block_table, pos // page, mode="clip")
@@ -440,7 +471,8 @@ def copy_page(k_pages: jax.Array, v_pages: jax.Array,
     across every layer (k_pages/v_pages ``[L, n_phys, page, KVH, D]``,
     src/dst scalar int32). One executable covers every (src, dst) pair
     — the ids are runtime operands, so admission-time COW never
-    compiles. Donated by the engine: XLA updates the pool in place."""
+    compiles. Donated by the engine and jitted in the pool's one layout:
+    the update is in place."""
     k_pages = k_pages.at[:, dst].set(k_pages[:, src])
     v_pages = v_pages.at[:, dst].set(v_pages[:, src])
     return k_pages, v_pages

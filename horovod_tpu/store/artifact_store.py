@@ -660,6 +660,33 @@ def reloadable(platform: str, n_devices: int, n_backend: int) -> bool:
     return platform != "tpu" or n_devices in (1, n_backend)
 
 
+def reload_keeps_layout(fmt: Any, shape: Tuple[int, ...], dtype: Any) -> bool:
+    """Whether an executable that went through serialization (this
+    store, JAX's persistent compile cache) still returns a result in
+    the layout pinned on it. Measured, not assumed: libtpu 0.0.34
+    honours a reloaded program's pinned ARGUMENT layouts but hands its
+    results back in the compiler's default layout, while reporting the
+    pinned one (PERF.md, PR 26) — a reloaded serve program would re-lay
+    the whole KV pool on its way out and the next call would refuse it.
+    The probe pins the layout of ``fmt`` (a ``jax.experimental.layout.
+    Format``) on the identity over ``shape`` on one device of it,
+    reloads that and looks at what comes out. True on the CPU backend,
+    and wherever the default layout of ``shape`` is the pinned one."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import serialize_executable as se
+    from jax.experimental.layout import Format
+    device = min(fmt.sharding.device_set, key=lambda d: d.id)
+    one = jax.sharding.SingleDeviceSharding(device)
+    compiled = jax.jit(
+        lambda a: a * 1, out_shardings=Format(fmt.layout, one)).lower(
+            jax.ShapeDtypeStruct(shape, dtype, sharding=one)).compile()
+    reloaded = _deserialize_onto([device], *se.serialize(compiled))
+    out = reloaded(jax.device_put(jnp.ones(shape, dtype), one))
+    return (out.format.layout.major_to_minor
+            == fmt.layout.major_to_minor)
+
+
 def _deserialize_onto(devices: List[Any], serialized: bytes, in_tree: Any,
                       out_tree: Any) -> Any:
     """``serialize_executable.deserialize_and_load`` onto ``devices`` (the

@@ -1,11 +1,15 @@
 """Where JAX's persistent compilation cache lives for the chip entry
-points (``chip_smoke.py``, ``bench.py``)."""
+points (``chip_smoke.py``, ``bench.py``), and how to compile past it."""
 
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
 
 ENV = "JAX_COMPILATION_CACHE_DIR"
+# uncached() flips a process-wide switch and flips it back: one at a time
+_UNCACHED = threading.RLock()
 
 
 def place(checkout: str) -> str:
@@ -25,3 +29,24 @@ def place(checkout: str) -> str:
     import jax
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+@contextlib.contextmanager
+def uncached():
+    """Compiles inside neither read nor write JAX's persistent cache:
+    the executable that runs is the one the compiler made in this
+    process, never one that went through serialization. For programs a
+    reload does not bring back whole (``store.artifact_store.
+    reload_keeps_layout``). Process-wide while it lasts; whether the
+    cache is in use is re-read on both edges."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    with _UNCACHED:
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        try:
+            yield
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            cc.reset_cache()

@@ -194,3 +194,39 @@ def test_entry_modules_initialise_no_backend():
     proc = subprocess.run([sys.executable, "-c", code],
                           capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, proc.stderr
+
+
+def test_uncached_compiles_neither_read_nor_write_the_persistent_cache(
+        tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from horovod_tpu.utils import compile_cache
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    before = {n: getattr(jax.config, n) for n in names}
+    entries = lambda: sorted(p.name for p in tmp_path.iterdir()
+                             if p.name.endswith("-cache"))
+    try:
+        for n, v in zip(names, (str(tmp_path), 0.0, 0)):
+            jax.config.update(n, v)
+        cc.reset_cache()
+        with compile_cache.uncached():
+            jax.jit(lambda x: x * 3 + 26).lower(jnp.ones(3)).compile()
+            assert entries() == []                          # not written
+        jax.jit(lambda x: x * 3 + 26).lower(jnp.ones(3)).compile()
+        written = entries()
+        assert len(written) == 1                            # in force again
+        with compile_cache.uncached():
+            os.remove(tmp_path / written[0])    # a read would now fail
+            with open(tmp_path / written[0], "wb") as f:
+                f.write(b"not an executable")
+            jax.jit(lambda x: x * 3 + 26).lower(jnp.ones(3)).compile()
+        assert jax.config.jax_enable_compilation_cache
+    finally:
+        for n, v in before.items():
+            jax.config.update(n, v)
+        cc.reset_cache()

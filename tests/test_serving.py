@@ -887,3 +887,280 @@ def test_draft_spec_validation_errors():
         _engine(draft="banana")
     with pytest.raises(ValueError, match="HOROVOD_SERVE_SPEC_K"):
         _engine(draft="ngram:3", spec_k=0)
+
+
+# ---------------------------------------------------------------------------
+# one pool, one layout: the flat page ids against per-layer writes
+# ---------------------------------------------------------------------------
+
+def _stacked_pool_bodies(cfg):
+    """The step bodies over a STACKED pool, the reference the engine's
+    flat pool is held to: the layer scan takes layer ``l``'s pool
+    ``[P+1, page, KVH, D]`` as a slice, ``write_token_kv`` /
+    ``write_chunk_kv`` write it with plain block tables and its own last
+    page as the scratch page, and the results are stacked back."""
+    from jax import lax
+    from horovod_tpu.parallel import tensor_parallel as tp_lib
+    from horovod_tpu.serving import engine as E
+    scale = cfg.head_dim ** -0.5
+
+    def tail(lp, x, o):
+        o = o.astype(x.dtype).reshape(x.shape[0], -1)
+        x = x + tp_lib.row_parallel(o, lp["wo"].astype(cfg.dtype),
+                                    cfg.tp_axis).astype(x.dtype)
+        return x + E._mlp(cfg, lp, x).astype(x.dtype)
+
+    def decode(params, k_pages, v_pages, block_tables, lengths, tokens):
+        x = tp_lib.vocab_parallel_embed(
+            tokens, params["embed"].astype(cfg.dtype), cfg.tp_axis)
+        valid = lengths < block_tables.shape[1] * k_pages.shape[2]
+
+        def layer(x, xs):
+            lp, kp, vp = xs
+            q, k, v = E._qkv(cfg, lp, tfm._rmsnorm(x, lp["attn_norm"]))
+            q, k = E._rope_rows(q, lengths), E._rope_rows(k, lengths)
+            kp, vp = kvc.write_token_kv(kp, vp, k, v, block_tables,
+                                        lengths, valid=valid)
+            o = kvc.paged_decode_attention(q, kp, vp, block_tables,
+                                           lengths + 1, scale)
+            return tail(lp, x, o), (kp, vp)
+
+        _, (k_new, v_new) = lax.scan(
+            layer, x, (params["layers"], k_pages, v_pages))
+        return k_new, v_new
+
+    def prefill(params, k_pages, v_pages, block_table, start, n_real,
+                tokens):
+        c = tokens.shape[0]
+        pos = start + jnp.arange(c, dtype=jnp.int32)
+        x = tp_lib.vocab_parallel_embed(
+            tokens, params["embed"].astype(cfg.dtype), cfg.tp_axis)
+        n_ctx = block_table.shape[0] * k_pages.shape[2]
+
+        def layer(x, xs):
+            lp, kp, vp = xs
+            q, k, v = E._qkv(cfg, lp, tfm._rmsnorm(x, lp["attn_norm"]))
+            q, k = E._rope_rows(q, pos), E._rope_rows(k, pos)
+            kp, vp = kvc.write_chunk_kv(kp, vp, k, v, block_table, start,
+                                        n_real)
+            kg = kvc.gather_pages(kp, block_table).astype(jnp.float32)
+            vg = kvc.gather_pages(vp, block_table).astype(jnp.float32)
+            s = jnp.einsum("chd,shd->chs", q.astype(jnp.float32),
+                           kg) * scale
+            visible = (jnp.arange(n_ctx, dtype=jnp.int32)[None, :]
+                       <= pos[:, None])
+            s = jnp.where(visible[:, None, :], s, -jnp.inf)
+            m = jnp.max(s, axis=-1, keepdims=True)
+            m = jnp.where(jnp.isfinite(m), m, 0.0)
+            p = jnp.where(visible[:, None, :], jnp.exp(s - m), 0.0)
+            l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+            o = jnp.einsum("chs,shd->chd", p / l, vg)
+            return tail(lp, x, o), (kp, vp)
+
+        _, (k_new, v_new) = lax.scan(
+            layer, x, (params["layers"], k_pages, v_pages))
+        return k_new, v_new
+
+    return decode, prefill
+
+
+def _pool_engine(tp, **kw):
+    """(engine, mesh): three layers, so a stray write has a middle layer
+    to land in; over a 2-way TP mesh when ``tp``."""
+    mesh = None
+    cfg = _cfg(n_layers=3)
+    if tp:
+        from jax.sharding import Mesh
+        mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+        cfg = _cfg(n_layers=3, tp_axis="tp")
+    params = tfm.init_params(cfg, jax.random.PRNGKey(1))
+    return ServeEngine(cfg, params, mesh=mesh, slots=4, page=16,
+                       max_seq=128, prefill_chunk=64, **kw), mesh
+
+
+def _assert_pool_in_format(eng):
+    for pages in (eng.k_pages, eng.v_pages):
+        assert pages.format.layout.major_to_minor == (0, 1, 2, 3, 4)
+        assert pages.sharding.is_equivalent_to(eng.pool_format.sharding,
+                                               pages.ndim)
+        assert pages.committed
+
+
+@pytest.mark.parametrize("tp", [False, True], ids=["one_device", "tp2"])
+def test_flat_pool_equals_per_layer_writes(tp):
+    """After chunked prefills (a bucket-padded remainder among them) and
+    decode steps with slots empty and mid-prefill, the 5-D pool is
+    bitwise what the stacked-pool bodies produce from the same calls:
+    every real write in its layer's page, every padded or masked write
+    in THAT layer's scratch page, nothing anywhere else."""
+    eng, mesh = _pool_engine(tp)
+    decode, prefill = _stacked_pool_bodies(eng.cfg)
+    if tp:
+        from jax.sharding import PartitionSpec as P
+        from horovod_tpu.eager import shard_map
+        kv, rep = eng.pool_format.sharding.spec, P()
+        pspecs = tfm.param_specs(eng.cfg)
+        decode = shard_map(decode, mesh, (pspecs, kv, kv, rep, rep, rep),
+                           (kv, kv))
+        prefill = shard_map(prefill, mesh,
+                            (pspecs, kv, kv, rep, rep, rep, rep), (kv, kv))
+    decode, prefill = jax.jit(decode), jax.jit(prefill)
+    want = [jnp.zeros_like(eng.k_pages), jnp.zeros_like(eng.v_pages)]
+
+    def shadow(program, reference):
+        def call(params, k, v, *rest):
+            want[:] = reference(params, *want, *rest)
+            return program(params, k, v, *rest)
+        return call
+
+    eng._decode = shadow(eng._decode, decode)
+    eng._prefill = {b: shadow(fn, prefill)
+                    for b, fn in eng._prefill.items()}
+
+    rng = np.random.default_rng(21)
+    long = rng.integers(0, 256, 70).astype(np.int32)   # 64 + 6 padded to 32
+    short = rng.integers(0, 256, 20).astype(np.int32)
+    a = eng.reserve(long.size + 8)
+    b = eng.reserve(short.size + 8)
+    tokens = np.zeros((eng.slots,), np.int32)
+    tokens[b] = eng.prefill(b, short)
+    start, first = eng.prefill_chunk(a, long, 0)        # a is mid-prefill
+    assert first is None
+    for _ in range(3):              # b decodes; a and two slots masked
+        tokens[b] = eng.decode_step(tokens)[b]
+    _, tokens[a] = eng.prefill_chunk(a, long, start)
+    for _ in range(3):
+        nxt = eng.decode_step(tokens)
+        tokens[a], tokens[b] = nxt[a], nxt[b]
+
+    for got, ref in zip((eng.k_pages, eng.v_pages), want):
+        got, ref = np.asarray(got), np.asarray(ref)
+        assert got.shape == (3, eng.pool.n_pages + 1, 16, 4, 16)
+        np.testing.assert_array_equal(got, ref)
+        held = set(eng.slot_pages[a]) | set(eng.slot_pages[b])
+        scratch = eng.pool.scratch_page
+        for layer in range(3):
+            assert np.any(got[layer, scratch])      # its own scratch page
+            for page in range(eng.pool.n_pages):
+                if page not in held:
+                    assert not np.any(got[layer, page]), (layer, page)
+    _assert_pool_in_format(eng)
+
+
+def test_truncated_draft_leaves_deeper_layers_untouched():
+    eng, _ = _pool_engine(False, draft="truncate:1", spec_k=2)
+    rng = np.random.default_rng(22)
+    prompt = rng.integers(0, 256, 20).astype(np.int32)
+    slot = eng.reserve(prompt.size + 8)
+    tokens = np.zeros((eng.slots,), np.int32)
+    tokens[slot] = eng.prefill(slot, prompt)
+    before = np.asarray(eng.k_pages), np.asarray(eng.v_pages)
+    active = np.zeros((eng.slots,), bool)
+    active[slot] = True
+    eng.propose_drafts(tokens, active)
+    for was, now in zip(before, (eng.k_pages, eng.v_pages)):
+        now = np.asarray(now)
+        np.testing.assert_array_equal(now[1:], was[1:])
+        page = eng.slot_pages[slot][prompt.size // 16]
+        assert np.any(now[0, page] != was[0, page])     # layer 0 drafted
+    _assert_pool_in_format(eng)
+
+
+@pytest.mark.parametrize("tp", [False, True], ids=["one_device", "tp2"])
+def test_every_program_returns_the_pool_in_the_engines_format(tp):
+    """Decode, prefill, verify, draft and COW all hand the pool back in
+    the engine's one Format (layout, sharding, committed); none falls
+    back to the jit path, which a rejected layout or sharding would."""
+    eng, _ = _pool_engine(tp, prefix_cache=True, draft="truncate:1",
+                          spec_k=2)
+    _assert_pool_in_format(eng)                         # as allocated
+    rng = np.random.default_rng(23)
+    prompt = rng.integers(0, 256, 40).astype(np.int32)
+    slot = eng.reserve(prompt.size + 8, prompt=prompt)
+    tokens = np.zeros((eng.slots,), np.int32)
+    tokens[slot] = eng.prefill(slot, prompt)
+    _assert_pool_in_format(eng)                         # prefill
+    tokens[slot] = eng.decode_step(tokens)[slot]
+    _assert_pool_in_format(eng)                         # decode
+    active = np.zeros((eng.slots,), bool)
+    active[slot] = True
+    drafts = eng.propose_drafts(tokens, active)
+    _assert_pool_in_format(eng)                         # draft
+    eng.spec_step(tokens, drafts, active=active)
+    _assert_pool_in_format(eng)                         # verify
+    diverging = prompt.copy()
+    diverging[20] = (diverging[20] + 1) % 256           # inside page 1
+    assert eng.reserve(diverging.size + 8, prompt=diverging) is not None
+    assert eng.cow_copies == 1
+    _assert_pool_in_format(eng)                         # COW
+    assert eng.stats()["store_rejected"] == []
+    assert set(eng.program_temp_bytes) == set(eng.store_outcomes)
+
+
+def test_engine_build_lowers_from_shapes_and_holds_one_pool(monkeypatch):
+    """Engine build hands the compiler shapes, never arrays (a donated
+    example argument would be a second pool), and leaves no array of the
+    pool's size alive beside K and V."""
+    from horovod_tpu.store import artifact_store
+    cfg = _cfg()
+    params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+    lowered_from = []
+    adopt = artifact_store.adopt_step
+
+    def spy(fn, args, **kw):
+        lowered_from.extend(jax.tree.leaves(args))
+        return adopt(fn, args, **kw)
+
+    monkeypatch.setattr(artifact_store, "adopt_step", spy)
+    before = {id(x) for x in jax.live_arrays()}
+    eng, _ = _engine(cfg, params, prefix_cache=True, draft="truncate:1",
+                     spec_k=2)
+    assert lowered_from and all(
+        isinstance(x, jax.ShapeDtypeStruct) for x in lowered_from)
+    pool_sized = [x for x in jax.live_arrays() if id(x) not in before
+                  and x.nbytes >= eng.k_pages.nbytes]
+    assert {id(x) for x in pool_sized} == {id(eng.k_pages),
+                                           id(eng.v_pages)}
+
+
+def test_engine_compiles_in_process_where_a_reload_loses_the_layout(
+        monkeypatch):
+    """libtpu 0.0.34 hands a reloaded executable's results back in the
+    default layout: where the probe says so, the engine neither takes
+    its programs from the artifact store nor from JAX's persistent
+    cache, and serves the same tokens."""
+    from horovod_tpu.store import artifact_store
+    from horovod_tpu.utils import compile_cache
+    cfg = _cfg()
+    params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+    prompt = np.random.default_rng(24).integers(0, 256, 40).astype(np.int32)
+    want = _greedy_solo(_engine(cfg, params)[0], prompt, 5)
+
+    monkeypatch.setattr(artifact_store, "reload_keeps_layout",
+                        lambda fmt, shape, dtype: False)
+    monkeypatch.setattr(
+        artifact_store, "adopt_step",
+        lambda *a, **kw: pytest.fail("a program came through the store"))
+    uncached, entered = compile_cache.uncached, []
+
+    def counting():
+        entered.append(1)
+        return uncached()
+
+    monkeypatch.setattr(compile_cache, "uncached", counting)
+    eng, _ = _engine(cfg, params, prefix_cache=True)
+    assert set(eng.store_outcomes.values()) == {"unsupported"}
+    assert eng.builds == len(eng.store_outcomes) and len(entered) == 1
+    assert jax.config.jax_enable_compilation_cache      # restored
+    assert _greedy_solo(eng, prompt, 5) == want
+
+
+@pytest.mark.parametrize("tp", [False, True], ids=["one_device", "tp2"])
+def test_reload_probe_runs_on_one_device_of_the_pools_sharding(tp):
+    """On the CPU backend a reload keeps the pinned layout (row-major is
+    the only one); the probe itself must run for a sharded pool too."""
+    from horovod_tpu.store import artifact_store
+    eng, _ = _pool_engine(tp)
+    assert eng.reload_keeps_layout is True
+    assert artifact_store.reload_keeps_layout(
+        eng.pool_format, (1, 1, 16, 2, 16), jnp.bfloat16) is True

@@ -1,0 +1,118 @@
+"""Compile-only checks of the serve engine's programs at the serve cell's
+real size (pythia-410m widths, 16 and 24 slots of 2048 context, pages of
+128, bf16) for a described ``v5e:2x2``: the KV pool stays in one layout and
+one buffer. The programs are built the way the engine builds them
+(``serve_programs`` over ``pool_format``, lowered from shapes); nothing
+executes. Bytes are printed (``pytest -s``) for PERF.md."""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from horovod_tpu.models import transformer as tfm
+from horovod_tpu.serving import engine as eng, kv_cache as kvc
+
+PAGE, CTX, BUCKET = 128, 2048, 256
+ROW_MAJOR = (0, 1, 2, 3, 4)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture()
+def compiled_kernels(monkeypatch):
+    """The program asks the default backend (the CPU here) whether to use its
+    kernels; for a described TPU the answer is yes."""
+    from horovod_tpu.ops.pallas import flash_attention
+    monkeypatch.setattr(flash_attention, "enabled", lambda: True)
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+
+
+def _cfg():
+    return tfm.TransformerConfig(
+        vocab_size=50304, d_model=1024, n_heads=16, head_dim=64,
+        n_layers=24, d_ff=4096, max_seq=CTX, dtype=jnp.bfloat16,
+        dp_axis=None, remat=False)
+
+
+def _compile(topo, program, slots):
+    """(compiled, pool) of one engine program over a pool of ``slots``
+    slots, on the first described chip."""
+    cfg = _cfg()
+    one = SingleDeviceSharding(topo.devices[0])
+    pool = kvc.PagePool(cfg.n_layers, slots * (CTX // PAGE), PAGE,
+                        cfg.n_heads, cfg.head_dim, dtype=cfg.dtype)
+    k = jax.eval_shape(pool.alloc_arrays)[0]
+    kv = jax.ShapeDtypeStruct(k.shape, k.dtype, sharding=one)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    n_max = CTX // PAGE
+    args = {
+        "decode": (params, kv, kv, i32(slots, n_max), i32(slots), i32(slots)),
+        "prefill": (params, kv, kv, i32(n_max), i32(), i32(), i32(BUCKET)),
+        "draft": (params, kv, kv, i32(slots, n_max), i32(slots), i32(slots)),
+        "cow": (kv, kv, i32(), i32()),
+    }[program]
+    jits = eng.serve_programs(cfg, kvc.pool_format(one), draft_layers=2)
+    return jits[program].lower(*args).compile(), pool
+
+
+def _pool_sized(text, layer_elems, opcodes):
+    """Instructions of the optimized HLO with one of ``opcodes`` whose
+    result is a layer's pool or more: (opcode, result shape) of each."""
+    found = []
+    for m in re.finditer(
+            r"= \(?(\w+)\[([\d,]*)\][^ ]* (%s)\(" % "|".join(opcodes), text):
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        if int(np.prod(dims)) >= layer_elems:
+            found.append((m.group(3), f"{m.group(1)}[{m.group(2)}]"))
+    return found
+
+
+@pytest.mark.parametrize("program,slots", [
+    ("decode", 16), ("decode", 24), ("prefill", 16), ("prefill", 24),
+    ("draft", 16), ("cow", 16)])
+def test_pool_stays_in_one_layout_and_one_buffer(topo, compiled_kernels,
+                                                 program, slots):
+    compiled, pool = _compile(topo, program, slots)
+    m = compiled.memory_analysis()
+    print(f"\nserve {program}, {slots} slots: arguments "
+          f"{m.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+          f"{m.temp_size_in_bytes / 1e9:.3f} GB, pool "
+          f"{pool.nbytes() / 1e9:.3f} GB unpadded")
+    assert m.temp_size_in_bytes < pool.nbytes() / 4
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 16 * 2 ** 30
+    text = compiled.as_text()
+    layer_elems = ((pool.n_pages + 1) * pool.page * pool.n_kv_heads
+                   * pool.head_dim)
+    # no step copies, slices out or stacks back a layer's pool; the COW
+    # program IS one dynamic-update-slice of a page per layer, in place
+    opcodes = ("copy",) if program == "cow" else (
+        "copy", "dynamic-slice", "dynamic-update-slice")
+    assert _pool_sized(text, layer_elems, opcodes) == []
+    # the pool goes in and comes out in the engine's one layout
+    first = 0 if program == "cow" else 1
+    formats = (list(compiled.input_formats[0][first:first + 2])
+               + list(compiled.output_formats[:2]))
+    assert [f.layout.major_to_minor for f in formats] == [ROW_MAJOR] * 4
+    if program in ("decode", "draft"):
+        from horovod_tpu.ops.pallas.flash_attention import \
+            compiled_kernels as ck
+        assert "hvd_paged_decode" in ck(text)

@@ -202,7 +202,3 @@ class TrainProgram(train_program.TrainProgramBase):
             further=variables["batch_stats"],
             further_update=jax.jit(ref.running_stats))
 
-
-def kernel_shapes(config: Dict[str, Any], traffic: Dict[str, Any]
-                  ) -> Dict[str, int]:
-    return {}

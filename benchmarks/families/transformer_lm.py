@@ -135,6 +135,15 @@ class TrainProgram(train_program.TrainProgramBase):
     def opt_state(self):
         return self.state.opt_state
 
+    def facts(self) -> Dict[str, Any]:
+        """Shapes of one attention call of the train step, per chip."""
+        config, traffic = self.config, self.traffic
+        return {"shapes": {
+            "batch": traffic["rows_per_chip"], "seq": traffic["seq_len"],
+            "heads": config["num_attention_heads"],
+            "head_dim": head_dim(config),
+            "layers": config["num_hidden_layers"]}}
+
     def release(self) -> None:
         self.state = self._start = self.compiled = None
         self._batches = self._tokens = self._labels = None
@@ -173,19 +182,14 @@ def reference_readings(config, traffic, seed, devices, ops, steps,
         stacked=STACKED, keep_rows=keep_rows)
 
 
-def kernel_shapes(config: Dict[str, Any], traffic: Dict[str, Any]
-                  ) -> Dict[str, int]:
-    """Shapes of one attention call of the train step, per chip."""
-    return {"batch": traffic["rows_per_chip"], "seq": traffic["seq_len"],
-            "heads": config["num_attention_heads"],
-            "head_dim": head_dim(config),
-            "layers": config["num_hidden_layers"]}
-
-
 class ServeProgram:
     """The program's serving engine and scheduler on weights from the seed,
-    with the benchmark's own counting around the engine's two device calls
-    (the spans and counters a later ``tracing`` PR moves into the program)."""
+    with the benchmark's own counting around the engine's two device calls.
+    For the record the traffic kind reads ``vocab`` (the ids the clients
+    draw), ``facts()``, ``hlo_texts()`` and ``counters()``, as of a
+    ``TrainProgram`` (``benchmarks/lib/train_program.py``)."""
+
+    main_program = "serve_decode"   # whose text ``rec.program["hlo_text"]`` is
 
     def __init__(self, config: Dict[str, Any], traffic: Dict[str, Any],
                  seed: int, devices: Sequence[Any], spans):
@@ -193,6 +197,7 @@ class ServeProgram:
 
         self.config, self.traffic, self.seed = config, traffic, seed
         self.devices = list(devices)
+        self.vocab = config["vocab_size"]
         self.Request = Request
         cfg = program_config(config, dp_axis=None)
         with jax.default_device(self.devices[0]):
@@ -243,8 +248,21 @@ class ServeProgram:
     def request(self, rid: int, prompt: np.ndarray, max_new: int):
         return self.Request(rid=rid, prompt=prompt, max_new_tokens=max_new)
 
-    def kernel_text(self) -> str:
-        return self.engine.executable_text("serve_decode")
+    def facts(self) -> Dict[str, Any]:
+        """What the paged-decode roofline needs of the model."""
+        return {"heads": self.config["num_attention_heads"],
+                "head_dim": head_dim(self.config),
+                "layers": self.config["num_hidden_layers"]}
+
+    def hlo_texts(self) -> Dict[str, str]:
+        """The decode program and one prefill program per bucket."""
+        return {label: self.engine.executable_text(label)
+                for label in self.engine.store_outcomes}
+
+    def counters(self) -> Dict[str, Any]:
+        return {"decode_keys": self.decode_keys,
+                "prefill_tokens": self.prefill_tokens,
+                "required_flops": self.required_flops}
 
     def release(self) -> None:
         self.engine = self.scheduler = None

@@ -32,6 +32,12 @@ import numpy as np
 from benchmarks.lib import chip, compare, lowprec, report, window
 
 
+# what this kind stores under ``rec.counters`` itself; the family's program
+# brings the rest (``program.counters()``)
+OWN_COUNTERS = ("requests_per_s", "ttft_s", "batch_occupancy",
+                "compared_tokens", "served_sample")
+
+
 def quantile_lengths(spec: Dict[str, Any], strata: int) -> List[int]:
     """Midpoints of ``strata`` equal-probability slices of the distribution."""
     q = (np.arange(strata) + 0.5) / strata
@@ -88,7 +94,7 @@ def run(cell, seed: int, seconds: float, trace: int, devices: List[Any],
     prog = family.ServeProgram(cell.config, traffic, seed, devices, spans)
     marks.append(("weights, engine and its programs", time.perf_counter()))
     sched = prog.scheduler
-    clients = Clients(traffic, cell.config["vocab_size"], seed)
+    clients = Clients(traffic, prog.vocab, seed)
 
     submitted: Dict[int, Tuple[int, float]] = {}    # rid -> (client, t)
     first_seen: Dict[int, float] = {}               # rid -> t of first token
@@ -154,8 +160,8 @@ def run(cell, seed: int, seconds: float, trace: int, devices: List[Any],
         from benchmarks.lib import trace as tracing
         session = tracing.Session(cell.name, seed)
     stats0 = sched.stats()
-    mark = (len(finished), len(prog.decode_s), prog.prefill_tokens,
-            prog.required_flops, emitted(), len(first_token_at))
+    totals0 = window.counter_marks(prog.counters(), OWN_COUNTERS)
+    mark = (len(finished), len(prog.decode_s), emitted(), len(first_token_at))
     with window.measured(compile_log, session), spans.span("bench.window"):
         t0 = time.perf_counter()
         while True:
@@ -164,9 +170,10 @@ def run(cell, seed: int, seconds: float, trace: int, devices: List[Any],
             if elapsed >= seconds:
                 break
     stats1 = sched.stats()
+    family_counters = prog.counters()
     counted = finished[mark[0]:]
-    out_tokens = emitted() - mark[4]
-    ttft = [t for _, t in first_token_at[mark[5]:]]
+    out_tokens = emitted() - mark[2]
+    ttft = [t for _, t in first_token_at[mark[3]:]]
     rec.elapsed_s = elapsed
     rec.compiles_in_window = compile_log.in_window
     rec.attempted = len(counted)
@@ -179,20 +186,18 @@ def run(cell, seed: int, seconds: float, trace: int, devices: List[Any],
     steps = stats1["decode_steps"] - stats0["decode_steps"]
     rec.unit_s = prog.decode_s[mark[1]:]
     rec.counters = {
+        **window.added_since(totals0, family_counters),
         "requests_per_s": len(counted) / elapsed,
         "ttft_s": ttft,
-        "decode_keys": prog.decode_keys[mark[1]:],
-        "prefill_tokens": prog.prefill_tokens - mark[2],
-        "required_flops": prog.required_flops - mark[3],
         "batch_occupancy": (
             (stats1["mean_occupancy"] * stats1["decode_steps"]
              - (stats0["mean_occupancy"] or 0.0) * stats0["decode_steps"])
             / steps) if steps else None,
     }
-    rec.program = {"hlo_text": prog.kernel_text() if trace else "",
-                   "heads": cell.config["num_attention_heads"],
-                   "head_dim": family.head_dim(cell.config),
-                   "layers": cell.config["num_hidden_layers"]}
+    hlo_texts = prog.hlo_texts() if trace else {}
+    rec.program = {"hlo_texts": hlo_texts,
+                   "hlo_text": hlo_texts.get(prog.main_program, ""),
+                   **prog.facts()}
     rec.device["memory_peak_bytes"] = chip.memory_peak_bytes(devices)
 
     path = report.write_units(
@@ -225,7 +230,7 @@ def run(cell, seed: int, seconds: float, trace: int, devices: List[Any],
     del sched, counted, finished, waiting
     gc.collect()
     if trace:
-        rec.trace = session.reduce()
+        rec.trace = session.reduce(hlo_texts)
     gaps = prog.reference_gaps(lowprec.F32, served,
                                int(traffic["check_pad_to"]))
     numbers = {"served_logit_gap": float(max(g.max() for g in gaps))}

@@ -18,6 +18,11 @@ from typing import Any, Dict, List
 from benchmarks.lib import chip, compare, lowprec, report, window
 
 
+# what this kind stores under ``rec.counters`` itself; the family's program
+# brings the rest (``program.counters()``)
+OWN_COUNTERS = ("steps_in_trace",)
+
+
 def first_steps(prog: Any, steps: int) -> Dict[str, Any]:
     """The program's readings over its first ``steps`` steps, through the
     window's own call (``prog.step``) and feed."""
@@ -52,15 +57,17 @@ def run(cell, seed: int, seconds: float, trace: int, devices: List[Any],
     marks.append(("warm steps", time.perf_counter()))
     k0 += int(traffic["warm_steps"])
     mem = prog.compiled.memory_analysis()
+    hlo_texts = prog.hlo_texts() if trace else {}
     rec.program = {
         "argument_bytes": int(mem.argument_size_in_bytes),
         "temp_bytes": int(mem.temp_size_in_bytes),
         "output_bytes": int(mem.output_size_in_bytes),
         "alias_bytes": int(mem.alias_size_in_bytes),
-        "hlo_text": prog.compiled.as_text() if trace else "",
+        "hlo_texts": hlo_texts,
+        "hlo_text": hlo_texts.get(prog.main_program, ""),
         "items_per_step": prog.items_per_step,
         "required_flops_per_step": prog.required_flops_per_step,
-        "shapes": family.kernel_shapes(cell.config, traffic),
+        **prog.facts(),
     }
     rec.setup = {"setup_s": time.perf_counter() - t_start,
                  "compile_s": compile_log.setup_s}
@@ -73,6 +80,7 @@ def run(cell, seed: int, seconds: float, trace: int, devices: List[Any],
         from benchmarks.lib import trace as tracing
         session = tracing.Session(cell.name, seed)
     losses: List[float] = []
+    totals0 = window.counter_marks(prog.counters(), OWN_COUNTERS)
     with window.measured(compile_log, session), spans.span("bench.window"):
         win = window.run_steps(
             lambda k: prog.step(k0 + k),
@@ -81,7 +89,8 @@ def run(cell, seed: int, seconds: float, trace: int, devices: List[Any],
     rec.compiles_in_window = compile_log.in_window
     rec.unit_s, rec.elapsed_s = win.step_s, win.elapsed_s
     rec.attempted = win.counted
-    rec.counters = {"steps_in_trace": win.counted + win.drained}
+    rec.counters = {**window.added_since(totals0, prog.counters()),
+                    "steps_in_trace": win.counted + win.drained}
     rec.failed = sum(1 for v in losses[:win.counted] if not math.isfinite(v))
     rate = win.counted * prog.items_per_step / win.elapsed_s / len(devices)
     rec.end_to_end = {traffic["rate_metric"]: rate,
@@ -104,7 +113,7 @@ def run(cell, seed: int, seconds: float, trace: int, devices: List[Any],
     prog.release()
     gc.collect()
     if trace:
-        rec.trace = session.reduce()
+        rec.trace = session.reduce(hlo_texts)
     want = prog.reference(lowprec.F32, check_steps)
     rec.correct, rec.compared = compare.judge(
         compare.training_numbers(got, want), traffic["limits"])

@@ -4,7 +4,7 @@ returns None where there is nothing to read, never 0 for a share."""
 from __future__ import annotations
 
 import re
-from typing import Callable, Optional
+from typing import Callable, Dict, Optional
 
 from benchmarks.lib import window
 from benchmarks.roofline import flash_attention as flash_cost
@@ -72,6 +72,34 @@ def flash_ms_per_step(run) -> Optional[float]:
         return None
     steps = run.trace.kernel_calls(kernels[0]) / run.program["shapes"]["layers"]
     return 1e3 * sum(seconds) / steps
+
+
+def span_ms_p50(run, name: str) -> Optional[float]:
+    """Median milliseconds of the host spans of that name in the traced
+    window."""
+    if run.trace is None:
+        return None
+    seconds = run.trace.span_seconds(name)
+    return 1e3 * window.median(seconds) if seconds else None
+
+
+def scope_ms_per_run(run, program: str, scopes: Dict[str, str], part: str
+                     ) -> Optional[float]:
+    """Own device milliseconds under one scope per run of the programs whose
+    name contains ``program``. ``scopes``: part of the metric's name -> the
+    ``jax.named_scope`` it reads; the part ``other`` is the rest of the
+    program's device time, under another scope or under none."""
+    if part != "other" and part not in scopes:
+        raise ValueError(f"no scope for the part {part!r} (have "
+                         f"{sorted(scopes)} and 'other')")
+    if run.trace is None:
+        return None
+    split = run.trace.scope_seconds(program, tuple(scopes.values()))
+    runs = run.trace.program_runs(program)
+    if split is None or not runs:
+        return None
+    seconds = split[scopes.get(part, "other")]
+    return 1e3 * seconds / runs if seconds else None
 
 
 def allreduce_bytes(hlo_text: str) -> int:

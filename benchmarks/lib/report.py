@@ -72,18 +72,31 @@ def print_setup(setup: Dict[str, float], t_start: float,
           f"among them)", file=sys.stderr)
 
 
-def read_metric(name: str, run: RunRecord) -> Optional[float]:
-    """The per-layer metric ``name`` by its own reader,
-    ``benchmarks/metrics/<name>.py``; a metric split by suffix
-    (``step_ms_p50.lm``, ``.cnn``) that has no file of its own is read by its
-    stem's (``step_ms_p50.py``). None where it finds nothing to read."""
-    path = os.path.join(BENCH_DIR, "metrics", name + ".py")
+def load_reader(name: str, metrics_dir: str = None) -> Tuple[Any, str]:
+    """The module that reads the per-layer metric ``name`` and the part it
+    is asked for: ``metrics/<name>.py`` and ``""``, or for a metric split by
+    suffix (``step_ms_p50.lm``, ``.cnn``) that has no file of its own its
+    stem's (``step_ms_p50.py``) and the suffix."""
+    metrics_dir = metrics_dir or os.path.join(BENCH_DIR, "metrics")
+    path, part = os.path.join(metrics_dir, name + ".py"), ""
     if not os.path.exists(path) and "." in name:
-        path = os.path.join(BENCH_DIR, "metrics",
-                            name.rsplit(".", 1)[0] + ".py")
+        stem, part = name.rsplit(".", 1)
+        path = os.path.join(metrics_dir, stem + ".py")
     spec = importlib.util.spec_from_file_location(
         "benchmarks.metrics." + name.replace(".", "_"), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    value = module.read(run)
+    return module, part
+
+
+def read_metric(name: str, run: RunRecord, metrics_dir: str = None
+                ) -> Optional[float]:
+    """The per-layer metric ``name`` by its own reader: ``read(run)``, or of
+    a stem that tells its parts apart ``read_part(run, part)``. None where
+    it finds nothing to read."""
+    module, part = load_reader(name, metrics_dir)
+    if part and hasattr(module, "read_part"):
+        value = module.read_part(run, part)
+    else:
+        value = module.read(run)
     return None if value is None else float(value)

@@ -1,5 +1,10 @@
 """What every family's ``TrainProgram`` shares: reading the first gradient
-out of the optimizer's state and the parameters' change since a snapshot."""
+out of the optimizer's state and the parameters' change since a snapshot, and
+what it hands the traffic kind for the record: ``facts()`` (merged into
+``rec.program``: the shapes a roofline needs), ``hlo_texts()`` (label ->
+compiled text of every program the window runs, for the trace's scopes) and
+``counters()`` (running totals of the family's own; the kind stores what the
+window added to each under ``rec.counters``)."""
 
 from __future__ import annotations
 
@@ -14,6 +19,17 @@ from benchmarks.lib import trees
 class TrainProgramBase:
     stacked: Tuple[str, ...] = ()
     _start: Any = None
+    main_program = "train_step"     # whose text ``rec.program["hlo_text"]`` is
+    compiled: Any = None            # the step, AOT-compiled
+
+    def facts(self) -> Dict[str, Any]:
+        return {"shapes": {}}
+
+    def hlo_texts(self) -> Dict[str, str]:
+        return {self.main_program: self.compiled.as_text()}
+
+    def counters(self) -> Dict[str, Any]:
+        return {}
 
     def params(self) -> Any:
         raise NotImplementedError

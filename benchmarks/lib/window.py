@@ -16,7 +16,7 @@ import gc
 import statistics
 import sys
 import time
-from typing import Any, Callable, List, Tuple
+from typing import Any, Callable, Dict, List, Tuple
 
 
 class Spans:
@@ -130,6 +130,30 @@ def run_steps(dispatch: Callable[[int], Any], wait: Callable[[Any], None],
     for handle in pending:          # finished after the clock stopped
         wait(handle)
     return StepWindow(ends, drained)
+
+
+def counter_marks(counters: Dict[str, Any], kinds_own: Tuple[str, ...]
+                  ) -> Dict[str, Any]:
+    """Where a family's running totals (``program.counters()``) stand at the
+    window's start: a number as it is, a list (which only grows) by its
+    length. A name the traffic kind uses itself (``kinds_own``) is refused
+    here, before the window: one of the two would be lost."""
+    clash = set(kinds_own) & set(counters)
+    if clash:
+        raise ValueError(f"the family's counters {sorted(clash)} carry names "
+                         f"the traffic kind uses itself")
+    return {name: len(v) if isinstance(v, list) else v
+            for name, v in counters.items()}
+
+
+def added_since(marks: Dict[str, Any], counters: Dict[str, Any]
+                ) -> Dict[str, Any]:
+    """What the window added to each of the family's running totals."""
+    if set(marks) != set(counters):
+        raise ValueError(f"the family's counters changed names inside the "
+                         f"window: {sorted(set(marks) ^ set(counters))}")
+    return {name: v[marks[name]:] if isinstance(v, list) else v - marks[name]
+            for name, v in counters.items()}
 
 
 def slowest(step_s: List[float], n: int = 3) -> List[Tuple[int, float]]:

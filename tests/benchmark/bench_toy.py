@@ -1,6 +1,7 @@
 """Toy cells for the CPU rehearsals: the real harness, kinds, families and
 references at a width a test can hold. Kernels run in interpret mode."""
 
+import importlib
 import os
 import time
 
@@ -37,3 +38,34 @@ def rehearse(name: str, seed: int = 7, seconds: float = 0.3, trace: int = 0):
     from benchmarks import run
     return run.run_cell(cell(name), seed, seconds, trace, require_tpu=False,
                         t_start=time.perf_counter())
+
+
+def record(name: str, seed: int = 7, seconds: float = 0.3):
+    """The record of one whole untraced run of a toy cell, as the traffic
+    kind hands it to the metric readers."""
+    from benchmarks.lib import chip
+    c = cell(name)
+    kind = importlib.import_module("benchmarks.kinds." + c.traffic["kind"])
+    devices = chip.take_chips(c.chips, require_tpu=False)
+    return kind.run(c, seed, seconds, 0, devices, time.perf_counter(),
+                    chip.CompileLog())
+
+
+def count_calls(monkeypatch, program_cls, method: str, counter: str) -> None:
+    """A toy counter of a family's own: ``program_cls.counters()`` gains
+    ``counter``, the calls of ``method`` so far, and ``counter + "_at"``, a
+    list that grows by one with each call."""
+    sound_method = getattr(program_cls, method)
+    sound_counters = program_cls.counters
+
+    def counted(self, *args, **kw):
+        self.toy_calls = getattr(self, "toy_calls", 0) + 1
+        return sound_method(self, *args, **kw)
+
+    def counters(self):
+        n = getattr(self, "toy_calls", 0)
+        return {**sound_counters(self), counter: n,
+                counter + "_at": list(range(n))}
+
+    monkeypatch.setattr(program_cls, method, counted)
+    monkeypatch.setattr(program_cls, "counters", counters)

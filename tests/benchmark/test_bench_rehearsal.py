@@ -38,6 +38,45 @@ def test_cell_runs_end_to_end(name):
     assert line["window"]["asked_s"] == 0.3 <= line["window"]["ran_s"]
 
 
+@pytest.mark.parametrize("name, method, kinds_own", [
+    ("serve_closed", "request", "ttft_s"),
+    ("lm_train_1", "step", "steps_in_trace"),
+])
+def test_a_familys_counter_arrives_as_the_windows_difference(
+        monkeypatch, name, method, kinds_own):
+    import importlib
+    family = importlib.import_module(
+        "benchmarks.families." + bench_toy.cell(name).config["family"])
+    program_cls = (family.ServeProgram if name.startswith("serve")
+                   else family.TrainProgram)
+    bench_toy.count_calls(monkeypatch, program_cls, method, "toy_calls")
+    rec = bench_toy.record(name)
+    added = rec.counters["toy_calls"]
+    # set-up made calls of its own (warm rotation; check and warm steps)
+    assert rec.counters["toy_calls_at"] == list(range(
+        rec.counters["toy_calls_at"][0], rec.counters["toy_calls_at"][0]
+        + added))
+    assert rec.counters["toy_calls_at"][0] > 0 and added > 0
+    if name.startswith("serve"):
+        # one request sent for each that finished inside the window
+        assert added == rec.attempted
+        # the family's own arrive the same way, beside the kind's
+        assert len(rec.counters["decode_keys"]) == len(rec.unit_s)
+        assert {"ttft_s", "batch_occupancy", "prefill_tokens",
+                "required_flops"} <= set(rec.counters)
+    else:
+        assert added == rec.counters["steps_in_trace"]
+    # a counter under a name the kind uses itself is refused
+    bench_toy.count_calls(monkeypatch, program_cls, method, kinds_own)
+    with pytest.raises(ValueError, match=kinds_own):
+        bench_toy.record(name)
+    # the run was refused before its window: put away what it had built
+    import horovod_tpu as hvd
+    from horovod_tpu import serving
+    serving.reset_for_tests()
+    hvd.shutdown()
+
+
 def test_same_seed_same_inputs():
     import jax
     import numpy as np

@@ -11,6 +11,10 @@ first-class package because the driver benchmarks the framework through them:
 - ``transformer`` — flagship Transformer LM exercising every parallelism axis
                     (DP/TP/PP/SP/EP) — the reference has only the primitives
                     for these (SURVEY §2.4); we ship the full stack.
+- ``longcat_flash`` — the shortcut-MoE layer with latent attention (two MLA
+                    blocks, two dense SwiGLU FFNs and a top-k expert block
+                    with zero-compute experts a layer), served through
+                    ``ServeEngine`` as one chip's share of its experts.
 """
 
 from horovod_tpu.models.mlp import MLP, MnistCNN  # noqa: F401
@@ -24,3 +28,4 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     TransformerConfig,
     TransformerLM,
 )
+from horovod_tpu.models.longcat_flash import LongCatFlashConfig  # noqa: F401

@@ -194,9 +194,9 @@ def grad_sync_axes(cfg: TransformerConfig) -> Params:
                         is_leaf=lambda x: isinstance(x, P))
 
 
-def _rmsnorm(x: jax.Array, scale: jax.Array) -> jax.Array:
+def _rmsnorm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     x32 = x.astype(jnp.float32)
-    rms = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6)
+    rms = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return (x32 * rms * scale).astype(x.dtype)
 
 

@@ -12,6 +12,15 @@ TPU-native choices: everything is static-shape (capacity buffers instead of
 the reference's dynamic recv-splits — dynamic shapes would force recompiles),
 dispatch/combine are one-hot matmuls (MXU-friendly, the standard TPU MoE
 formulation), and the exchange is a single XLA AllToAll on ICI.
+
+Beside the switch layer (:func:`moe_ffn`) stands the layer a chip runs when
+it holds a SHARE of a top-k expert block (:func:`topk_route`,
+:func:`expert_share_ffn`): the router keeps its full width and its experts
+per token, the chip is told which routed experts it holds (``first``,
+``count``), computes their terms and every zero-compute (identity) term of
+its own tokens, and leaves out the absent experts' terms. No token is
+dropped: an expert's rows are bounded by the tokens present, not by a
+capacity factor. On one chip there is no exchange.
 """
 
 from __future__ import annotations
@@ -117,3 +126,101 @@ def moe_ffn(
     out = jnp.einsum("tec,ecd->td", combine.astype(expert_out.dtype),
                      expert_out)
     return out.reshape(orig_shape), MoEMetrics(aux, dropped)
+
+
+# ---------------------------------------------------------------------------
+# top-k routing over a share of the experts (no capacity, no drop)
+# ---------------------------------------------------------------------------
+
+class TopKRouting(NamedTuple):
+    experts: jax.Array        # [T, k] int32, ids over ALL router outputs
+    gates: jax.Array          # [T, k] float32, scaling * p_e (not renormalised)
+
+
+# Layout of the counters :func:`expert_share_ffn` returns, ``[4 + count]``:
+# assignments to experts held here, to zero-compute experts, to absent
+# experts, held experts that got at least one row, then rows per held expert.
+N_SHARE_TOTALS = 4
+
+
+def topk_route(x: jax.Array, router_w: jax.Array, bias: jax.Array, k: int,
+               scaling: float) -> TopKRouting:
+    """``p = softmax(float32(x) Wr)`` over every router output; the ``k``
+    chosen are the top-k of ``p + bias`` (a learned correction that moves the
+    choice, never the weight); ``g_e = scaling * p_e``, not renormalised
+    over the chosen. float32 at ``highest``: on a TPU a float32 product
+    otherwise runs in one bfloat16 pass, and a rounded score flips a choice.
+    """
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    p = jax.nn.softmax(logits, axis=-1)
+    _, experts = lax.top_k(p + bias.astype(jnp.float32), k)
+    gates = jnp.take_along_axis(p, experts, axis=-1) * scaling
+    return TopKRouting(experts.astype(jnp.int32), gates)
+
+
+def share_gates(routing: TopKRouting, n_routed: int, first: int, count: int
+                ) -> Tuple[jax.Array, jax.Array]:
+    """(``[T, count]`` gate of each held expert, 0 where the token did not
+    choose it; ``[T]`` summed gate of the token's zero-compute experts, ids
+    ``>= n_routed``)."""
+    local = routing.experts - first                               # [T, k]
+    held = (local[..., None] == jnp.arange(count)[None, None, :])
+    g_held = jnp.sum(jnp.where(held, routing.gates[..., None], 0.0), axis=1)
+    g_zero = jnp.sum(jnp.where(routing.experts >= n_routed, routing.gates,
+                               0.0), axis=-1)
+    return g_held, g_zero
+
+
+def share_counts(routing: TopKRouting, n_routed: int, first: int, count: int,
+                 valid: Optional[jax.Array] = None) -> jax.Array:
+    """The routing counters of one call, ``[N_SHARE_TOTALS + count]`` int32
+    (layout above), over the rows ``valid`` marks (padding routes too, and
+    is not counted)."""
+    e = routing.experts
+    rows = jnp.ones(e.shape[:1], bool) if valid is None else valid
+    held = (e >= first) & (e < first + count) & rows[:, None]
+    zero = (e >= n_routed) & rows[:, None]
+    n_held, n_zero = jnp.sum(held), jnp.sum(zero)
+    per_expert = jnp.sum(
+        held[..., None] & ((e - first)[..., None]
+                           == jnp.arange(count)[None, None, :]), axis=(0, 1))
+    totals = jnp.stack([n_held, n_zero,
+                        jnp.sum(rows) * e.shape[1] - n_held - n_zero,
+                        jnp.sum(per_expert > 0)])
+    return jnp.concatenate([totals, per_expert]).astype(jnp.int32)
+
+
+def expert_share_ffn(x: jax.Array, routing: TopKRouting, w_gate: jax.Array,
+                     w_up: jax.Array, w_down: jax.Array, *, n_routed: int,
+                     first: int = 0) -> jax.Array:
+    """This chip's part of a top-k SwiGLU expert block with zero-compute
+    identity experts::
+
+        s = sum_{chosen e held here} g_e SwiGLU_e(x) + sum_{chosen e >= n_routed} g_e x
+
+    x ``[T, D]``; ``w_gate`` / ``w_up`` ``[count, D, F]`` and ``w_down``
+    ``[count, F, D]`` are routed experts ``first .. first + count``. The
+    terms of routed experts held elsewhere are left out; summed over all the
+    shares, with the identity terms counted once, the parts give the whole
+    layer. A masked product: every held expert runs over every row present
+    and the gate (0 where the token chose another) folds into its
+    activation, so the down projection is ONE product over ``count * F`` and
+    no token can overflow a buffer. At a few rows an expert it streams the
+    same weights a grouped product would; at a prefill chunk of T rows it
+    does ``count * T`` row-products where about ``T * k * count / E`` are
+    needed. Scopes: ``hvd_moe_experts`` (the products), ``hvd_moe_combine``
+    (the gates of the held experts and the identity term).
+    """
+    count = w_gate.shape[0]
+    with jax.named_scope("hvd_moe_combine"):
+        g_held, g_zero = share_gates(routing, n_routed, first, count)
+    with jax.named_scope("hvd_moe_experts"):
+        hg = jnp.einsum("td,edf->tef", x, w_gate.astype(x.dtype))
+        hu = jnp.einsum("td,edf->tef", x, w_up.astype(x.dtype))
+        act = (jax.nn.silu(hg.astype(jnp.float32)) * hu.astype(jnp.float32)
+               * g_held[..., None]).astype(x.dtype)
+        s = jnp.einsum("tef,efd->td", act, w_down.astype(x.dtype),
+                       preferred_element_type=jnp.float32)
+    with jax.named_scope("hvd_moe_combine"):
+        return s + g_zero[:, None] * x.astype(jnp.float32)

@@ -1,4 +1,6 @@
-"""AOT prefill/decode serving engine for the flagship TransformerLM.
+"""AOT prefill/decode serving engine for the flagship TransformerLM, and
+for any model that hands the engine its own step bodies and cache rows
+(:class:`ServeModel`; ``models/longcat_flash.py`` does).
 
 The inference twin of ``parallel/trainer.py``: the same parameter tree,
 RoPE, norms and TP decomposition as the training forward
@@ -30,8 +32,10 @@ pipeline parallelism are training-side concerns and are rejected here.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, \
+    Union
 
 import numpy as np
 
@@ -306,61 +310,135 @@ def _prefill_body(cfg: tfm.TransformerConfig, params: Any,
 
 
 # ---------------------------------------------------------------------------
+# what the engine asks of a model
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class ServeModel:
+    """A model as the engine sees it. The engine owns slots, pages, block
+    tables, the AOT/store plumbing and the program names; the model says
+    what one token caches and what a step computes.
+
+    ``decode(cfg, params, *pool, *state, block_tables, lengths, tokens)``
+    and ``prefill(cfg, params, *pool, *state, block_table, start, n_real,
+    tokens)`` return ``(*pool, *state, next_token(s), logits)``: ``pool``
+    one array per :class:`~horovod_tpu.serving.kv_cache.CacheRows` of
+    ``cache_rows(cfg)``, each ``[blocks, n_pages + 1, page, *row]``;
+    ``state`` the further device arrays of ``state(cfg)`` (running
+    counters), donated and handed on like the pool but left to the
+    compiler's layout. ``check(cfg, draft_mode)`` refuses what the model
+    cannot serve, in ``ValueError``'s words; ``stats(cfg, state)`` is
+    what ``ServeEngine.stats()`` publishes of ``state`` (the one place
+    it is read back). ``draft`` (the decode body over the first
+    ``n_layers`` layers) only where the model offers ``truncate:N``."""
+    check: Callable[[Any, str], None]
+    cache_rows: Callable[[Any], Tuple[kvc.CacheRows, ...]]
+    decode: Callable[..., Tuple]
+    prefill: Callable[..., Tuple]
+    param_specs: Callable[[Any], Any]
+    state: Callable[[Any], Tuple[jax.ShapeDtypeStruct, ...]] = lambda cfg: ()
+    stats: Optional[Callable[[Any, Tuple], Dict[str, Any]]] = None
+    draft: Optional[Callable[..., Tuple]] = None
+
+
+def _dense_rows(cfg: tfm.TransformerConfig) -> Tuple[kvc.CacheRows, ...]:
+    return kvc.dense_rows(cfg.n_layers, cfg.n_heads, cfg.head_dim)
+
+
+def _dense_draft(cfg, n_layers, *args):
+    return _decode_body(cfg, *args, n_layers=n_layers)
+
+
+DENSE = ServeModel(
+    check=lambda cfg, draft_mode: _check_cfg(cfg), cache_rows=_dense_rows,
+    decode=_decode_body, prefill=_prefill_body,
+    param_specs=tfm.param_specs, draft=_dense_draft)
+
+
+def serve_model(cfg: Any) -> ServeModel:
+    """The dense block for a ``TransformerConfig``; any other config
+    brings its own (``cfg.serve_model()``)."""
+    if isinstance(cfg, tfm.TransformerConfig):
+        return DENSE
+    own = getattr(cfg, "serve_model", None)
+    if own is None:
+        raise TypeError(
+            f"serving needs a TransformerConfig or a config with a "
+            f"serve_model() of its own, got {type(cfg).__name__}")
+    return own()
+
+
+# ---------------------------------------------------------------------------
 # engine
 # ---------------------------------------------------------------------------
 
 def _named_jit(fn: Callable, name: str, pool_args: Tuple[int, ...],
-               n_args: int, n_out: int, fmt: Format):
+               n_args: int, n_out: int, fmts: Sequence[Format],
+               state_args: Tuple[int, ...] = ()):
     """``jax.jit`` of a step function under a name of its own: the
     compiled module, and so the ``XLA Modules`` line of a device trace,
     reads ``jit_<name>`` where a ``functools.partial`` or a ``shard_map``
     wrapper would read ``jit__unknown`` — what a trace reader tells the
     engine's programs apart by.
 
-    Every engine program takes the pool (K, V) at ``pool_args``, donated,
-    and returns it first; both sides are pinned to the pool's one
-    ``fmt`` so no program converts the pool's layout on its way in or
-    out. The other arguments and results are left to the compiler."""
+    Every engine program takes the pool (the dense block's: K, V) at
+    ``pool_args``, donated, and returns it first; both sides are pinned
+    to the pool's ``fmts`` (one for each of its arrays) so no program
+    converts the pool's layout on its way in or out. A model's
+    further state (``state_args``) is donated too and follows the pool.
+    The other arguments and results are left to the compiler."""
     def named(*args):
         return fn(*args)
     named.__name__ = named.__qualname__ = name
+    fmts = tuple(fmts)
+    at = dict(zip(pool_args, fmts))
     return jax.jit(
-        named, donate_argnums=pool_args,
-        in_shardings=tuple(fmt if i in pool_args else None
-                           for i in range(n_args)),
-        out_shardings=(fmt, fmt) + (None,) * (n_out - 2))
+        named, donate_argnums=pool_args + state_args,
+        in_shardings=tuple(at.get(i) for i in range(n_args)),
+        out_shardings=fmts + (None,) * (n_out - len(fmts)))
 
 
-def serve_programs(cfg: tfm.TransformerConfig, fmt: Format,
+def serve_programs(cfg: Any, fmt: Union[Format, Sequence[Format]],
                    mesh: Optional[Mesh] = None,
                    draft_layers: Optional[int] = None
                    ) -> Dict[str, Callable]:
-    """The engine's jitted program families over a pool held in ``fmt``:
-    ``decode`` (also the verify step, at another batch), ``prefill``
-    (every bucket), ``cow`` and, with ``draft_layers``, ``draft``.
-    Shard_map'd over ``mesh`` when ``cfg.tp_axis`` is set, plain
-    otherwise."""
-    # name -> (function, where K stands among its arguments (V follows;
-    # the parameters lead when that is 1), arguments, results)
-    table = {"decode": (functools.partial(_decode_body, cfg), 1, 6, 4),
-             "prefill": (functools.partial(_prefill_body, cfg), 1, 7, 4),
-             "cow": (kvc.copy_page, 0, 4, 2)}
+    """The engine's jitted program families over a pool held in ``fmt``
+    (one format for every array of the pool, or one each): ``decode``
+    (also the verify step, at another batch), ``prefill`` (every
+    bucket), ``cow`` and, with ``draft_layers``, ``draft``. Shard_map'd
+    over ``mesh`` when ``cfg.tp_axis`` is set, plain otherwise."""
+    model = serve_model(cfg)
+    n_pool = len(model.cache_rows(cfg))
+    n_state = len(model.state(cfg))
+    held = n_pool + n_state
+    fmts = (fmt,) * n_pool if isinstance(fmt, Format) else tuple(fmt)
+    # name -> (function, where the pool's first array stands among its
+    # arguments (the others and the state follow; the parameters lead
+    # when that is 1), arguments, results)
+    table = {"decode": (functools.partial(model.decode, cfg), 1,
+                        held + 4, held + 2),
+             "prefill": (functools.partial(model.prefill, cfg), 1,
+                         held + 5, held + 2),
+             "cow": (kvc.copy_page, 0, n_pool + 2, n_pool)}
     if draft_layers:
-        table["draft"] = (functools.partial(
-            _decode_body, cfg, n_layers=draft_layers), 1, 6, 4)
+        table["draft"] = (functools.partial(model.draft, cfg, draft_layers),
+                          1, held + 4, held + 2)
     programs = {}
     for name, (fn, k_at, n_args, n_out) in table.items():
+        pool_args = tuple(range(k_at, k_at + n_pool))
         if cfg.tp_axis and mesh is not None:
             from horovod_tpu.eager import shard_map
-            kv = fmt.sharding.spec
+            kv = tuple(f.sharding.spec for f in fmts)
             in_specs = [P()] * n_args
-            in_specs[k_at] = in_specs[k_at + 1] = kv
+            in_specs[k_at:k_at + n_pool] = kv
             if k_at:
-                in_specs[0] = tfm.param_specs(cfg)
+                in_specs[0] = model.param_specs(cfg)
             fn = shard_map(fn, mesh, in_specs=tuple(in_specs),
-                           out_specs=(kv, kv) + (P(),) * (n_out - 2))
-        programs[name] = _named_jit(fn, f"hvd_serve_{name}",
-                                    (k_at, k_at + 1), n_args, n_out, fmt)
+                           out_specs=kv + (P(),) * (n_out - n_pool))
+        programs[name] = _named_jit(
+            fn, f"hvd_serve_{name}", pool_args, n_args, n_out, fmts,
+            state_args=(tuple(range(k_at + n_pool, k_at + held))
+                        if k_at else ()))
     return programs
 
 
@@ -393,7 +471,12 @@ class ServeEngine:
                  prefix_cache: Optional[bool] = None,
                  draft: Optional[str] = None,
                  spec_k: Optional[int] = None):
-        _check_cfg(cfg)
+        self.model = model = serve_model(cfg)
+        self.draft_spec = str(
+            knobs.get("HOROVOD_SERVE_DRAFT") if draft is None else draft)
+        self.draft_mode, self.draft_n = _parse_draft(
+            self.draft_spec, cfg.n_layers)
+        model.check(cfg, self.draft_mode)
         # a replica need not go through hvd.init(): HOROVOD_TRACE=1 turns
         # the recorder on here too (a no-op when it is on already)
         trace.init_from_env()
@@ -416,10 +499,6 @@ class ServeEngine:
         self.prefix_cache = bool(
             knobs.get("HOROVOD_SERVE_PREFIX_CACHE")
             if prefix_cache is None else prefix_cache)
-        self.draft_spec = str(
-            knobs.get("HOROVOD_SERVE_DRAFT") if draft is None else draft)
-        self.draft_mode, self.draft_n = _parse_draft(
-            self.draft_spec, cfg.n_layers)
         self.spec_k = (int(spec_k if spec_k is not None
                            else knobs.get("HOROVOD_SERVE_SPEC_K"))
                        if self.draft_mode != "off" else 0)
@@ -436,9 +515,9 @@ class ServeEngine:
                 f"n_heads={cfg.n_heads} not divisible by tp="
                 f"{self._tp_size}")
 
+        rows = model.cache_rows(cfg)
         self.pool = kvc.PagePool(cfg.n_layers, pool_pages, self.page,
-                                 cfg.n_heads, cfg.head_dim,
-                                 dtype=cfg.dtype)
+                                 dtype=cfg.dtype, rows=rows)
         self.allocator = kvc.PageAllocator(pool_pages)
         self.tables = kvc.BlockTables(self.slots, self.n_max_pages,
                                       self.pool.scratch_page)
@@ -454,31 +533,43 @@ class ServeEngine:
         # over KV heads under TP, otherwise params and pages replicated
         # over it (a one-device mesh pins the replica to that chip);
         # without a mesh, on the device the caller's params already live.
+        # The parameters stay in the dtype they are given in: a model
+        # served from bfloat16 leaves holds no float32 copy and its
+        # programs cast nothing.
         if tp and mesh is not None:
-            kv_sharding = NamedSharding(mesh, P(None, None, None, tp, None))
-            pspecs = tfm.param_specs(cfg)
+            def over_tp(r: kvc.CacheRows) -> NamedSharding:
+                spec = [None] * (3 + len(r.row))
+                if r.tp_axis is not None:
+                    spec[3 + r.tp_axis] = tp
+                return NamedSharding(mesh, P(*spec))
+            kv_shardings = [over_tp(r) for r in rows]
+            pspecs = model.param_specs(cfg)
             self.params = jax.device_put(params, jax.tree.map(
                 lambda s: NamedSharding(mesh, s), pspecs,
                 is_leaf=lambda x: isinstance(x, P)))
         elif mesh is not None:
-            kv_sharding = NamedSharding(mesh, P())
-            self.params = jax.device_put(params, kv_sharding)
+            kv_shardings = [NamedSharding(mesh, P())] * len(rows)
+            self.params = jax.device_put(params, kv_shardings[0])
         else:
             self.params = params = jax.tree.map(jnp.asarray, params)
-            kv_sharding = SingleDeviceSharding(
-                next(iter(jax.tree.leaves(params)[0].devices())))
+            kv_shardings = [SingleDeviceSharding(
+                next(iter(jax.tree.leaves(params)[0].devices())))] * len(rows)
         # the pool's one layout and placement: allocated in it, and
         # pinned on every program that takes or returns the pool
-        self.pool_format = kvc.pool_format(kv_sharding)
+        self.pool_formats = tuple(
+            kvc.pool_format(sh, 3 + len(r.row))
+            for sh, r in zip(kv_shardings, rows))
+        self.pool_format = self.pool_formats[0]
         # Where a reloaded executable loses the pinned layout of its
         # results, every program that makes or returns the pool is
         # compiled in this process: past JAX's persistent cache and
         # past the artifact store.
         from horovod_tpu.store import artifact_store as store_mod
+        probe_row = list(rows[0].row)
+        if rows[0].tp_axis is not None:
+            probe_row[rows[0].tp_axis] //= self._tp_size
         self.reload_keeps_layout = store_mod.reload_keeps_layout(
-            self.pool_format,
-            (1, 1, self.page, cfg.n_heads // self._tp_size, cfg.head_dim),
-            cfg.dtype)
+            self.pool_format, (1, 1, self.page, *probe_row), cfg.dtype)
         if not self.reload_keeps_layout:
             logger.warning(
                 "serve: a reloaded executable does not keep the KV "
@@ -486,7 +577,7 @@ class ServeEngine:
                 "engine's programs in process (no persistent compile "
                 "cache, no artifact store) at every engine build")
         programs = serve_programs(
-            cfg, self.pool_format, mesh,
+            cfg, self.pool_formats, mesh,
             self.draft_n if self.draft_mode == "truncate" else None)
         self._decode_jit = programs["decode"]
         self._prefill_jit = programs["prefill"]
@@ -519,8 +610,15 @@ class ServeEngine:
         truncated-layer draft step, and the COW page copy. `builds`
         counts actual compiles — the warm-boot gate asserts it stays 0
         on a warm store, new executables included."""
-        self.k_pages, self.v_pages = self.pool.alloc_arrays(
-            self.pool_format)
+        self.pools: Tuple[jax.Array, ...] = self.pool.alloc_arrays(
+            self.pool_formats)
+        # a model's further state: replicated where the pool lives
+        sharding = self.pool_format.sharding
+        if isinstance(sharding, NamedSharding):
+            sharding = NamedSharding(sharding.mesh, P())
+        self.state: Tuple[jax.Array, ...] = tuple(
+            jax.device_put(jnp.zeros(s.shape, s.dtype), sharding)
+            for s in self.model.state(self.cfg))
         self._decode = self._adopt(
             self._decode_jit, self._decode_args(), "serve_decode")
         self._prefill: Dict[int, Callable] = {}
@@ -547,15 +645,19 @@ class ServeEngine:
     # Programs are lowered from shapes: a concrete donated example would
     # have to be a second pool.
     def _pool_args(self) -> Tuple:
-        return (_abstract(self.k_pages), _abstract(self.v_pages))
+        return tuple(_abstract(a) for a in self.pools)
+
+    def _held_args(self) -> Tuple:
+        """The pool and the model's further state, as a step takes them."""
+        return tuple(_abstract(a) for a in self.pools + self.state)
 
     def _decode_args(self, rows: Optional[int] = None) -> Tuple:
         rows = rows or self.slots
-        return (jax.tree.map(_abstract, self.params), *self._pool_args(),
+        return (jax.tree.map(_abstract, self.params), *self._held_args(),
                 _i32(rows, self.n_max_pages), _i32(rows), _i32(rows))
 
     def _prefill_args(self, bucket: int) -> Tuple:
-        return (jax.tree.map(_abstract, self.params), *self._pool_args(),
+        return (jax.tree.map(_abstract, self.params), *self._held_args(),
                 _i32(self.n_max_pages), _i32(), _i32(), _i32(bucket))
 
     def _cow_args(self) -> Tuple:
@@ -588,6 +690,26 @@ class ServeEngine:
                 compiled.memory_analysis().temp_size_in_bytes)
         self._dispatch[label] = wrapped
         return wrapped
+
+    # -- the device state a step takes and hands back ------------------------
+    @property
+    def k_pages(self) -> jax.Array:
+        """The dense block's K pool (the pool's first array)."""
+        return self.pools[0]
+
+    @property
+    def v_pages(self) -> jax.Array:
+        """The dense block's V pool (the pool's second array)."""
+        return self.pools[1]
+
+    def _step(self, program: Callable, *args) -> Tuple:
+        """Run a step program on the parameters, the pool and the
+        model's state; keep what it hands back of the last two and
+        return the rest (next tokens, logits)."""
+        out = program(self.params, *self.pools, *self.state, *args)
+        n_pool, held = len(self.pools), len(self.pools) + len(self.state)
+        self.pools, self.state = tuple(out[:n_pool]), tuple(out[n_pool:held])
+        return out[held:]
 
     def executable_text(self, label: str = "serve_decode") -> str:
         """Compiled HLO text of one of the engine's AOT executables (the
@@ -645,10 +767,9 @@ class ServeEngine:
             # prefill overwrites it from the divergence point on.
             src, t = cow
             self.allocator.incref(src)
-            self.k_pages, self.v_pages = self._cow(
-                self.k_pages, self.v_pages,
-                jnp.asarray(src, jnp.int32),
-                jnp.asarray(tail[0], jnp.int32))
+            self.pools = tuple(self._cow(
+                *self.pools, jnp.asarray(src, jnp.int32),
+                jnp.asarray(tail[0], jnp.int32)))
             self.allocator.decref(src)
             self.cow_copies += 1
             skip += t
@@ -704,9 +825,8 @@ class ServeEngine:
             bt_row = jnp.asarray(self.tables.tables[slot])
             chunk = np.zeros((bucket,), np.int32)
             chunk[:n_real] = prompt[start:start + n_real]
-            self.k_pages, self.v_pages, tok, _ = self._prefill[bucket](
-                self.params, self.k_pages, self.v_pages, bt_row,
-                jnp.asarray(start, jnp.int32),
+            tok, _ = self._step(
+                self._prefill[bucket], bt_row, jnp.asarray(start, jnp.int32),
                 jnp.asarray(n_real, jnp.int32), jnp.asarray(chunk))
         start += n_real
         if start < prompt.size:
@@ -759,9 +879,8 @@ class ServeEngine:
                 ln_np = ln_np.copy()
                 bt_np[~active] = self.pool.scratch_page
                 ln_np[~active] = 0
-            self.k_pages, self.v_pages, nxt, _ = self._decode(
-                self.params, self.k_pages, self.v_pages,
-                jnp.asarray(bt_np), jnp.asarray(ln_np),
+            nxt, _ = self._step(
+                self._decode, jnp.asarray(bt_np), jnp.asarray(ln_np),
                 jnp.asarray(np.asarray(tokens, np.int32)))
         # Read the result back BEFORE touching the host tables: the
         # dispatch is asynchronous and jnp.asarray may alias the NumPy
@@ -795,9 +914,8 @@ class ServeEngine:
         toks[~active] = 0
         for i in range(k):
             with trace.span("engine.draft.dispatch", cat=trace.CAT_SERVE):
-                self.k_pages, self.v_pages, nxt, _ = self._draft(
-                    self.params, self.k_pages, self.v_pages,
-                    jnp.asarray(bt_np), jnp.asarray(ln_np),
+                nxt, _ = self._step(
+                    self._draft, jnp.asarray(bt_np), jnp.asarray(ln_np),
                     jnp.asarray(toks))
             with trace.span("engine.draft.wait", cat=trace.CAT_SERVE):
                 nxt = np.asarray(nxt)
@@ -844,10 +962,9 @@ class ServeEngine:
             bt[~row_active] = self.pool.scratch_page
             ln[~row_active] = 0
             toks[~row_active] = 0
-            self.k_pages, self.v_pages, nxt, _ = self._verify(
-                self.params, self.k_pages, self.v_pages,
-                jnp.asarray(bt), jnp.asarray(ln.astype(np.int32)),
-                jnp.asarray(toks))
+            nxt, _ = self._step(
+                self._verify, jnp.asarray(bt),
+                jnp.asarray(ln.astype(np.int32)), jnp.asarray(toks))
         self.tables.lengths[active] += k + 1
         with trace.span("engine.verify.wait", cat=trace.CAT_SERVE):
             return np.asarray(nxt).reshape(self.slots, k + 1)
@@ -873,7 +990,10 @@ class ServeEngine:
 
     def stats(self) -> Dict[str, Any]:
         free = self.allocator.free_pages
+        own = ({} if self.model.stats is None
+               else self.model.stats(self.cfg, self.state))
         return {
+            **own,
             "slots": self.slots,
             "occupied": sum(1 for p in self.slot_pages if p is not None),
             "page": self.page,

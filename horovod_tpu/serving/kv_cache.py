@@ -13,8 +13,11 @@ the fragmentation that caps batch size in the contiguous layout is gone.
 Split of responsibilities:
 
 - **Device state** (inside the AOT-compiled steps): the page pool
-  arrays, ``[n_layers, n_pages + 1, page, n_kv_heads, head_dim]``,
-  donated to every step. The engine keeps them in ONE row-major layout
+  arrays, ``[n_layers, n_pages + 1, page, n_kv_heads, head_dim]`` for K
+  and for V — or, for a model that describes a cache of its own
+  (:class:`CacheRows`: what one token stores in each cached block),
+  ``[blocks, n_pages + 1, page, *row]`` — donated to every step. The
+  engine keeps them in ONE row-major layout
   (:func:`pool_format`) and its step bodies address them as one flat
   run of pages, layer ``l``'s page ``p`` at ``l * (n_pages + 1) + p``,
   so a step's only pool-shaped instructions are in-place scatters
@@ -45,7 +48,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, Optional, Sequence, Set, Tuple, Union
 
 import numpy as np
 
@@ -54,22 +57,52 @@ import jax.numpy as jnp
 from jax.experimental.layout import Format, Layout
 
 
-def pool_format(sharding: jax.sharding.Sharding) -> Format:
-    """The one layout the 5-D page pool lives in, from allocation to the
-    decode kernel's DMA: row-major over ``sharding``. A Pallas operand
-    and a scatter are row-major on TPU, while the compiler's own choice
-    for the pool's shape puts a page's tokens on the lanes — left free,
-    every step converts each layer's pool there and back. The CPU
-    backend is row-major anyway."""
-    return Format(Layout(major_to_minor=(0, 1, 2, 3, 4)), sharding)
+def pool_format(sharding: jax.sharding.Sharding, ndim: int = 5) -> Format:
+    """The one layout a page-pool array lives in, from allocation to the
+    decode kernel's DMA: row-major over ``sharding`` (``ndim`` axes: 5
+    for a K or V array, 3 + the axes of a model's own cache row). A
+    Pallas operand and a scatter are row-major on TPU, while the
+    compiler's own choice for the pool's shape puts a page's tokens on
+    the lanes — left free, every step converts each layer's pool there
+    and back. The CPU backend is row-major anyway."""
+    return Format(Layout(major_to_minor=tuple(range(ndim))), sharding)
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheRows:
+    """One array of a page pool, by what ONE token stores in it: ``row``
+    numbers (a shape) in each of ``blocks`` cached blocks — an attention
+    block that caches; a dense layer is one block of K rows
+    ``(n_kv_heads, head_dim)`` and one of V, a layer of two
+    latent-attention blocks is two blocks of one ``(576,)`` row and no
+    V. The array is ``[blocks, n_pages + 1, page, *row]``. ``tp_axis``:
+    which axis of ``row`` tensor parallelism splits, if any."""
+    name: str
+    blocks: int
+    row: Tuple[int, ...]
+    tp_axis: Optional[int] = None
+
+
+def dense_rows(n_layers: int, n_kv_heads: int, head_dim: int
+               ) -> Tuple[CacheRows, ...]:
+    """The dense block's pool: a K and a V array, each one
+    ``(n_kv_heads, head_dim)`` row a token and layer, heads over tp."""
+    kv = (int(n_kv_heads), int(head_dim))
+    return (CacheRows("k", int(n_layers), kv, tp_axis=0),
+            CacheRows("v", int(n_layers), kv, tp_axis=0))
 
 
 class PagePool:
     """Static geometry of the paged cache (all sizes fixed at engine
-    build time — they key the compiled serve executables)."""
+    build time — they key the compiled serve executables). The dense
+    block's pool is a K and a V array of ``(n_kv_heads, head_dim)`` rows
+    in each of ``n_layers`` blocks; a model with a cache of its own
+    describes it with ``rows=`` (:class:`CacheRows`, one per array) and
+    the allocator, block tables and prefix index serve it unchanged."""
 
     def __init__(self, n_layers: int, n_pages: int, page: int,
-                 n_kv_heads: int, head_dim: int, dtype=jnp.float32):
+                 n_kv_heads: int = 0, head_dim: int = 0, dtype=jnp.float32,
+                 rows: Optional[Sequence[CacheRows]] = None):
         if n_pages < 1 or page < 1:
             raise ValueError(
                 f"page pool needs n_pages>=1 and page>=1, got "
@@ -80,36 +113,46 @@ class PagePool:
         self.n_kv_heads = int(n_kv_heads)
         self.head_dim = int(head_dim)
         self.dtype = dtype
+        if rows is None:
+            rows = dense_rows(self.n_layers, self.n_kv_heads, self.head_dim)
+        self.rows: Tuple[CacheRows, ...] = tuple(rows)
 
     @property
     def scratch_page(self) -> int:
         """Physical id of the write sink for padded/empty positions."""
         return self.n_pages
 
-    def alloc_arrays(self, fmt: Optional[Format] = None
-                     ) -> Tuple[jax.Array, jax.Array]:
-        """Zeroed (k_pages, v_pages), each
-        ``[n_layers, n_pages + 1, page, n_kv_heads, head_dim]`` (the +1
-        is the scratch page), made in place in ``fmt`` (layout and
-        sharding — the engine's :func:`pool_format`; under tensor
+    def shapes(self) -> Tuple[Tuple[int, ...], ...]:
+        """Each array's shape, ``[blocks, n_pages + 1, page, *row]`` (the
+        +1 is the scratch page)."""
+        return tuple((r.blocks, self.n_pages + 1, self.page) + tuple(r.row)
+                     for r in self.rows)
+
+    def alloc_arrays(self, fmt: Union[None, Format, Sequence[Format]] = None
+                     ) -> Tuple[jax.Array, ...]:
+        """The zeroed arrays of the pool, one per :class:`CacheRows` (the
+        dense block's: ``(k_pages, v_pages)``, each
+        ``[n_layers, n_pages + 1, page, n_kv_heads, head_dim]``), made in
+        place in ``fmt`` (layout and sharding — the engine's
+        :func:`pool_format`, one for all or one each; under tensor
         parallelism its sharding splits the KV-head axis) so no second
         pool-sized buffer exists even while allocating."""
-        shape = (self.n_layers, self.n_pages + 1, self.page,
-                 self.n_kv_heads, self.head_dim)
+        shapes = self.shapes()
         if fmt is None:
-            return jnp.zeros(shape, self.dtype), jnp.zeros(shape, self.dtype)
-        zeros = jax.jit(lambda: jnp.zeros(shape, self.dtype),
-                        out_shardings=fmt)
-        return zeros(), zeros()
+            return tuple(jnp.zeros(shape, self.dtype) for shape in shapes)
+        fmts = [fmt] * len(shapes) if isinstance(fmt, Format) else list(fmt)
+        return tuple(
+            jax.jit(lambda shape=shape: jnp.zeros(shape, self.dtype),
+                    out_shardings=f)()
+            for shape, f in zip(shapes, fmts))
 
     def pages_for(self, n_tokens: int) -> int:
         return -(-max(int(n_tokens), 0) // self.page)
 
     def nbytes(self) -> int:
-        """HBM the pool holds (both K and V, scratch page included)."""
+        """HBM the pool holds (every array, scratch page included)."""
         itemsize = jnp.dtype(self.dtype).itemsize
-        return (2 * self.n_layers * (self.n_pages + 1) * self.page
-                * self.n_kv_heads * self.head_dim * itemsize)
+        return sum(int(np.prod(shape)) for shape in self.shapes()) * itemsize
 
 
 def _pool_gauges():
@@ -426,18 +469,28 @@ def write_token_kv(k_pages: jax.Array, v_pages: jax.Array,
     single layer's pool by default; over the engine's flat pool the
     block tables carry the layer's offset and the caller names the
     layer's own scratch page."""
-    page = k_pages.shape[1]
+    return write_token_rows((k_pages, v_pages), (k_new, v_new), block_tables,
+                            positions, valid=valid, scratch=scratch)
+
+
+def write_token_rows(pages: Sequence[jax.Array], new: Sequence[jax.Array],
+                     block_tables: jax.Array, positions: jax.Array,
+                     valid: Optional[jax.Array] = None,
+                     scratch: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, ...]:
+    """:func:`write_token_kv` for however many arrays one block of the
+    pool has: ``pages[i]`` ``[n_phys, page, *row_i]`` takes ``new[i]``
+    ``[B, *row_i]``, all at the same page and offset."""
+    page = pages[0].shape[1]
     if scratch is None:
-        scratch = k_pages.shape[0] - 1
+        scratch = pages[0].shape[0] - 1
     logical = positions // page
     phys = jnp.take_along_axis(block_tables, logical[:, None],
                                axis=1)[:, 0]
     offs = positions % page
     if valid is not None:
         phys = jnp.where(valid, phys, scratch)
-    k_pages = k_pages.at[phys, offs].set(k_new)
-    v_pages = v_pages.at[phys, offs].set(v_new)
-    return k_pages, v_pages
+    return tuple(p.at[phys, offs].set(n) for p, n in zip(pages, new))
 
 
 def write_chunk_kv(k_pages: jax.Array, v_pages: jax.Array,
@@ -451,40 +504,49 @@ def write_chunk_kv(k_pages: jax.Array, v_pages: jax.Array,
     ``start .. start + C``; positions at or past ``start + n_real`` are
     padding and land on the scratch page (``scratch``, as in
     :func:`write_token_kv`). block_table ``[n_max]``."""
-    page = k_pages.shape[1]
+    return write_chunk_rows((k_pages, v_pages), (k_new, v_new), block_table,
+                            start, n_real, scratch=scratch)
+
+
+def write_chunk_rows(pages: Sequence[jax.Array], new: Sequence[jax.Array],
+                     block_table: jax.Array, start: jax.Array,
+                     n_real: jax.Array, scratch: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, ...]:
+    """:func:`write_chunk_kv` for however many arrays one block of the
+    pool has (``new[i]`` ``[C, *row_i]``)."""
+    page = pages[0].shape[1]
     if scratch is None:
-        scratch = k_pages.shape[0] - 1
-    c = k_new.shape[0]
+        scratch = pages[0].shape[0] - 1
+    c = new[0].shape[0]
     pos = start + jnp.arange(c, dtype=jnp.int32)
     phys = jnp.take(block_table, pos // page, mode="clip")
     phys = jnp.where(jnp.arange(c) < n_real, phys, scratch)
     offs = pos % page
-    k_pages = k_pages.at[phys, offs].set(k_new)
-    v_pages = v_pages.at[phys, offs].set(v_new)
-    return k_pages, v_pages
+    return tuple(p.at[phys, offs].set(n) for p, n in zip(pages, new))
 
 
-def copy_page(k_pages: jax.Array, v_pages: jax.Array,
-              src: jax.Array, dst: jax.Array
-              ) -> Tuple[jax.Array, jax.Array]:
-    """Device-side copy-on-write body: duplicate ONE physical page
-    across every layer (k_pages/v_pages ``[L, n_phys, page, KVH, D]``,
-    src/dst scalar int32). One executable covers every (src, dst) pair
-    — the ids are runtime operands, so admission-time COW never
+def copy_page(*pages_src_dst: jax.Array) -> Tuple[jax.Array, ...]:
+    """Device-side copy-on-write body, ``copy_page(*pages, src, dst)``:
+    duplicate ONE physical page across every block of every array of the
+    pool (the dense block's: k_pages/v_pages ``[L, n_phys, page, KVH,
+    D]``; src/dst scalar int32). One executable covers every (src, dst)
+    pair — the ids are runtime operands, so admission-time COW never
     compiles. Donated by the engine and jitted in the pool's one layout:
     the update is in place."""
-    k_pages = k_pages.at[:, dst].set(k_pages[:, src])
-    v_pages = v_pages.at[:, dst].set(v_pages[:, src])
-    return k_pages, v_pages
+    *pages, src, dst = pages_src_dst
+    return tuple(p.at[:, dst].set(p[:, src]) for p in pages)
 
 
 def gather_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
-    """Contiguous ``[n_max*page, KVH, D]`` view of one sequence's pages
-    (single layer) in block-table order — the prefill attention context
-    (prefill is compute-bound; the gather copy is irrelevant there,
-    unlike at decode where the kernel follows the table in place)."""
-    g = jnp.take(pages, block_table, axis=0)      # [n_max, page, KVH, D]
-    return g.reshape((-1,) + g.shape[2:])
+    """Contiguous ``[n_max*page, *row]`` view of one sequence's pages
+    (one block of the pool; the dense block's rows are ``KVH, D``) in
+    block-table order — the prefill attention context (prefill is
+    compute-bound; the gather copy is irrelevant there, unlike at decode
+    where the kernel follows the table in place). With block tables
+    ``[B, n_max]``: ``[B, n_max*page, *row]``, one view per sequence."""
+    g = jnp.take(pages, block_table, axis=0)      # [.., n_max, page, *row]
+    lead = block_table.shape[:-1]
+    return g.reshape(lead + (-1,) + g.shape[len(lead) + 2:])
 
 
 def paged_attention_reference(q: jax.Array, k_pages: jax.Array,

@@ -330,12 +330,19 @@ class ServeModel:
     cannot serve, in ``ValueError``'s words; ``stats(cfg, state)`` is
     what ``ServeEngine.stats()`` publishes of ``state`` (the one place
     it is read back). ``draft`` (the decode body over the first
-    ``n_layers`` layers) only where the model offers ``truncate:N``."""
+    ``n_layers`` layers) only where the model offers ``truncate:N``.
+
+    ``served_params(cfg, placed)`` is the tree the programs read, made
+    once at engine build from the tree as placed on the device: the
+    same structure, each leaf either the placed array itself or a copy
+    of it in another dtype under the same sharding (:func:`cast_once`).
+    Absent, the programs read the tree as given."""
     check: Callable[[Any, str], None]
     cache_rows: Callable[[Any], Tuple[kvc.CacheRows, ...]]
     decode: Callable[..., Tuple]
     prefill: Callable[..., Tuple]
     param_specs: Callable[[Any], Any]
+    served_params: Optional[Callable[[Any, Any], Any]] = None
     state: Callable[[Any], Tuple[jax.ShapeDtypeStruct, ...]] = lambda cfg: ()
     stats: Optional[Callable[[Any, Tuple], Dict[str, Any]]] = None
     draft: Optional[Callable[..., Tuple]] = None
@@ -349,10 +356,42 @@ def _dense_draft(cfg, n_layers, *args):
     return _decode_body(cfg, *args, n_layers=n_layers)
 
 
+def cast_once(x: Any, dtype: Any) -> Any:
+    """``x`` in ``dtype`` where it lives: the array itself when it is in
+    ``dtype`` already (no copy), else one cast on the device that keeps
+    its sharding. ``x`` is neither donated nor deleted. A
+    ``ShapeDtypeStruct`` (what a compile-only lowering has of a tree) is
+    answered with one."""
+    if x.dtype == dtype:
+        return x
+    if isinstance(x, jax.ShapeDtypeStruct):
+        return jax.ShapeDtypeStruct(x.shape, dtype, sharding=x.sharding)
+    return jax.jit(lambda a: a.astype(dtype), out_shardings=x.sharding)(x)
+
+
+# What the dense bodies cast to ``cfg.dtype`` on the way into a product.
+# The norm scales are not among them: ``_rmsnorm`` multiplies the scale
+# in float32, so casting it would change the numbers.
+_DENSE_PRODUCT_LEAVES = ("wq", "wk", "wv", "wo", "w_in", "w_out")
+
+
+def _dense_served_params(cfg: tfm.TransformerConfig, params: Any) -> Any:
+    """The dense tree with every product's weights in ``cfg.dtype``: what
+    ``_qkv``, ``_mlp``, ``_gather_logits``, ``wo`` and the embedding
+    would otherwise cast in every run of every program."""
+    dt, layers = cfg.dtype, params["layers"]
+    return {**params,
+            "embed": cast_once(params["embed"], dt),
+            "head": cast_once(params["head"], dt),
+            "layers": {**layers, **{n: cast_once(layers[n], dt)
+                                    for n in _DENSE_PRODUCT_LEAVES}}}
+
+
 DENSE = ServeModel(
     check=lambda cfg, draft_mode: _check_cfg(cfg), cache_rows=_dense_rows,
     decode=_decode_body, prefill=_prefill_body,
-    param_specs=tfm.param_specs, draft=_dense_draft)
+    param_specs=tfm.param_specs, served_params=_dense_served_params,
+    draft=_dense_draft)
 
 
 def serve_model(cfg: Any) -> ServeModel:
@@ -533,9 +572,6 @@ class ServeEngine:
         # over KV heads under TP, otherwise params and pages replicated
         # over it (a one-device mesh pins the replica to that chip);
         # without a mesh, on the device the caller's params already live.
-        # The parameters stay in the dtype they are given in: a model
-        # served from bfloat16 leaves holds no float32 copy and its
-        # programs cast nothing.
         if tp and mesh is not None:
             def over_tp(r: kvc.CacheRows) -> NamedSharding:
                 spec = [None] * (3 + len(r.row))
@@ -544,16 +580,24 @@ class ServeEngine:
                 return NamedSharding(mesh, P(*spec))
             kv_shardings = [over_tp(r) for r in rows]
             pspecs = model.param_specs(cfg)
-            self.params = jax.device_put(params, jax.tree.map(
+            placed = jax.device_put(params, jax.tree.map(
                 lambda s: NamedSharding(mesh, s), pspecs,
                 is_leaf=lambda x: isinstance(x, P)))
         elif mesh is not None:
             kv_shardings = [NamedSharding(mesh, P())] * len(rows)
-            self.params = jax.device_put(params, kv_shardings[0])
+            placed = jax.device_put(params, kv_shardings[0])
         else:
-            self.params = params = jax.tree.map(jnp.asarray, params)
+            placed = jax.tree.map(jnp.asarray, params)
             kv_shardings = [SingleDeviceSharding(
-                next(iter(jax.tree.leaves(params)[0].devices())))] * len(rows)
+                next(iter(jax.tree.leaves(placed)[0].devices())))] * len(rows)
+        # The tree the programs read is made once, here: the leaves the
+        # model names cast to ``cfg.dtype`` on the device, every other
+        # leaf (and any in ``cfg.dtype`` already) the placed array
+        # itself, so no program reads or converts a float32 weight
+        # stack. The engine keeps no reference to a leaf it replaced:
+        # what the caller drops of its own tree is freed.
+        self.params, self.weights = self._served_params(placed)
+        del placed
         # the pool's one layout and placement: allocated in it, and
         # pinned on every program that takes or returns the pool
         self.pool_formats = tuple(
@@ -596,11 +640,35 @@ class ServeEngine:
         logger.info(
             "serve engine up: %d slots, %d+1 pages x %d tokens "
             "(%.1f MiB KV pool), prefill buckets %s, tp=%d, builds=%d, "
-            "decode temporaries %.1f MiB",
+            "decode temporaries %.1f MiB, weights %.1f MiB resident "
+            "(%d leaves cast once from %.1f MiB)",
             self.slots, pool_pages, self.page,
             self.pool.nbytes() / 2 ** 20, self.buckets, self._tp_size,
             self.builds,
-            self.program_temp_bytes.get("serve_decode", 0) / 2 ** 20)
+            self.program_temp_bytes.get("serve_decode", 0) / 2 ** 20,
+            self.weights["resident_bytes"] / 2 ** 20,
+            self.weights["cast_leaves"],
+            self.weights["cast_from_bytes"] / 2 ** 20)
+
+    def _served_params(self, placed: Any) -> Tuple[Any, Dict[str, int]]:
+        """(the tree the programs read, what making it took): the
+        model's ``served_params`` of the placed tree, or that tree where
+        the model names nothing. ``cast_leaves`` counts the leaves that
+        are no longer the placed array, ``cast_from_bytes`` what those
+        held, ``resident_bytes`` what the served tree holds (whole
+        arrays, whatever their sharding)."""
+        served = placed
+        if self.model.served_params is not None:
+            with trace.span("engine.weights.prepare", cat=trace.CAT_SERVE):
+                served = jax.block_until_ready(
+                    self.model.served_params(self.cfg, placed))
+        replaced = [a for a, b in zip(jax.tree.leaves(placed),
+                                      jax.tree.leaves(served)) if b is not a]
+        return served, {
+            "cast_leaves": len(replaced),
+            "cast_from_bytes": sum(int(a.nbytes) for a in replaced),
+            "resident_bytes": sum(int(b.nbytes)
+                                  for b in jax.tree.leaves(served))}
 
     # -- AOT/store plumbing --------------------------------------------------
     def _build(self) -> None:
@@ -1007,6 +1075,7 @@ class ServeEngine:
                     1.0 - free / float(self.pool.n_pages), 4),
             },
             "kv_pool_bytes": self.pool.nbytes(),
+            "weights": dict(self.weights),
             "prefill_buckets": list(self.buckets),
             "prefix_cache": self.prefix_cache,
             "prefix_index": (self.prefix.stats()

@@ -1,9 +1,11 @@
 """Compile-only checks of the serve engine's programs at the serve cell's
 real size (pythia-410m widths, 16 and 24 slots of 2048 context, pages of
 128, bf16) for a described ``v5e:2x2``: the KV pool stays in one layout and
-one buffer. The programs are built the way the engine builds them
-(``serve_programs`` over ``pool_format``, lowered from shapes); nothing
-executes. Bytes are printed (``pytest -s``) for PERF.md."""
+one buffer, and the programs the engine lowers from the tree it prepared
+hold no float32 buffer the shape of a weight. The programs are built the
+way the engine builds them (``serve_programs`` over ``pool_format``,
+lowered from shapes); nothing executes. Bytes are printed (``pytest -s``)
+for PERF.md."""
 
 import os
 import re
@@ -50,9 +52,11 @@ def _cfg():
         dp_axis=None, remat=False)
 
 
-def _compile(topo, program, slots):
+def _compile(topo, program, slots, prepared=False):
     """(compiled, pool) of one engine program over a pool of ``slots``
-    slots, on the first described chip."""
+    slots, on the first described chip: from the float32 tree's shapes,
+    or (``prepared``) from the shapes of the tree the engine makes of it
+    at build."""
     cfg = _cfg()
     one = SingleDeviceSharding(topo.devices[0])
     pool = kvc.PagePool(cfg.n_layers, slots * (CTX // PAGE), PAGE,
@@ -62,6 +66,8 @@ def _compile(topo, program, slots):
     params = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
         jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))))
+    if prepared:
+        params = eng.serve_model(cfg).served_params(cfg, params)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     n_max = CTX // PAGE
     args = {
@@ -116,3 +122,51 @@ def test_pool_stays_in_one_layout_and_one_buffer(topo, compiled_kernels,
         from horovod_tpu.ops.pallas.flash_attention import \
             compiled_kernels as ck
         assert "hvd_paged_decode" in ck(text)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_prepared_programs_hold_no_float32_weight(topo, compiled_kernels,
+                                                  program):
+    """The cell's decode and 256-token prefill programs as the engine
+    lowers them, from the shapes of the tree it prepared: 0.81 GB of
+    bfloat16 weights beside the 6.47 GB pool where the float32 tree made
+    8.09 GB of arguments, and no float32 buffer the shape of a weight
+    stack or of one layer's slice of it."""
+    cfg = _cfg()
+    given = jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
+    served = eng.serve_model(cfg).served_params(cfg, given)
+    named = lambda t: {**{k: v for k, v in t.items() if k != "layers"},
+                       **t["layers"]}
+    cast = sorted(n for n, a in named(served).items()
+                  if a.dtype != named(given)[n].dtype)
+    assert cast == sorted(["embed", "head", "wq", "wk", "wv", "wo", "w_in",
+                           "w_out"])
+    assert all(named(served)[n].dtype == jnp.bfloat16 for n in cast)
+    nbytes = lambda t: sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                           for a in jax.tree.leaves(t))
+    compiled, pool = _compile(topo, program, 16, prepared=True)
+    m = compiled.memory_analysis()
+    print(f"\nserve {program} from the prepared tree, 16 slots: arguments "
+          f"{m.argument_size_in_bytes / 1e9:.3f} GB (weights "
+          f"{nbytes(served) / 1e9:.3f} of {nbytes(given) / 1e9:.3f} given), "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.3f} GB")
+    assert nbytes(given) == pytest.approx(1.62e9, rel=5e-3)
+    assert nbytes(served) == pytest.approx(0.81e9, rel=5e-3)
+    assert m.argument_size_in_bytes == pytest.approx(7.28e9, rel=1e-2)
+    assert m.temp_size_in_bytes < 0.05e9     # 0.605 were the cast copies
+    shapes = set()                  # the norm scales stay float32
+    for a in (named(given)[n] for n in cast):
+        shapes |= {tuple(a.shape), tuple(a.shape[1:])}
+    shapes = {s for s in shapes if len(s) >= 2}
+    wide, fused = [], False
+    for line in compiled.as_text().splitlines():
+        if line.startswith(("%fused_computation", "fused_computation")):
+            fused = True
+        elif line.startswith("}"):
+            fused = False
+        elif not fused:
+            wide += re.findall(r"= f32\[([\d,]+)\]\S* [\w\-]+\(", line)
+    assert wide                     # the scan found the float32 buffers
+    widened = [dims for dims in wide
+               if tuple(int(d) for d in dims.split(",")) in shapes]
+    assert not widened, widened

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.models.transformer import _rmsnorm
+from horovod_tpu.models.transformer import _rmsnorm, rope, visible_softmax
 from horovod_tpu.parallel import moe as moe_lib
 
 Params = Dict[str, Any]
@@ -112,8 +112,8 @@ class LongCatFlashConfig:
 
     def serve_model(self):
         """What :class:`horovod_tpu.serving.ServeEngine` asks of this
-        model (``serving.engine.ServeModel``)."""
-        from horovod_tpu.serving.engine import ServeModel
+        model (``serving.model.ServeModel``)."""
+        from horovod_tpu.serving.model import ServeModel
         return ServeModel(
             check=_check_serve, cache_rows=_cache_rows, decode=decode_body,
             prefill=prefill_body, param_specs=param_specs,
@@ -182,21 +182,6 @@ def param_specs(cfg: LongCatFlashConfig) -> Params:
 # pieces of a layer
 # ---------------------------------------------------------------------------
 
-def rope_rows(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding over the last axis, interleaved pairs, one position
-    per ROW: x ``[N, ..., D]``, pos ``[N]``."""
-    d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = pos[:, None].astype(jnp.float32) * freqs[None, :]       # [N, D/2]
-    ang = ang.reshape((x.shape[0],) + (1,) * (x.ndim - 2) + (d // 2,))
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., 0::2], x32[..., 1::2]
-    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                    axis=-1).reshape(x.shape)
-    return out.astype(x.dtype)
-
-
 def _norm(cfg, x, scale):
     """RMSNorm of the residual stream (float32) or of a latent, in float32;
     the result in the dtype of ``x``."""
@@ -218,8 +203,8 @@ def mla_project(cfg: LongCatFlashConfig, bp: Params, x: jax.Array,
     kv = x @ bp["wkv_a"].astype(dt)
     c = (_norm(cfg, kv[:, :cfg.kv_lora_rank], bp["kv_norm"])
          * cfg.a_kv).astype(dt)
-    k_r = rope_rows(kv[:, cfg.kv_lora_rank:], pos, cfg.rope_theta)
-    q_rope = rope_rows(q_rope, pos, cfg.rope_theta)
+    k_r = rope(kv[:, cfg.kv_lora_rank:], pos, cfg.rope_theta, heads=0)
+    q_rope = rope(q_rope, pos, cfg.rope_theta)
     return q_nope, q_rope, jnp.concatenate([c, k_r], axis=-1)
 
 
@@ -229,17 +214,6 @@ def _wkv_b(cfg, bp):
     w = bp["wkv_b"].astype(cfg.dtype).reshape(
         cfg.kv_lora_rank, cfg.n_heads, cfg.qk_nope_dim + cfg.v_dim)
     return w[..., :cfg.qk_nope_dim], w[..., cfg.qk_nope_dim:]
-
-
-def _softmax_rows(s: jax.Array, visible: jax.Array) -> jax.Array:
-    """float32 softmax over the last axis of s ``[N, H, T]`` under visible
-    ``[N, T]``; a row that sees nothing (an empty slot) gives zeros."""
-    vis = visible[:, None, :]
-    s = jnp.where(vis, s, -jnp.inf)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    m = jnp.where(jnp.isfinite(m), m, 0.0)
-    p = jnp.where(vis, jnp.exp(s - m), 0.0)
-    return p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
 
 
 def mla_attend_absorbed(cfg: LongCatFlashConfig, bp: Params,
@@ -260,7 +234,7 @@ def mla_attend_absorbed(cfg: LongCatFlashConfig, bp: Params,
                         preferred_element_type=jnp.float32)
              + jnp.einsum("nhd,ntd->nht", q_rope, k_r,
                           preferred_element_type=jnp.float32)) * scale
-        p = _softmax_rows(s, visible).astype(dt)
+        p = visible_softmax(s, visible).astype(dt)
         o_lat = jnp.einsum("nht,ntr->nhr", p, c).astype(dt)
     with jax.named_scope("hvd_mla_proj"):
         o = jnp.einsum("nhr,rhv->nhv", o_lat, wv)
@@ -285,7 +259,7 @@ def mla_attend_expanded(cfg: LongCatFlashConfig, bp: Params,
                         preferred_element_type=jnp.float32)
              + jnp.einsum("nhd,td->nht", q_rope, k_r,
                           preferred_element_type=jnp.float32)) * scale
-        p = _softmax_rows(s, visible).astype(dt)
+        p = visible_softmax(s, visible).astype(dt)
         o = jnp.einsum("nht,thv->nhv", p, v)
     return o.reshape(o.shape[0], -1).astype(dt)
 
@@ -352,7 +326,7 @@ def logits_of(cfg: LongCatFlashConfig, params: Params, h: jax.Array
 
 
 # ---------------------------------------------------------------------------
-# the serving engine's step bodies (serving.engine.ServeModel)
+# the serving engine's step bodies (serving.model.ServeModel)
 # ---------------------------------------------------------------------------
 
 def _check_serve(cfg: LongCatFlashConfig, draft_mode: str) -> None:
@@ -438,22 +412,24 @@ def routing_stats(cfg: LongCatFlashConfig, state: Tuple[jax.Array, ...]
     return {"moe": out}
 
 
-def decode_body(cfg: LongCatFlashConfig, params: Params, pool: jax.Array,
+def _serve_step(cfg: LongCatFlashConfig, params: Params, pool: jax.Array,
                 counters: jax.Array, block_tables: jax.Array,
-                lengths: jax.Array, tokens: jax.Array):
-    """One decode step over all slots through the latent cache. pool
-    ``[2L, P+1, page, row]``: layer l's attention block i is block
-    ``2l + i``. Empty slots carry length 0 and scratch block tables; their
-    rows sink into the scratch page and are not counted."""
+                tokens: jax.Array, pos: jax.Array, counted: jax.Array,
+                write, mla_attend, program: int,
+                out_row: Optional[jax.Array] = None):
+    """What a decode step and a prefill chunk share: embed ``tokens``
+    ``[N]``, the layers at positions ``pos`` ``[N]`` through the latent
+    cache (pool ``[2L, P+1, page, row]``: layer l's attention block i is
+    block ``2l + i``), each block writing its rows through ``write(pages,
+    new, block_tables, scratch)`` and attending with ``mla_attend`` over
+    the gathered pages, each row seeing the cached positions up to its
+    own; the head (of row ``out_row`` only, if given), argmax. The rows
+    ``counted`` go into ``program``'s routing counters."""
     from horovod_tpu.serving import kv_cache as kvc
-    from horovod_tpu.serving.engine import _with_index
-    stride, page = pool.shape[1], pool.shape[2]
-    n_ctx = block_tables.shape[1] * page
-    valid = lengths < n_ctx
-    live = lengths > 0              # a served slot has its prompt cached
-    visible = jnp.arange(n_ctx, dtype=jnp.int32)[None, :] <= lengths[:, None]
-    h = params["embed"][tokens].astype(jnp.float32)                 # [S, D]
-    flat = pool.reshape((-1,) + pool.shape[2:])
+    n_ctx = block_tables.shape[-1] * pool.shape[2]
+    visible = jnp.arange(n_ctx, dtype=jnp.int32)[None, :] <= pos[:, None]
+    h = params["embed"][tokens].astype(jnp.float32)                 # [N, D]
+    flat, = kvc.flat_pool(pool)
 
     def body(carry, xs):
         h, flat, total = carry
@@ -461,30 +437,48 @@ def decode_body(cfg: LongCatFlashConfig, params: Params, pool: jax.Array,
 
         def attend(i, x):
             nonlocal flat
-            base = (2 * li + i) * stride
-            bt = block_tables + base
+            bt, scratch = kvc.block_pages(pool.shape, 2 * li + i,
+                                          block_tables)
             with jax.named_scope("hvd_mla_proj"):
-                q_nope, q_rope, row = mla_project(cfg, lp["mla"][i], x,
-                                                  lengths)
+                q_nope, q_rope, row = mla_project(cfg, lp["mla"][i], x, pos)
             with jax.named_scope("hvd_kv_write"):
-                flat, = kvc.write_token_rows(
-                    (flat,), (row,), bt, lengths, valid=valid,
-                    scratch=base + stride - 1)
+                flat, = write((flat,), (row,), bt, scratch)
             with jax.named_scope("hvd_attention"):
-                rows = kvc.gather_pages(flat, bt)         # [S, n_ctx, row]
-            return mla_attend_absorbed(cfg, lp["mla"][i], q_nope, q_rope,
-                                       rows, visible)
+                rows = kvc.gather_pages(flat, bt)   # [(N,) n_ctx, row]
+            return mla_attend(cfg, lp["mla"][i], q_nope, q_rope, rows,
+                              visible)
 
-        h, counts = layer(cfg, lp, h, attend, valid=live)
+        h, counts = layer(cfg, lp, h, attend, valid=counted)
         return (h, flat, total + counts), None
 
     zero = jnp.zeros((counters.shape[-1],), jnp.int32)
     (h, flat, total), _ = lax.scan(body, (h, flat, zero),
-                                   _with_index(params["layers"]))
-    logits = logits_of(cfg, params, h)                            # [S, V]
+                                   kvc.with_index(params["layers"]))
+    if out_row is not None:
+        h = jnp.take(h, out_row, axis=0)                            # [D]
+    logits = logits_of(cfg, params, h)
     next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return (flat.reshape(pool.shape), _add_counts(counters, total, DECODE),
+    return (flat.reshape(pool.shape), _add_counts(counters, total, program),
             next_tokens, logits)
+
+
+def decode_body(cfg: LongCatFlashConfig, params: Params, pool: jax.Array,
+                counters: jax.Array, block_tables: jax.Array,
+                lengths: jax.Array, tokens: jax.Array):
+    """One decode step over all slots through the latent cache, ``Wkvb``
+    absorbed (each slot over its own pages). Empty slots carry length 0
+    and scratch block tables; their rows sink into the scratch page and
+    are not counted (a served slot has its prompt cached)."""
+    from horovod_tpu.serving import kv_cache as kvc
+    valid = lengths < block_tables.shape[1] * pool.shape[2]
+
+    def write(pages, new, bt, scratch):
+        return kvc.write_token_rows(pages, new, bt, lengths, valid=valid,
+                                    scratch=scratch)
+
+    return _serve_step(cfg, params, pool, counters, block_tables, tokens,
+                       lengths, lengths > 0, write, mla_attend_absorbed,
+                       DECODE)
 
 
 def prefill_body(cfg: LongCatFlashConfig, params: Params, pool: jax.Array,
@@ -495,43 +489,14 @@ def prefill_body(cfg: LongCatFlashConfig, params: Params, pool: jax.Array,
     attention over the cached prefix + the chunk (keys and values expanded
     from the cached rows), the last real token's logits out."""
     from horovod_tpu.serving import kv_cache as kvc
-    from horovod_tpu.serving.engine import _with_index
-    stride, page = pool.shape[1], pool.shape[2]
     c = tokens.shape[0]
     pos = start + jnp.arange(c, dtype=jnp.int32)
-    n_ctx = block_table.shape[0] * page
-    visible = jnp.arange(n_ctx, dtype=jnp.int32)[None, :] <= pos[:, None]
-    real = jnp.arange(c) < n_real
-    h = params["embed"][tokens].astype(jnp.float32)                 # [C, D]
-    flat = pool.reshape((-1,) + pool.shape[2:])
 
-    def body(carry, xs):
-        h, flat, total = carry
-        lp, li = xs
+    def write(pages, new, bt, scratch):
+        return kvc.write_chunk_rows(pages, new, bt, start, n_real,
+                                    scratch=scratch)
 
-        def attend(i, x):
-            nonlocal flat
-            base = (2 * li + i) * stride
-            bt = block_table + base
-            with jax.named_scope("hvd_mla_proj"):
-                q_nope, q_rope, row = mla_project(cfg, lp["mla"][i], x, pos)
-            with jax.named_scope("hvd_kv_write"):
-                flat, = kvc.write_chunk_rows(
-                    (flat,), (row,), bt, start, n_real,
-                    scratch=base + stride - 1)
-            with jax.named_scope("hvd_attention"):
-                rows = kvc.gather_pages(flat, bt)            # [n_ctx, row]
-            return mla_attend_expanded(cfg, lp["mla"][i], q_nope, q_rope,
-                                       rows, visible)
-
-        h, counts = layer(cfg, lp, h, attend, valid=real)
-        return (h, flat, total + counts), None
-
-    zero = jnp.zeros((counters.shape[-1],), jnp.int32)
-    (h, flat, total), _ = lax.scan(body, (h, flat, zero),
-                                   _with_index(params["layers"]))
-    last = jnp.take(h, jnp.maximum(n_real - 1, 0), axis=0)        # [D]
-    logits = logits_of(cfg, params, last)                         # [V]
-    next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return (flat.reshape(pool.shape), _add_counts(counters, total, PREFILL),
-            next_token, logits)
+    return _serve_step(cfg, params, pool, counters, block_table, tokens,
+                       pos, jnp.arange(c) < n_real, write,
+                       mla_attend_expanded, PREFILL,
+                       out_row=jnp.maximum(n_real - 1, 0))

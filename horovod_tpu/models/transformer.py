@@ -24,7 +24,7 @@ train step; ``__graft_entry__`` uses that for the driver's compile checks.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -84,6 +84,15 @@ class TransformerConfig:
     @property
     def qkv_dim(self) -> int:
         return self.n_heads * self.head_dim
+
+    def serve_model(self):
+        """What :class:`horovod_tpu.serving.ServeEngine` asks of this
+        model (``serving.model.ServeModel``)."""
+        from horovod_tpu.serving.model import ServeModel
+        return ServeModel(
+            check=_check_serve, cache_rows=_cache_rows, decode=decode_body,
+            prefill=prefill_body, param_specs=param_specs,
+            served_params=served_params, draft=_draft_body)
 
 
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
@@ -200,19 +209,39 @@ def _rmsnorm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (x32 * rms * scale).astype(x.dtype)
 
 
-def _rope(x: jax.Array, pos: jax.Array) -> jax.Array:
-    """Rotary embeddings; x [B, S, H, D], pos [S] global positions."""
+def rope(x: jax.Array, pos: jax.Array, theta: float = 10000.0,
+         heads: int = 1) -> jax.Array:
+    """Rotary embedding over the last axis of x, interleaved pairs, in
+    float32; the result in the dtype of ``x``. x is ``[..., *pos.shape,
+    *H, D]`` with ``heads`` axes ``H`` between the positions' and ``D``: a
+    batch ``[B, S, H, D]`` with ``pos[S]``, rows ``[N, H, D]`` with
+    ``pos[N]``, a key without heads ``[N, D]`` with ``pos[N]`` and
+    ``heads=0``. The one rotary of the package: a cached key is bitwise
+    the key training rotates."""
     d = x.shape[-1]
-    freqs = 1.0 / (10000.0 ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = pos[:, None].astype(jnp.float32) * freqs[None, :]   # [S, D/2]
+    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = pos[..., None].astype(jnp.float32) * freqs           # [*P, D/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = x[..., 0::2], x[..., 1::2]
-    cos = cos[None, :, None, :]
-    sin = sin[None, :, None, :]
-    xr1 = x1 * cos - x2 * sin
-    xr2 = x1 * sin + x2 * cos
-    out = jnp.stack([xr1, xr2], axis=-1).reshape(x.shape)
+    # onto x's axes: size 1 over the leading axes and over the heads
+    lead = x.ndim - 1 - heads - pos.ndim
+    ones = (*range(lead), *range(x.ndim - 1 - heads, x.ndim - 1))
+    cos, sin = lax.expand_dims(cos, ones), lax.expand_dims(sin, ones)
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                    axis=-1).reshape(x.shape)
     return out.astype(x.dtype)
+
+
+def visible_softmax(s: jax.Array, visible: jax.Array) -> jax.Array:
+    """float32 softmax over the last axis of s ``[N, H, T]`` under visible
+    ``[N, T]`` (which keys row n may see); a row that sees nothing (an
+    empty slot) gives zeros."""
+    vis = visible[:, None, :]
+    s = jnp.where(vis, s, -jnp.inf)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    p = jnp.where(vis, jnp.exp(s - m), 0.0)
+    return p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
 
 
 def _dense_mlp(cfg: TransformerConfig, h: jax.Array, w_in: jax.Array,
@@ -222,77 +251,104 @@ def _dense_mlp(cfg: TransformerConfig, h: jax.Array, w_in: jax.Array,
     print_saved_residuals``) attribute them, and so name-based policies can
     target them; the selective-recompute wrapper in ``_layer`` (see
     ``TransformerConfig.mlp_recompute``) scopes a nothing-saveable
-    checkpoint to exactly this function."""
+    checkpoint to exactly this function. (In a forward-only program the
+    names lower to nothing.)"""
     from jax.ad_checkpoint import checkpoint_name
     u = checkpoint_name(tp_lib.column_parallel(h, w_in), "mlp_wide")
     u = checkpoint_name(jax.nn.gelu(u), "mlp_wide")
     return tp_lib.row_parallel(u, w_out, cfg.tp_axis)
 
 
-def _layer(cfg: TransformerConfig, lp: Params, x: jax.Array,
-           aux_acc: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """One transformer block on local shards. x [b, s_local, D] replicated
-    over tp/ep; lp = this layer's (local) params."""
-    dt = cfg.dtype
-    sp = cfg.sp_axis
-    s_local = x.shape[1]
-    if sp:
-        pos0 = lax.axis_index(sp) * s_local
-    else:
-        pos0 = 0
-    pos = pos0 + jnp.arange(s_local)
+def block(cfg: TransformerConfig, lp: Params, x: jax.Array, pos: jax.Array,
+          attend: Callable[[jax.Array, jax.Array, jax.Array], jax.Array],
+          mlp: Callable[[jax.Array], jax.Array]) -> jax.Array:
+    """THE dense block, on local shards: rows x ``[..., D]`` (a training
+    batch ``[b, s_local, D]`` with ``pos[s_local]``, a decode step's
+    ``[slots, D]`` with ``pos[slots]``, a prefill chunk's ``[C, D]`` with
+    ``pos[C]``); lp = this layer's (local) params.
 
+    ``attend(q, k, v)``, each ``[..., H_local, head_dim]`` with q and k
+    rotated, gives the attention's output in that shape: the caller owns
+    the attention, and with it the cache. ``mlp(h)`` is the MLP half on the
+    normed rows. Training, decode, verify, draft and prefill are this one
+    function under different ``attend`` / ``mlp``; it does not know which.
+
+    hvd_attention / hvd_mlp / hvd_kv_write (the callers' scopes) are names
+    on the device side of the step (HLO metadata op_name; the backward's
+    operations read transpose(jvp(hvd_attention))). They change nothing
+    computed. Both norms stand outside them."""
+    dt = cfg.dtype
     h = _rmsnorm(x, lp["attn_norm"])
     q = tp_lib.column_parallel(h, lp["wq"].astype(dt))
-    kk = tp_lib.column_parallel(h, lp["wk"].astype(dt))
-    vv = tp_lib.column_parallel(h, lp["wv"].astype(dt))
-    hl = q.shape[-1] // cfg.head_dim     # local head count (H / tp)
-    shp = (x.shape[0], s_local, hl, cfg.head_dim)
-    q, kk, vv = (t.reshape(shp) for t in (q, kk, vv))
-    q = _rope(q, pos)
-    kk = _rope(kk, pos)
-    # hvd_attention / hvd_mlp / hvd_loss: names on the device side of the
-    # step (HLO metadata op_name; the backward's operations read
-    # transpose(jvp(hvd_attention))). They change nothing computed.
-    with jax.named_scope("hvd_attention"):
-        if sp and cfg.attention == "ring":
-            o = sp_lib.ring_attention(q, kk, vv, sp, causal=True)
-        elif sp and cfg.attention == "ulysses":
-            o = sp_lib.ulysses_attention(q, kk, vv, sp, causal=True)
-        else:
-            o = sp_lib.local_attention(q, kk, vv, causal=True)
-    o = o.reshape(x.shape[0], s_local, -1)
-    attn_out = tp_lib.row_parallel(o, lp["wo"].astype(dt), cfg.tp_axis)
-    x = x + attn_out.astype(x.dtype)
+    k = tp_lib.column_parallel(h, lp["wk"].astype(dt))
+    v = tp_lib.column_parallel(h, lp["wv"].astype(dt))
+    # [..., H_local, head_dim]: H / tp heads on this shard
+    by_head = x.shape[:-1] + (-1, cfg.head_dim)
+    q, k, v = (t.reshape(by_head) for t in (q, k, v))
+    o = attend(rope(q, pos), rope(k, pos), v)
+    o = o.astype(x.dtype).reshape(x.shape[:-1] + (-1,))
+    x = x + tp_lib.row_parallel(o, lp["wo"].astype(dt),
+                                cfg.tp_axis).astype(x.dtype)
+    return x + mlp(_rmsnorm(x, lp["mlp_norm"])).astype(x.dtype)
 
-    h = _rmsnorm(x, lp["mlp_norm"])
-    if cfg.num_experts:
+
+def _mlp_half(cfg: TransformerConfig, lp: Params,
+              mlp_fn: Callable = _dense_mlp) -> Callable:
+    """``block``'s ``mlp`` for the dense FFN: ``mlp_fn`` (``_dense_mlp``
+    or a checkpointed one) on this layer's weights, under ``hvd_mlp``."""
+    def mlp(h):
         with jax.named_scope("hvd_mlp"):
-            mlp_out, metrics = moe_lib.moe_ffn(
-                h, lp["router"], lp["w_in"].astype(dt),
-                lp["w_out"].astype(dt), ep_axis=cfg.ep_axis,
+            return mlp_fn(cfg, h, lp["w_in"].astype(cfg.dtype),
+                          lp["w_out"].astype(cfg.dtype))
+    return mlp
+
+
+def _layer(cfg: TransformerConfig, lp: Params, x: jax.Array,
+           aux_acc: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """One training block on local shards. x [b, s_local, D] replicated
+    over tp/ep; lp = this layer's (local) params."""
+    sp = cfg.sp_axis
+    s_local = x.shape[1]
+    pos0 = lax.axis_index(sp) * s_local if sp else 0
+    pos = pos0 + jnp.arange(s_local)
+
+    def attend(q, k, v):
+        with jax.named_scope("hvd_attention"):
+            if sp and cfg.attention == "ring":
+                return sp_lib.ring_attention(q, k, v, sp, causal=True)
+            if sp and cfg.attention == "ulysses":
+                return sp_lib.ulysses_attention(q, k, v, sp, causal=True)
+            return sp_lib.local_attention(q, k, v, causal=True)
+
+    def moe(h):
+        nonlocal aux_acc
+        with jax.named_scope("hvd_mlp"):
+            out, metrics = moe_lib.moe_ffn(
+                h, lp["router"], lp["w_in"].astype(cfg.dtype),
+                lp["w_out"].astype(cfg.dtype), ep_axis=cfg.ep_axis,
                 capacity_factor=cfg.capacity_factor)
         aux_acc = aux_acc + metrics.aux_loss
+        return out
+
+    if cfg.num_experts:
+        mlp = moe
+    elif cfg.mlp_recompute and not cfg.remat:
+        # Checkpoint exactly the d_ff-wide region: its only internals
+        # are the two named activations (plus gelu's unnamed wide
+        # intermediates, which is why the policy is nothing_saveable
+        # rather than save_anything_except_these_names — the latter
+        # would keep saving gelu's internals). Inputs (h, weights) stay
+        # saved for free; the backward recomputes one [.., d]x[d, 4d]
+        # matmul + gelu instead of round-tripping 2 x [.., d_ff] per
+        # layer through HBM — the measured middle ground between
+        # no-remat (the ~20 ms/step activation-stack traffic) and
+        # full-layer remat (recompute-bound, PERF.md r5).
+        mlp = _mlp_half(cfg, lp, jax.checkpoint(
+            _dense_mlp, static_argnums=(0,),
+            policy=jax.checkpoint_policies.nothing_saveable))
     else:
-        mlp_fn = _dense_mlp
-        if cfg.mlp_recompute and not cfg.remat:
-            # Checkpoint exactly the d_ff-wide region: its only internals
-            # are the two named activations (plus gelu's unnamed wide
-            # intermediates, which is why the policy is nothing_saveable
-            # rather than save_anything_except_these_names — the latter
-            # would keep saving gelu's internals). Inputs (h, weights) stay
-            # saved for free; the backward recomputes one [.., d]x[d, 4d]
-            # matmul + gelu instead of round-tripping 2 x [.., d_ff] per
-            # layer through HBM — the measured middle ground between
-            # no-remat (the ~20 ms/step activation-stack traffic) and
-            # full-layer remat (recompute-bound, PERF.md r5).
-            mlp_fn = jax.checkpoint(
-                _dense_mlp, static_argnums=(0,),
-                policy=jax.checkpoint_policies.nothing_saveable)
-        with jax.named_scope("hvd_mlp"):
-            mlp_out = mlp_fn(cfg, h, lp["w_in"].astype(dt),
-                             lp["w_out"].astype(dt))
-    x = x + mlp_out.astype(x.dtype)
+        mlp = _mlp_half(cfg, lp)
+    x = block(cfg, lp, x, pos, attend, mlp)
     return x, aux_acc
 
 
@@ -396,6 +452,191 @@ def loss_fn(cfg: TransformerConfig, params: Params, tokens: jax.Array,
             aux_mean = lax.pmean(aux_mean, ax)
         loss = loss + cfg.moe_aux_weight * aux_mean
     return loss
+
+
+# ---------------------------------------------------------------------------
+# the serving engine's step bodies (serving.model.ServeModel): the block
+# over a paged KV cache. They run per shard, inside shard_map when tp_axis
+# is set: heads, FFN and vocabulary are split exactly as in training, and
+# the page pool over its KV-head axis.
+# ---------------------------------------------------------------------------
+
+def _check_serve(cfg: TransformerConfig, draft_mode: str) -> None:
+    unsupported = [n for n, a in (("sp", cfg.sp_axis), ("ep", cfg.ep_axis),
+                                  ("pp", cfg.pp_axis)) if a]
+    if unsupported or cfg.num_experts:
+        raise ValueError(
+            "serving supports the dense TP/DP transformer only; got "
+            f"axes {unsupported or 'none'}, num_experts="
+            f"{cfg.num_experts}. Build a serving TransformerConfig with "
+            "sp/ep/pp axes None (TP via tp_axis is supported).")
+
+
+def _cache_rows(cfg: TransformerConfig):
+    from horovod_tpu.serving.kv_cache import dense_rows
+    return dense_rows(cfg.n_layers, cfg.n_heads, cfg.head_dim)
+
+
+# What the block and the head cast to ``cfg.dtype`` on the way into a
+# product. The norm scales are not among them: ``_rmsnorm`` multiplies the
+# scale in float32, so casting it would change the numbers.
+_PRODUCT_LEAVES = ("wq", "wk", "wv", "wo", "w_in", "w_out")
+
+
+def served_params(cfg: TransformerConfig, params: Params) -> Params:
+    """The tree with every product's weights in ``cfg.dtype``: what the
+    block, the head and the embedding would otherwise cast in every run of
+    every program."""
+    from horovod_tpu.serving.model import cast_once
+    dt, layers = cfg.dtype, params["layers"]
+    return {**params,
+            "embed": cast_once(params["embed"], dt),
+            "head": cast_once(params["head"], dt),
+            "layers": {**layers, **{n: cast_once(layers[n], dt)
+                                    for n in _PRODUCT_LEAVES}}}
+
+
+def attend_gathered(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+                    block_table: jax.Array, pos: jax.Array, scale: float
+                    ) -> jax.Array:
+    """Prefill's attention, in float32: the chunk's queries q ``[C, H, D]``
+    at positions ``pos`` over ONE sequence's pages in block-table order
+    (``k_pages`` / ``v_pages`` ``[n_phys, page, H, D]``), each query seeing
+    the cached positions up to its own: the prefix and the chunk."""
+    from horovod_tpu.serving import kv_cache as kvc
+    kg = kvc.gather_pages(k_pages, block_table).astype(jnp.float32)
+    vg = kvc.gather_pages(v_pages, block_table).astype(jnp.float32)
+    s = jnp.einsum("chd,shd->chs", q.astype(jnp.float32), kg) * scale
+    ctx = jnp.arange(kg.shape[0], dtype=jnp.int32)
+    visible = ctx[None, :] <= pos[:, None]           # causal + prefix
+    return jnp.einsum("chs,shd->chd", visible_softmax(s, visible), vg)
+
+
+def _serve_step(cfg: TransformerConfig, params: Params, k_pages: jax.Array,
+                v_pages: jax.Array, block_tables: jax.Array,
+                tokens: jax.Array, pos: jax.Array, write: Callable,
+                attend: Callable, n_layers: Optional[int] = None,
+                out_row: Optional[jax.Array] = None):
+    """What a decode step and a prefill chunk share: embed ``tokens``
+    ``[N]``, the block at positions ``pos`` ``[N]`` over the layers
+    (the first ``n_layers``), each writing its K/V through ``write(pages,
+    new, block_tables, scratch)`` and then attending through ``attend(q,
+    k_pages, v_pages, block_tables)``, final norm, head (of row
+    ``out_row`` only, if given), argmax. Returns ``(k_pages, v_pages,
+    next token(s), float32 logits)``.
+
+    The pool is carried through the layer scan as one flat run of pages
+    and a layer addressed by offset block tables
+    (``kv_cache.block_pages``), so no instruction slices a layer's pool
+    out or stacks it back."""
+    from horovod_tpu.serving import kv_cache as kvc
+    x = tp_lib.vocab_parallel_embed(
+        tokens, params["embed"].astype(cfg.dtype), cfg.tp_axis)   # [N, D]
+    layers = params["layers"]
+    if n_layers is not None:
+        layers = jax.tree.map(lambda a: a[:n_layers], layers)
+
+    def layer(carry, xs):
+        x, pool = carry
+        lp, li = xs
+        bt, scratch = kvc.block_pages(k_pages.shape, li, block_tables)
+
+        def attend_cached(q, k, v):
+            nonlocal pool
+            with jax.named_scope("hvd_kv_write"):
+                pool = write(pool, (k, v), bt, scratch)
+            with jax.named_scope("hvd_attention"):
+                return attend(q, *pool, bt)
+
+        x = block(cfg, lp, x, pos, attend_cached, _mlp_half(cfg, lp))
+        return (x, pool), None
+
+    (x, pool), _ = lax.scan(
+        layer, (x, kvc.flat_pool(k_pages, v_pages)), kvc.with_index(layers))
+    x = _rmsnorm(x, params["final_norm"])
+    if out_row is not None:
+        x = jnp.take(x, out_row, axis=0)                           # [D]
+    # full-vocab f32 logits (the TP head gathered)
+    logits = (x @ params["head"].astype(cfg.dtype)).astype(jnp.float32)
+    if cfg.tp_axis:
+        logits = lax.all_gather(logits, cfg.tp_axis, axis=-1, tiled=True)
+    next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return (*(p.reshape(k_pages.shape) for p in pool), next_tokens, logits)
+
+
+def decode_body(cfg: TransformerConfig, params: Params,
+                k_pages: jax.Array, v_pages: jax.Array,
+                block_tables: jax.Array, lengths: jax.Array,
+                tokens: jax.Array, *, n_layers: Optional[int] = None):
+    """One decode step over all slots: tokens ``[S]`` (this step's input
+    token per slot), lengths ``[S]`` (tokens already cached — the
+    position this token lands at). Empty slots carry length 0 and
+    scratch-page block tables; their writes sink into the scratch page
+    and their outputs are ignored by the scheduler. Each slot attends over
+    its own pages through the paged-decode path (flash kernel on TPU, jnp
+    reference elsewhere — ``kv_cache.paged_decode_attention``).
+
+    The SAME body at batch ``slots * (K+1)`` is the speculative verify
+    step: each slot's block-table row repeated K+1 times with lengths
+    ``len_s .. len_s + K`` and tokens ``[last_accepted, draft_1..K]``
+    — every row's K/V lands in the pages BEFORE the layer attends, so
+    the ragged-lengths attention gives each row exact causality over
+    the drafts that precede it, and row i's argmax is bitwise what
+    sequential decode would emit after consuming rows 0..i.
+
+    ``n_layers`` (static) truncates the stack: layers ``0..n-1`` of
+    the target plus the shared final norm/head — the self-drafting
+    model of the ``truncate:N`` speculative mode. It scans fewer layers
+    over the same pool, so layers ``>= n`` are not touched. Its K/V
+    writes land in the shared pool; verify recomputes those layers'
+    identical values over the same positions and overwrites them, so no
+    reader ever observes a draft-only value."""
+    from horovod_tpu.serving import kv_cache as kvc
+    scale = cfg.head_dim ** -0.5
+    # Speculative rows near the context ceiling can carry positions past
+    # the last block-table column; the gather would clamp them INTO the
+    # request's own last page and corrupt it. Route them to scratch —
+    # accepted lengths never reach them, so the value is never read.
+    valid = lengths < block_tables.shape[1] * k_pages.shape[2]
+
+    def write(pages, new, bt, scratch):
+        return kvc.write_token_rows(pages, new, bt, lengths, valid=valid,
+                                    scratch=scratch)
+
+    def attend(q, kp, vp, bt):
+        return kvc.paged_decode_attention(q, kp, vp, bt, lengths + 1, scale)
+
+    return _serve_step(cfg, params, k_pages, v_pages, block_tables, tokens,
+                       lengths, write, attend, n_layers=n_layers)
+
+
+def _draft_body(cfg: TransformerConfig, n_layers: int, *args):
+    return decode_body(cfg, *args, n_layers=n_layers)
+
+
+def prefill_body(cfg: TransformerConfig, params: Params,
+                 k_pages: jax.Array, v_pages: jax.Array,
+                 block_table: jax.Array, start: jax.Array,
+                 n_real: jax.Array, tokens: jax.Array):
+    """One prefill chunk of ONE sequence: tokens ``[C]`` (bucket-padded),
+    positions ``start .. start+n_real`` written to the pages, causal
+    attention over the cached prefix + the chunk, last real token's
+    logits out. Chunked prefill: a later chunk attends over the earlier
+    chunks through the pages it finds already written."""
+    from horovod_tpu.serving import kv_cache as kvc
+    scale = cfg.head_dim ** -0.5
+    pos = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
+
+    def write(pages, new, bt, scratch):
+        return kvc.write_chunk_rows(pages, new, bt, start, n_real,
+                                    scratch=scratch)
+
+    def attend(q, kp, vp, bt):
+        return attend_gathered(q, kp, vp, bt, pos, scale)
+
+    return _serve_step(cfg, params, k_pages, v_pages, block_table, tokens,
+                       pos, write, attend,
+                       out_row=jnp.maximum(n_real - 1, 0))
 
 
 class TransformerLM:

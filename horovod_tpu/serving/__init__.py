@@ -1,14 +1,19 @@
 """Serving subsystem (ROADMAP item 1, docs/serving.md): AOT
-continuous-batching inference for the flagship TransformerLM.
+continuous-batching inference for the models under ``horovod_tpu/models``
+that describe themselves to it (``cfg.serve_model()``: the flagship
+TransformerLM, the shortcut-MoE latent-attention model).
 
 - :mod:`~horovod_tpu.serving.kv_cache` — paged KV cache: fixed page
   pool, refcounted allocator, block tables, the shared-prefix
-  hash-chain index (copy-on-write divergence), paged-attention
-  reference.
+  hash-chain index (copy-on-write divergence), how a step body addresses
+  a block of the flat pool, page writes, paged-attention reference.
+- :mod:`~horovod_tpu.serving.model` — ``ServeModel``, what the engine
+  asks of a model; the models import it, never the engine.
 - :mod:`~horovod_tpu.serving.engine` — AOT prefill/decode engine over
-  the page pool, artifact-store-served (``serve`` kind) so warm boots
-  compile nothing; ``load_for_serving`` is the train->serve handoff;
-  speculative verify/draft executables when HOROVOD_SERVE_DRAFT is on.
+  the page pool: slots, pages, programs; it holds no model.
+  Artifact-store-served (``serve`` kind) so warm boots compile nothing;
+  ``load_for_serving`` is the train->serve handoff; speculative
+  verify/draft executables when HOROVOD_SERVE_DRAFT is on.
 - :mod:`~horovod_tpu.serving.scheduler` — iteration-level continuous
   batching with the coordinator's cycle/deadline idiom; accept-prefix
   speculative decode; the host-side n-gram drafter.

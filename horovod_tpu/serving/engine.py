@@ -1,38 +1,38 @@
-"""AOT prefill/decode serving engine for the flagship TransformerLM, and
-for any model that hands the engine its own step bodies and cache rows
-(:class:`ServeModel`; ``models/longcat_flash.py`` does).
+"""The serving engine: slots, pages, block tables and AOT-compiled step
+programs for whatever model hands it step bodies and cache rows
+(``cfg.serve_model()`` -> :class:`~horovod_tpu.serving.model.ServeModel`;
+the dense ``TransformerConfig`` and ``LongCatFlashConfig`` both do, from
+``horovod_tpu/models/``). The engine holds no model: it names no parameter
+leaf and no device scope, and computes nothing of a layer.
 
-The inference twin of ``parallel/trainer.py``: the same parameter tree,
-RoPE, norms and TP decomposition as the training forward
-(``models/transformer.py``), restructured around a paged KV cache
-(:mod:`serving.kv_cache`) into exactly TWO compiled program families —
+Around a paged cache (:mod:`serving.kv_cache`) it builds exactly TWO
+compiled program families from the model's bodies —
 
 - **prefill**: one sequence, one chunk of its prompt at a fixed bucket
-  length (powers of two up to ``HOROVOD_SERVE_PREFILL_CHUNK``), K/V
-  written into the sequence's pages, logits of the last real token out;
+  length (powers of two up to ``HOROVOD_SERVE_PREFILL_CHUNK``), the chunk's
+  cache rows written into the sequence's pages, logits of the last real
+  token out;
 - **decode**: ONE token for every batch slot at once
   (``HOROVOD_SERVE_SLOTS`` fixed), each slot attending over its own
-  pages through the paged-decode path (flash kernel on TPU, jnp
-  reference elsewhere — ``kv_cache.paged_decode_attention``).
+  pages (the same program at batch ``slots * (K+1)`` is the speculative
+  verify step; over a model's first layers, its ``truncate:N`` draft).
 
 Every variant is AOT-compiled at engine boot and served through the
-PR 12 artifact store under the new ``serve`` kind, so a warm replica
+PR 12 artifact store under the ``serve`` kind, so a warm replica
 reaches its first token with ZERO builder invocations
 (``ServeEngine.builds`` — the BENCH_TTFS warm-boot story applied to
 serving). Shapes are static by construction: no request, prompt length
 or batch occupancy can trigger a compile after boot.
 
-Tensor parallelism: when ``cfg.tp_axis`` is set the whole step runs
-inside ``shard_map`` with heads/FFN/vocab sharded exactly as in
-training (``tensor_parallel``); the page pool is sharded over the KV
-head axis, so each shard pages only its own heads. Sequence, expert and
-pipeline parallelism are training-side concerns and are rejected here.
+Tensor parallelism: when ``cfg.tp_axis`` is set a whole step runs
+inside ``shard_map`` under the model's ``param_specs``; the page pool is
+sharded over the axis its :class:`~horovod_tpu.serving.kv_cache.CacheRows`
+name (the dense block's: KV heads), so each shard pages only its own.
 """
 
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, \
     Union
@@ -41,7 +41,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental.layout import Format
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
@@ -49,12 +48,18 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 from horovod_tpu import tracing as trace
 from horovod_tpu.config import knobs
 from horovod_tpu.models import transformer as tfm
-from horovod_tpu.parallel import tensor_parallel as tp_lib
 from horovod_tpu.serving import kv_cache as kvc
+from horovod_tpu.serving.model import ServeModel, cast_once  # noqa: F401
 from horovod_tpu.utils import compile_cache
 from horovod_tpu.utils.logging import get_logger
 
 logger = get_logger("horovod_tpu.serving")
+
+# The dense step bodies under the names they had while they lived here:
+# tests/benchmark/test_bench_real_size_compiles.py and bench.py (--verify,
+# --cost-report) import them from this module (ROADMAP S12 and D1 end that).
+_decode_body = tfm.decode_body
+_prefill_body = tfm.prefill_body
 
 
 def prefill_buckets(chunk_cap: Optional[int] = None) -> List[int]:
@@ -103,307 +108,14 @@ def _parse_draft(spec: str, n_layers: int) -> Tuple[str, int]:
         f"or 'truncate:N'")
 
 
-def _check_cfg(cfg: tfm.TransformerConfig) -> None:
-    unsupported = [n for n, a in (("sp", cfg.sp_axis), ("ep", cfg.ep_axis),
-                                  ("pp", cfg.pp_axis)) if a]
-    if unsupported or cfg.num_experts:
-        raise ValueError(
-            "serving supports the dense TP/DP transformer only; got "
-            f"axes {unsupported or 'none'}, num_experts="
-            f"{cfg.num_experts}. Build a serving TransformerConfig with "
-            "sp/ep/pp axes None (TP via tp_axis is supported).")
-
-
-def _rope_rows(x: jax.Array, pos: jax.Array) -> jax.Array:
-    """Rotary embedding with an explicit position per ROW: x
-    ``[N, H, D]``, pos ``[N]``. Identical formula to the training
-    ``transformer._rope`` (which takes one position vector for a whole
-    [B, S] batch) so cached K matches training numerics exactly."""
-    d = x.shape[-1]
-    freqs = 1.0 / (10000.0 ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = pos[:, None].astype(jnp.float32) * freqs[None, :]    # [N, D/2]
-    cos = jnp.cos(ang)[:, None, :]
-    sin = jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                    axis=-1).reshape(x.shape)
-    return out.astype(x.dtype)
-
-
-# ---------------------------------------------------------------------------
-# per-shard step bodies (run inside shard_map when tp_axis is set)
-# ---------------------------------------------------------------------------
-
-def _qkv(cfg, lp, h):
-    dt = cfg.dtype
-    q = tp_lib.column_parallel(h, lp["wq"].astype(dt))
-    k = tp_lib.column_parallel(h, lp["wk"].astype(dt))
-    v = tp_lib.column_parallel(h, lp["wv"].astype(dt))
-    hl = q.shape[-1] // cfg.head_dim          # local head count (H / tp)
-    shp = h.shape[:-1] + (hl, cfg.head_dim)
-    return q.reshape(shp), k.reshape(shp), v.reshape(shp)
-
-
-def _mlp(cfg, lp, x):
-    dt = cfg.dtype
-    h = tfm._rmsnorm(x, lp["mlp_norm"])
-    u = jax.nn.gelu(tp_lib.column_parallel(h, lp["w_in"].astype(dt)))
-    return tp_lib.row_parallel(u, lp["w_out"].astype(dt), cfg.tp_axis)
-
-
-def _gather_logits(cfg, x, head):
-    """[.., D] hidden -> full-vocab f32 logits (TP head gathered)."""
-    logits = (x @ head.astype(cfg.dtype)).astype(jnp.float32)
-    if cfg.tp_axis:
-        logits = lax.all_gather(logits, cfg.tp_axis, axis=-1, tiled=True)
-    return logits
-
-
-def _flat_pool(k_pages: jax.Array, v_pages: jax.Array):
-    """The 5-D pool ``[L, P+1, page, KVH, D]`` as one run of pages
-    ``[L*(P+1), page, KVH, D]`` (a bitcast in the pool's row-major
-    layout) and the stride ``P+1`` between layers: layer ``l``'s page
-    ``p`` is flat page ``l*stride + p``, its scratch page
-    ``l*stride + stride - 1``. The step bodies carry this whole through
-    the layer scan and offset the block tables, so no instruction
-    slices a layer's pool out or stacks it back."""
-    stride = k_pages.shape[1]
-    flat = (-1,) + k_pages.shape[2:]
-    return k_pages.reshape(flat), v_pages.reshape(flat), stride
-
-
-def _with_index(layers: Any):
-    """Scan operand: the stacked layer parameters beside each layer's
-    index (the pool offset is computed from it)."""
-    n = jax.tree.leaves(layers)[0].shape[0]
-    return layers, jnp.arange(n, dtype=jnp.int32)
-
-
-def _decode_body(cfg: tfm.TransformerConfig, params: Any,
-                 k_pages: jax.Array, v_pages: jax.Array,
-                 block_tables: jax.Array, lengths: jax.Array,
-                 tokens: jax.Array, *, n_layers: Optional[int] = None):
-    """One decode step over all slots: tokens ``[S]`` (this step's input
-    token per slot), lengths ``[S]`` (tokens already cached — the
-    position this token lands at). Empty slots carry length 0 and
-    scratch-page block tables; their writes sink into the scratch page
-    and their outputs are ignored by the scheduler.
-
-    The SAME body at batch ``slots * (K+1)`` is the speculative verify
-    step: each slot's block-table row repeated K+1 times with lengths
-    ``len_s .. len_s + K`` and tokens ``[last_accepted, draft_1..K]``
-    — every row's K/V lands in the pages BEFORE the layer attends, so
-    the ragged-lengths attention gives each row exact causality over
-    the drafts that precede it, and row i's argmax is bitwise what
-    sequential decode would emit after consuming rows 0..i.
-
-    ``n_layers`` (static) truncates the stack: layers ``0..n-1`` of
-    the target plus the shared final norm/head — the self-drafting
-    model of the ``truncate:N`` speculative mode. It scans fewer layers
-    over the same pool, so layers ``>= n`` are not touched. Its K/V
-    writes land in the shared pool; verify recomputes those layers'
-    identical values over the same positions and overwrites them, so no
-    reader ever observes a draft-only value."""
-    scale = cfg.head_dim ** -0.5
-    x = tp_lib.vocab_parallel_embed(
-        tokens, params["embed"].astype(cfg.dtype), cfg.tp_axis)   # [S, D]
-    layers = params["layers"]
-    if n_layers is not None:
-        layers = jax.tree.map(lambda a: a[:n_layers], layers)
-    # Speculative rows near the context ceiling can carry positions past
-    # the last block-table column; the gather would clamp them INTO the
-    # request's own last page and corrupt it. Route them to scratch —
-    # accepted lengths never reach them, so the value is never read.
-    n_ctx = block_tables.shape[1] * k_pages.shape[2]
-    valid = lengths < n_ctx
-    kp, vp, stride = _flat_pool(k_pages, v_pages)
-
-    def layer(carry, xs):
-        x, kp, vp = carry
-        lp, li = xs
-        base = li * stride
-        bt = block_tables + base
-        h = tfm._rmsnorm(x, lp["attn_norm"])
-        q, k, v = _qkv(cfg, lp, h)                       # [S, Hl, Dh]
-        q = _rope_rows(q, lengths)
-        k = _rope_rows(k, lengths)
-        with jax.named_scope("hvd_kv_write"):
-            kp, vp = kvc.write_token_kv(
-                kp, vp, k, v, bt, lengths, valid=valid,
-                scratch=base + stride - 1)
-        with jax.named_scope("hvd_attention"):
-            o = kvc.paged_decode_attention(
-                q, kp, vp, bt, lengths + 1, scale)
-        o = o.astype(x.dtype).reshape(x.shape[0], -1)
-        x = x + tp_lib.row_parallel(o, lp["wo"].astype(cfg.dtype),
-                                    cfg.tp_axis).astype(x.dtype)
-        with jax.named_scope("hvd_mlp"):
-            x = x + _mlp(cfg, lp, x).astype(x.dtype)
-        return (x, kp, vp), None
-
-    (x, kp, vp), _ = lax.scan(layer, (x, kp, vp), _with_index(layers))
-    x = tfm._rmsnorm(x, params["final_norm"])
-    logits = _gather_logits(cfg, x, params["head"])       # [S, V] f32
-    next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return (kp.reshape(k_pages.shape), vp.reshape(v_pages.shape),
-            next_tokens, logits)
-
-
-def _prefill_body(cfg: tfm.TransformerConfig, params: Any,
-                  k_pages: jax.Array, v_pages: jax.Array,
-                  block_table: jax.Array, start: jax.Array,
-                  n_real: jax.Array, tokens: jax.Array):
-    """One prefill chunk of ONE sequence: tokens ``[C]`` (bucket-padded),
-    positions ``start .. start+n_real`` written to the pages, causal
-    attention over the cached prefix + the chunk, last real token's
-    logits out. Chunked prefill: a later chunk attends over the earlier
-    chunks through the pages it finds already written."""
-    scale = cfg.head_dim ** -0.5
-    c = tokens.shape[0]
-    pos = start + jnp.arange(c, dtype=jnp.int32)
-    x = tp_lib.vocab_parallel_embed(
-        tokens, params["embed"].astype(cfg.dtype), cfg.tp_axis)   # [C, D]
-    page = k_pages.shape[2]
-    n_ctx = block_table.shape[0] * page
-    kp, vp, stride = _flat_pool(k_pages, v_pages)
-
-    def layer(carry, xs):
-        x, kp, vp = carry
-        lp, li = xs
-        base = li * stride
-        bt = block_table + base
-        h = tfm._rmsnorm(x, lp["attn_norm"])
-        q, k, v = _qkv(cfg, lp, h)                       # [C, Hl, Dh]
-        q = _rope_rows(q, pos)
-        k = _rope_rows(k, pos)
-        with jax.named_scope("hvd_kv_write"):
-            kp, vp = kvc.write_chunk_kv(kp, vp, k, v, bt, start, n_real,
-                                        scratch=base + stride - 1)
-        with jax.named_scope("hvd_attention"):
-            kg = kvc.gather_pages(kp, bt).astype(jnp.float32)
-            vg = kvc.gather_pages(vp, bt).astype(jnp.float32)
-            s = jnp.einsum("chd,shd->chs", q.astype(jnp.float32),
-                           kg) * scale
-            ctx = jnp.arange(n_ctx, dtype=jnp.int32)
-            visible = ctx[None, :] <= pos[:, None]       # causal + prefix
-            s = jnp.where(visible[:, None, :], s, -jnp.inf)
-            m = jnp.max(s, axis=-1, keepdims=True)
-            m = jnp.where(jnp.isfinite(m), m, 0.0)
-            p = jnp.where(visible[:, None, :], jnp.exp(s - m), 0.0)
-            l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-            o = jnp.einsum("chs,shd->chd", p / l, vg)
-        o = o.astype(x.dtype).reshape(c, -1)
-        x = x + tp_lib.row_parallel(o, lp["wo"].astype(cfg.dtype),
-                                    cfg.tp_axis).astype(x.dtype)
-        with jax.named_scope("hvd_mlp"):
-            x = x + _mlp(cfg, lp, x).astype(x.dtype)
-        return (x, kp, vp), None
-
-    (x, kp, vp), _ = lax.scan(layer, (x, kp, vp),
-                              _with_index(params["layers"]))
-    x = tfm._rmsnorm(x, params["final_norm"])
-    last = jnp.take(x, jnp.maximum(n_real - 1, 0), axis=0)     # [D]
-    logits = _gather_logits(cfg, x=last, head=params["head"])  # [V] f32
-    next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return (kp.reshape(k_pages.shape), vp.reshape(v_pages.shape),
-            next_token, logits)
-
-
-# ---------------------------------------------------------------------------
-# what the engine asks of a model
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class ServeModel:
-    """A model as the engine sees it. The engine owns slots, pages, block
-    tables, the AOT/store plumbing and the program names; the model says
-    what one token caches and what a step computes.
-
-    ``decode(cfg, params, *pool, *state, block_tables, lengths, tokens)``
-    and ``prefill(cfg, params, *pool, *state, block_table, start, n_real,
-    tokens)`` return ``(*pool, *state, next_token(s), logits)``: ``pool``
-    one array per :class:`~horovod_tpu.serving.kv_cache.CacheRows` of
-    ``cache_rows(cfg)``, each ``[blocks, n_pages + 1, page, *row]``;
-    ``state`` the further device arrays of ``state(cfg)`` (running
-    counters), donated and handed on like the pool but left to the
-    compiler's layout. ``check(cfg, draft_mode)`` refuses what the model
-    cannot serve, in ``ValueError``'s words; ``stats(cfg, state)`` is
-    what ``ServeEngine.stats()`` publishes of ``state`` (the one place
-    it is read back). ``draft`` (the decode body over the first
-    ``n_layers`` layers) only where the model offers ``truncate:N``.
-
-    ``served_params(cfg, placed)`` is the tree the programs read, made
-    once at engine build from the tree as placed on the device: the
-    same structure, each leaf either the placed array itself or a copy
-    of it in another dtype under the same sharding (:func:`cast_once`).
-    Absent, the programs read the tree as given."""
-    check: Callable[[Any, str], None]
-    cache_rows: Callable[[Any], Tuple[kvc.CacheRows, ...]]
-    decode: Callable[..., Tuple]
-    prefill: Callable[..., Tuple]
-    param_specs: Callable[[Any], Any]
-    served_params: Optional[Callable[[Any, Any], Any]] = None
-    state: Callable[[Any], Tuple[jax.ShapeDtypeStruct, ...]] = lambda cfg: ()
-    stats: Optional[Callable[[Any, Tuple], Dict[str, Any]]] = None
-    draft: Optional[Callable[..., Tuple]] = None
-
-
-def _dense_rows(cfg: tfm.TransformerConfig) -> Tuple[kvc.CacheRows, ...]:
-    return kvc.dense_rows(cfg.n_layers, cfg.n_heads, cfg.head_dim)
-
-
-def _dense_draft(cfg, n_layers, *args):
-    return _decode_body(cfg, *args, n_layers=n_layers)
-
-
-def cast_once(x: Any, dtype: Any) -> Any:
-    """``x`` in ``dtype`` where it lives: the array itself when it is in
-    ``dtype`` already (no copy), else one cast on the device that keeps
-    its sharding. ``x`` is neither donated nor deleted. A
-    ``ShapeDtypeStruct`` (what a compile-only lowering has of a tree) is
-    answered with one."""
-    if x.dtype == dtype:
-        return x
-    if isinstance(x, jax.ShapeDtypeStruct):
-        return jax.ShapeDtypeStruct(x.shape, dtype, sharding=x.sharding)
-    return jax.jit(lambda a: a.astype(dtype), out_shardings=x.sharding)(x)
-
-
-# What the dense bodies cast to ``cfg.dtype`` on the way into a product.
-# The norm scales are not among them: ``_rmsnorm`` multiplies the scale
-# in float32, so casting it would change the numbers.
-_DENSE_PRODUCT_LEAVES = ("wq", "wk", "wv", "wo", "w_in", "w_out")
-
-
-def _dense_served_params(cfg: tfm.TransformerConfig, params: Any) -> Any:
-    """The dense tree with every product's weights in ``cfg.dtype``: what
-    ``_qkv``, ``_mlp``, ``_gather_logits``, ``wo`` and the embedding
-    would otherwise cast in every run of every program."""
-    dt, layers = cfg.dtype, params["layers"]
-    return {**params,
-            "embed": cast_once(params["embed"], dt),
-            "head": cast_once(params["head"], dt),
-            "layers": {**layers, **{n: cast_once(layers[n], dt)
-                                    for n in _DENSE_PRODUCT_LEAVES}}}
-
-
-DENSE = ServeModel(
-    check=lambda cfg, draft_mode: _check_cfg(cfg), cache_rows=_dense_rows,
-    decode=_decode_body, prefill=_prefill_body,
-    param_specs=tfm.param_specs, served_params=_dense_served_params,
-    draft=_dense_draft)
-
-
 def serve_model(cfg: Any) -> ServeModel:
-    """The dense block for a ``TransformerConfig``; any other config
-    brings its own (``cfg.serve_model()``)."""
-    if isinstance(cfg, tfm.TransformerConfig):
-        return DENSE
+    """What ``cfg``'s model hands the engine: its ``cfg.serve_model()``."""
     own = getattr(cfg, "serve_model", None)
     if own is None:
         raise TypeError(
-            f"serving needs a TransformerConfig or a config with a "
-            f"serve_model() of its own, got {type(cfg).__name__}")
+            f"serving needs a config with a serve_model() of its own (as "
+            f"TransformerConfig and LongCatFlashConfig have), got "
+            f"{type(cfg).__name__}")
     return own()
 
 
@@ -500,7 +212,7 @@ class ServeEngine:
     step-boundary API the scheduler drives.
     """
 
-    def __init__(self, cfg: tfm.TransformerConfig, params: Any,
+    def __init__(self, cfg: Any, params: Any,
                  mesh: Optional[Mesh] = None, *,
                  slots: Optional[int] = None,
                  page: Optional[int] = None,

@@ -18,10 +18,11 @@ Split of responsibilities:
   (:class:`CacheRows`: what one token stores in each cached block),
   ``[blocks, n_pages + 1, page, *row]`` — donated to every step. The
   engine keeps them in ONE row-major layout
-  (:func:`pool_format`) and its step bodies address them as one flat
-  run of pages, layer ``l``'s page ``p`` at ``l * (n_pages + 1) + p``,
-  so a step's only pool-shaped instructions are in-place scatters
-  (docs/serving.md, "Pool layout"). One extra *scratch page* per layer
+  (:func:`pool_format`) and the models' step bodies address them as one
+  flat run of pages (:func:`flat_pool`, :func:`block_pages`: block
+  ``b``'s page ``p`` at ``b * (n_pages + 1) + p``), so a step's only
+  pool-shaped instructions are in-place scatters (docs/serving.md,
+  "Pool layout"). One extra *scratch page* per layer
   (physical id ``n_pages``) absorbs the writes of padded positions and
   empty slots — every store the compiled step issues targets a valid
   physical page, no predication needed.
@@ -450,27 +451,36 @@ class BlockTables:
 
 
 # ---------------------------------------------------------------------------
-# functional page writes (used inside the compiled steps)
+# the pool inside the compiled steps: how a block is addressed, page writes
 # ---------------------------------------------------------------------------
 
-def write_token_kv(k_pages: jax.Array, v_pages: jax.Array,
-                   k_new: jax.Array, v_new: jax.Array,
-                   block_tables: jax.Array, positions: jax.Array,
-                   valid: Optional[jax.Array] = None,
-                   scratch: Optional[jax.Array] = None
-                   ) -> Tuple[jax.Array, jax.Array]:
-    """Scatter one token's K/V per sequence into its page.
+def flat_pool(*pools: jax.Array) -> Tuple[jax.Array, ...]:
+    """Each pool array ``[blocks, P+1, page, *row]`` as one run of pages
+    ``[blocks*(P+1), page, *row]`` (a bitcast in the pool's row-major
+    layout). The step bodies carry these whole through the layer scan and
+    address a block through :func:`block_pages`, so no instruction slices
+    a block's pool out or stacks it back; ``flat.reshape(pool.shape)``
+    gives the pool back."""
+    return tuple(p.reshape((-1,) + p.shape[2:]) for p in pools)
 
-    k_pages/v_pages ``[n_phys, page, KVH, D]``, k_new/v_new
-    ``[B, KVH, D]``, positions ``[B]`` (global token index the write
-    lands at), valid ``[B]`` bool — invalid writes are routed to the
-    scratch page instead of being dropped, which keeps the op a plain
-    scatter. ``scratch`` is that page's physical id: the last page of a
-    single layer's pool by default; over the engine's flat pool the
-    block tables carry the layer's offset and the caller names the
-    layer's own scratch page."""
-    return write_token_rows((k_pages, v_pages), (k_new, v_new), block_tables,
-                            positions, valid=valid, scratch=scratch)
+
+def block_pages(pool_shape: Tuple[int, ...], block: jax.Array,
+                block_tables: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Cached block ``block`` of a pool of shape ``pool_shape`` among the
+    flat run of :func:`flat_pool`: (``block_tables`` offset to the block's
+    pages, the block's own scratch page). Block ``b``'s page ``p`` is flat
+    page ``b*(P+1) + p``, its scratch page the last of those. The one
+    place that knows."""
+    stride = pool_shape[1]
+    base = block * stride
+    return block_tables + base, base + stride - 1
+
+
+def with_index(layers):
+    """Scan operand: the stacked layer parameters beside each layer's
+    index (what a body hands to :func:`block_pages`)."""
+    n = jax.tree.leaves(layers)[0].shape[0]
+    return layers, jnp.arange(n, dtype=jnp.int32)
 
 
 def write_token_rows(pages: Sequence[jax.Array], new: Sequence[jax.Array],
@@ -478,9 +488,16 @@ def write_token_rows(pages: Sequence[jax.Array], new: Sequence[jax.Array],
                      valid: Optional[jax.Array] = None,
                      scratch: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, ...]:
-    """:func:`write_token_kv` for however many arrays one block of the
-    pool has: ``pages[i]`` ``[n_phys, page, *row_i]`` takes ``new[i]``
-    ``[B, *row_i]``, all at the same page and offset."""
+    """Scatter one token's rows per sequence into its page, for however
+    many arrays one block of the pool has (the dense block's: K and V).
+
+    ``pages[i]`` ``[n_phys, page, *row_i]`` takes ``new[i]``
+    ``[B, *row_i]``, all at the same page and offset; positions ``[B]``
+    (global token index the write lands at), valid ``[B]`` bool — invalid
+    writes are routed to the scratch page instead of being dropped, which
+    keeps the op a plain scatter. ``scratch`` is that page's physical id:
+    the last page of a single block's pool by default; over the flat pool
+    both come from :func:`block_pages`."""
     page = pages[0].shape[1]
     if scratch is None:
         scratch = pages[0].shape[0] - 1
@@ -493,27 +510,16 @@ def write_token_rows(pages: Sequence[jax.Array], new: Sequence[jax.Array],
     return tuple(p.at[phys, offs].set(n) for p, n in zip(pages, new))
 
 
-def write_chunk_kv(k_pages: jax.Array, v_pages: jax.Array,
-                   k_new: jax.Array, v_new: jax.Array,
-                   block_table: jax.Array, start: jax.Array,
-                   n_real: jax.Array, scratch: Optional[jax.Array] = None
-                   ) -> Tuple[jax.Array, jax.Array]:
-    """Scatter a prefill chunk's K/V (one sequence) into its pages.
-
-    k_new/v_new ``[C, KVH, D]`` for chunk positions
-    ``start .. start + C``; positions at or past ``start + n_real`` are
-    padding and land on the scratch page (``scratch``, as in
-    :func:`write_token_kv`). block_table ``[n_max]``."""
-    return write_chunk_rows((k_pages, v_pages), (k_new, v_new), block_table,
-                            start, n_real, scratch=scratch)
-
-
 def write_chunk_rows(pages: Sequence[jax.Array], new: Sequence[jax.Array],
                      block_table: jax.Array, start: jax.Array,
                      n_real: jax.Array, scratch: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, ...]:
-    """:func:`write_chunk_kv` for however many arrays one block of the
-    pool has (``new[i]`` ``[C, *row_i]``)."""
+    """Scatter a prefill chunk's rows (one sequence) into its pages.
+
+    ``new[i]`` ``[C, *row_i]`` for chunk positions ``start .. start + C``;
+    positions at or past ``start + n_real`` are padding and land on the
+    scratch page (``scratch``, as in :func:`write_token_rows`).
+    block_table ``[n_max]``."""
     page = pages[0].shape[1]
     if scratch is None:
         scratch = pages[0].shape[0] - 1

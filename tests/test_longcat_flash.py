@@ -357,9 +357,12 @@ def test_weights_stay_in_the_dtype_given():
 
 
 def _normal(text):
-    """A compiled text without what moves with a source line or a core
-    count: the tables of files and stack frames, metadata, backend
-    configuration."""
+    """A compiled text without what moves with a source line, a core count
+    or the order in which the tracer made the instructions: the tables of
+    files and stack frames, metadata, backend configuration, and every
+    instruction's and computation's name replaced by its rank of first
+    appearance (``%mul.394`` and ``%mul.430`` of two traces of one
+    program are both ``%v17``)."""
     out = []
     for line in text.splitlines():
         if re.match(r"^(\d+ |FileNames|FunctionNames|FileLocations|"
@@ -369,13 +372,17 @@ def _normal(text):
         line = re.sub(r", backend_config=\{.*\}$", "", line)
         line = re.sub(r", frontend_attributes=\{[^}]*\}", "", line)
         out.append(line.rstrip())
-    return "\n".join(out)
+    names = {}
+    return re.sub(r"%[A-Za-z_][\w.\-]*",
+                  lambda m: names.setdefault(m.group(0), f"%v{len(names)}"),
+                  "\n".join(out))
 
 
 def test_dense_programs_are_unchanged_by_the_model_described_pool():
     """The dense block's decode, prefill and copy-on-write programs, compiled
-    for a small config on the CPU, against what the tree before the refactor
-    (PR 27's) compiled: the same text once source lines are taken out, so the
+    for a small config on the CPU, against what the tree before the bodies
+    moved out of the engine and onto the one block (PR 30's) compiled: the
+    same text once source lines and instruction names are taken out, so the
     same pool arrays, the same layout pin and no new instruction.
     ``tests/data/serve_dense_programs.json`` holds that tree's readings."""
     with open(os.path.join(ROOT, "tests", "data",
@@ -398,3 +405,42 @@ def test_dense_programs_are_unchanged_by_the_model_described_pool():
         assert dict(sorted(ops.items())) == was["opcodes"], label
         assert hashlib.sha256(text.encode()).hexdigest() == was["sha256"], \
             label
+
+
+# ---------------------------------------------------------------------------
+# the one rotary (models/transformer.py: training, the dense serve bodies
+# and this model's q_rope / k_r all call it)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_rope_on_a_batch_is_rope_row_by_row_bitwise(dtype):
+    """What training rotates (``[B, S, H, D]`` with one ``pos[S]``) and what
+    a decode step or a prefill chunk rotates (``[N, H, D]`` with a position
+    a row) are the same bits: a cached key is the key training saw."""
+    b, s, h, d = 3, 17, 4, 16
+    x = jax.random.normal(jax.random.PRNGKey(5), (b, s, h, d)).astype(dtype)
+    pos = 1000 + jnp.arange(s, dtype=jnp.int32)
+    batch = tfm.rope(x, pos)
+    rows = tfm.rope(x.reshape(b * s, h, d), jnp.tile(pos, b))
+    assert batch.dtype == rows.dtype == dtype
+    np.testing.assert_array_equal(
+        np.asarray(batch.astype(jnp.float32)).reshape(b * s, h, d),
+        np.asarray(rows.astype(jnp.float32)))
+    # ... and a key without heads is the one-head key
+    np.testing.assert_array_equal(
+        np.asarray(tfm.rope(x[0, :, 0], pos, heads=0).astype(jnp.float32)),
+        np.asarray(batch[0, :, 0].astype(jnp.float32)))
+    assert np.any(np.asarray(batch != x))
+
+
+def test_rope_of_a_headless_key_equals_the_reference_at_its_theta():
+    n, d, theta = 33, 8, 1e7
+    k_r = jax.random.normal(jax.random.PRNGKey(6), (n, d), jnp.float32)
+    pos = jnp.arange(n, dtype=jnp.int32) * 97           # up to 3104
+    got = tfm.rope(k_r, pos, theta, heads=0)
+    want = ref.rope(k_r[:, None, :], pos, theta)[:, 0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=0, atol=4 * np.finfo(np.float32).eps)
+    # theta matters: the dense model's default is another rotation
+    assert np.abs(np.asarray(tfm.rope(k_r, pos, heads=0) - got)).max() > 0.1

@@ -895,71 +895,52 @@ def test_draft_spec_validation_errors():
 
 def _stacked_pool_bodies(cfg):
     """The step bodies over a STACKED pool, the reference the engine's
-    flat pool is held to: the layer scan takes layer ``l``'s pool
-    ``[P+1, page, KVH, D]`` as a slice, ``write_token_kv`` /
-    ``write_chunk_kv`` write it with plain block tables and its own last
-    page as the scratch page, and the results are stacked back."""
+    flat pool is held to: the one dense block (``tfm.block``, with the
+    attentions the engine's bodies use) under an ``attend`` that takes
+    layer ``l``'s pool ``[P+1, page, KVH, D]`` as a slice of the scan,
+    writes it through ``write_token_rows`` / ``write_chunk_rows`` with
+    plain block tables and its own last page as the scratch page, and
+    stacks the results back."""
     from jax import lax
     from horovod_tpu.parallel import tensor_parallel as tp_lib
-    from horovod_tpu.serving import engine as E
     scale = cfg.head_dim ** -0.5
 
-    def tail(lp, x, o):
-        o = o.astype(x.dtype).reshape(x.shape[0], -1)
-        x = x + tp_lib.row_parallel(o, lp["wo"].astype(cfg.dtype),
-                                    cfg.tp_axis).astype(x.dtype)
-        return x + E._mlp(cfg, lp, x).astype(x.dtype)
-
-    def decode(params, k_pages, v_pages, block_tables, lengths, tokens):
+    def stacked(params, k_pages, v_pages, tokens, pos, write, attend):
         x = tp_lib.vocab_parallel_embed(
             tokens, params["embed"].astype(cfg.dtype), cfg.tp_axis)
-        valid = lengths < block_tables.shape[1] * k_pages.shape[2]
 
         def layer(x, xs):
-            lp, kp, vp = xs
-            q, k, v = E._qkv(cfg, lp, tfm._rmsnorm(x, lp["attn_norm"]))
-            q, k = E._rope_rows(q, lengths), E._rope_rows(k, lengths)
-            kp, vp = kvc.write_token_kv(kp, vp, k, v, block_tables,
-                                        lengths, valid=valid)
-            o = kvc.paged_decode_attention(q, kp, vp, block_tables,
-                                           lengths + 1, scale)
-            return tail(lp, x, o), (kp, vp)
+            lp, *pages = xs
 
-        _, (k_new, v_new) = lax.scan(
-            layer, x, (params["layers"], k_pages, v_pages))
-        return k_new, v_new
+            def attend_sliced(q, k, v):
+                pages[:] = write(pages, (k, v))
+                return attend(q, *pages)
+
+            x = tfm.block(cfg, lp, x, pos, attend_sliced,
+                          tfm._mlp_half(cfg, lp))
+            return x, tuple(pages)
+
+        _, pools = lax.scan(layer, x, (params["layers"], k_pages, v_pages))
+        return pools
+
+    def decode(params, k_pages, v_pages, block_tables, lengths, tokens):
+        valid = lengths < block_tables.shape[1] * k_pages.shape[2]
+        return stacked(
+            params, k_pages, v_pages, tokens, lengths,
+            lambda pages, new: kvc.write_token_rows(
+                pages, new, block_tables, lengths, valid=valid),
+            lambda q, kp, vp: kvc.paged_decode_attention(
+                q, kp, vp, block_tables, lengths + 1, scale))
 
     def prefill(params, k_pages, v_pages, block_table, start, n_real,
                 tokens):
-        c = tokens.shape[0]
-        pos = start + jnp.arange(c, dtype=jnp.int32)
-        x = tp_lib.vocab_parallel_embed(
-            tokens, params["embed"].astype(cfg.dtype), cfg.tp_axis)
-        n_ctx = block_table.shape[0] * k_pages.shape[2]
-
-        def layer(x, xs):
-            lp, kp, vp = xs
-            q, k, v = E._qkv(cfg, lp, tfm._rmsnorm(x, lp["attn_norm"]))
-            q, k = E._rope_rows(q, pos), E._rope_rows(k, pos)
-            kp, vp = kvc.write_chunk_kv(kp, vp, k, v, block_table, start,
-                                        n_real)
-            kg = kvc.gather_pages(kp, block_table).astype(jnp.float32)
-            vg = kvc.gather_pages(vp, block_table).astype(jnp.float32)
-            s = jnp.einsum("chd,shd->chs", q.astype(jnp.float32),
-                           kg) * scale
-            visible = (jnp.arange(n_ctx, dtype=jnp.int32)[None, :]
-                       <= pos[:, None])
-            s = jnp.where(visible[:, None, :], s, -jnp.inf)
-            m = jnp.max(s, axis=-1, keepdims=True)
-            m = jnp.where(jnp.isfinite(m), m, 0.0)
-            p = jnp.where(visible[:, None, :], jnp.exp(s - m), 0.0)
-            l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-            o = jnp.einsum("chs,shd->chd", p / l, vg)
-            return tail(lp, x, o), (kp, vp)
-
-        _, (k_new, v_new) = lax.scan(
-            layer, x, (params["layers"], k_pages, v_pages))
-        return k_new, v_new
+        pos = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
+        return stacked(
+            params, k_pages, v_pages, tokens, pos,
+            lambda pages, new: kvc.write_chunk_rows(
+                pages, new, block_table, start, n_real),
+            lambda q, kp, vp: tfm.attend_gathered(
+                q, kp, vp, block_table, pos, scale))
 
     return decode, prefill
 
@@ -1164,3 +1145,62 @@ def test_reload_probe_runs_on_one_device_of_the_pools_sharding(tp):
     assert eng.reload_keeps_layout is True
     assert artifact_store.reload_keeps_layout(
         eng.pool_format, (1, 1, 16, 2, 16), jnp.bfloat16) is True
+
+
+# ---------------------------------------------------------------------------
+# layering: the engine holds no model, no model imports the engine
+# ---------------------------------------------------------------------------
+
+_PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "horovod_tpu")
+
+
+def test_models_do_not_reach_into_the_engine():
+    """In a fresh interpreter ``import horovod_tpu.models`` loads nothing
+    of ``horovod_tpu.serving``, and no source file under ``models/`` names
+    ``serving.engine``: a model builds its ``ServeModel`` from
+    ``serving.model`` and ``serving.kv_cache``, inside functions."""
+    import subprocess
+    import sys
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, horovod_tpu.models\n"
+         "print(sorted(m for m in sys.modules "
+         "if m.startswith('horovod_tpu.serving')))"],
+        capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == "[]"
+    models = os.path.join(_PACKAGE, "models")
+    for name in sorted(os.listdir(models)):
+        if name.endswith(".py"):
+            with open(os.path.join(models, name)) as f:
+                assert "serving.engine" not in f.read(), name
+
+
+def test_the_engine_names_no_leaf_and_no_scope():
+    with open(os.path.join(_PACKAGE, "serving", "engine.py")) as f:
+        source = f.read()
+    for word in ("named_scope(", '"wq"', '"w_in"', '"attn_norm"',
+                 "tensor_parallel", "TransformerConfig)"):
+        assert word not in source, word
+
+
+@pytest.mark.parametrize("family", ["dense", "longcat", "none"])
+def test_serve_model_asks_the_config(family):
+    from horovod_tpu.models import LongCatFlashConfig
+    from horovod_tpu.serving import engine as eng_mod
+    if family == "none":
+        with pytest.raises(TypeError, match="serve_model"):
+            eng_mod.serve_model(object())
+        return
+    cfg = _cfg() if family == "dense" else LongCatFlashConfig()
+    model = eng_mod.serve_model(cfg)
+    assert isinstance(model, eng_mod.ServeModel)
+    assert len(model.cache_rows(cfg)) == (2 if family == "dense" else 1)
+    assert (model.draft is not None) == (family == "dense")
+    # the names the benchmark's compile check and bench.py import
+    assert eng_mod._decode_body is tfm.decode_body
+    assert eng_mod._prefill_body is tfm.prefill_body
+    assert eng_mod.cast_once(jnp.ones((2,), jnp.float32),
+                             jnp.bfloat16).dtype == jnp.bfloat16

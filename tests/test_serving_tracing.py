@@ -328,7 +328,7 @@ def test_the_engines_device_programs_carry_names():
             assert any(f"/{scope}/" in n for n in names), (label, scope)
 
 
-def test_the_train_steps_scopes_are_in_the_compiled_hlo():
+def _train_step_text():
     import optax
     from horovod_tpu.parallel import trainer
     hvd.init(devices=jax.devices()[:2])
@@ -339,7 +339,26 @@ def test_the_train_steps_scopes_are_in_the_compiled_hlo():
         cfg, optax.sgd(0.1, momentum=0.9), mesh)
     state = init(jax.random.PRNGKey(0))
     tokens = jnp.zeros((4, 32), jnp.int32)
-    text = step.lower(state, tokens, tokens).compile().as_text()
+    return step.lower(state, tokens, tokens).compile().as_text()
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill_32",
+                                     "train_step"])
+def test_no_norm_stands_under_hvd_mlp(program):
+    """The block's ``mlp_norm`` stands outside ``hvd_mlp`` in every program
+    made of it, as its ``attn_norm`` stands outside ``hvd_attention``: the
+    scope's device time is the MLP's products and activation."""
+    text = (_train_step_text() if program == "train_step"
+            else _scheduler().engine.executable_text(program))
+    norms = [m.group(1) for m in re.finditer(
+        r' rsqrt\(.*op_name="([^"]*)"', text)]
+    assert norms                        # the norms are there, and named
+    assert not [n for n in norms if "hvd_mlp" in n or "hvd_attention" in n]
+    assert any("hvd_mlp" in n for n in re.findall(r'op_name="([^"]*)"', text))
+
+
+def test_the_train_steps_scopes_are_in_the_compiled_hlo():
+    text = _train_step_text()
     names = set(re.findall(r'op_name="([^"]*)"', text))
 
     def some(scope, *parts):

@@ -90,7 +90,7 @@ def _call(eng, program):
                 jnp.asarray(BUCKET - 3), jnp.asarray(chunk))
         eng.release(slot)
         return (eng._prefill[BUCKET],
-                functools.partial(eng_mod._prefill_body, cfg), args)
+                functools.partial(tfm.prefill_body, cfg), args)
     rows = SLOTS * (SPEC_K + 1) if program == "verify" else SLOTS
     rep = rows // SLOTS
     bt = np.repeat(eng.tables.tables, rep, axis=0)
@@ -102,7 +102,7 @@ def _call(eng, program):
             jnp.asarray(rng.integers(0, 256, rows, np.int32)))
     compiled = {"decode": eng._decode, "verify": eng._verify,
                 "draft": eng._draft}[program]
-    body = functools.partial(eng_mod._decode_body, cfg,
+    body = functools.partial(tfm.decode_body, cfg,
                              n_layers=1 if program == "draft" else None)
     return compiled, body, args
 

@@ -17,6 +17,13 @@ compiled program families from the model's bodies —
   pages (the same program at batch ``slots * (K+1)`` is the speculative
   verify step; over a model's first layers, its ``truncate:N`` draft).
 
+Tokens go from program to program on the device: the engine keeps every
+slot's newest token in one ``[slots] int32`` vector there, a decode step
+reads it and its result replaces it, and a prompt's first token is put
+into it by a third, model-independent program (``hvd_serve_token``). So a
+step can be queued before the host has read the one before
+(:meth:`ServeEngine.decode_step`).
+
 Every variant is AOT-compiled at engine boot and served through the
 PR 12 artifact store under the ``serve`` kind, so a warm replica
 reaches its first token with ZERO builder invocations
@@ -193,6 +200,14 @@ def serve_programs(cfg: Any, fmt: Union[Format, Sequence[Format]],
     return programs
 
 
+def hvd_serve_token(newest: jax.Array, token: jax.Array,
+                    slot: jax.Array) -> jax.Array:
+    """``newest`` with ``token`` at ``slot``: how a prompt's first token
+    reaches the next decode step without passing through the host (the
+    compiled module reads ``jit_hvd_serve_token``)."""
+    return newest.at[slot].set(token)
+
+
 def _abstract(x: Any) -> Any:
     """Shape, dtype and placement of a live array, for lowering."""
     return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
@@ -345,6 +360,11 @@ class ServeEngine:
         # program that copied or re-laid the pool would read pool-sized
         self.program_temp_bytes: Dict[str, int] = {}
         self._dispatch: Dict[str, Callable] = {}
+        # decode steps enqueued; those enqueued while the step before was
+        # still unread; and how often the host read a step with nothing
+        # queued behind it, by what made it
+        self.decode_counts: Dict[str, Any] = {
+            "steps": 0, "dispatched_ahead": 0, "drained": {}}
         with (contextlib.nullcontext() if self.reload_keeps_layout
               else compile_cache.uncached()):
             self._build()
@@ -399,6 +419,14 @@ class ServeEngine:
         self.state: Tuple[jax.Array, ...] = tuple(
             jax.device_put(jnp.zeros(s.shape, s.dtype), sharding)
             for s in self.model.state(self.cfg))
+        # every slot's newest token, where the next decode step reads it;
+        # and the decode step whose tokens the host has not read yet
+        self._newest: jax.Array = jax.device_put(
+            jnp.zeros((self.slots,), jnp.int32), sharding)
+        self._unread: Optional[jax.Array] = None
+        self._put_first = self._adopt(
+            jax.jit(hvd_serve_token),
+            (_abstract(self._newest), _i32(), _i32()), "serve_first_token")
         self._decode = self._adopt(
             self._decode_jit, self._decode_args(), "serve_decode")
         self._prefill: Dict[int, Callable] = {}
@@ -578,13 +606,16 @@ class ServeEngine:
         return self.buckets[-1]
 
     def prefill_chunk(self, slot: int, prompt: np.ndarray,
-                      start: int) -> Tuple[int, Optional[int]]:
+                      start: int) -> Tuple[int, Optional[jax.Array]]:
         """Run ONE bucket-sized prefill chunk of ``prompt`` beginning at
         ``start``; returns (next_start, first_token) where first_token
         is the greedy argmax at the last prompt position — None while
-        chunks remain. The scheduler calls this once per cycle so
-        in-flight decodes stall one chunk at a time, never the whole
-        prompt."""
+        chunks remain, and on the last chunk a device scalar the host
+        has NOT read: the call never waits for the device, ``int()`` of
+        it does. The same token is already among the slots' newest on
+        the device, so a decode step can follow at once. The scheduler
+        calls this once per cycle so in-flight decodes stall one chunk
+        at a time, never the whole prompt."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("empty prompt")
@@ -596,13 +627,11 @@ class ServeEngine:
         n_real = min(prompt.size - start,
                      self.bucket_for(prompt.size - start))
         bucket = self.bucket_for(n_real)
-        # dispatch returns before the device is done; the wait is the
-        # readback of a prompt's last chunk, below
         with trace.span(
                 "engine.prefill.dispatch", cat=trace.CAT_SERVE,
                 attrs=({"slot": slot, "start": start, "tokens": n_real,
                         "bucket": bucket} if trace.enabled() else None)):
-            bt_row = jnp.asarray(self.tables.tables[slot])
+            bt_row = jnp.asarray(self.tables.tables[slot].copy())
             chunk = np.zeros((bucket,), np.int32)
             chunk[:n_real] = prompt[start:start + n_real]
             tok, _ = self._step(
@@ -617,11 +646,8 @@ class ServeEngine:
             # the next matching prompt adopts these pages (the index
             # takes its own ref — the pages outlive this request)
             self.prefix.register(prompt, self.slot_pages[slot] or [])
-        with trace.span(
-                "engine.prefill.wait", cat=trace.CAT_SERVE,
-                attrs={"slot": slot} if trace.enabled() else None):
-            first = int(tok)
-        return start, first
+        self._newest = self._put_first(self._newest, tok, np.int32(slot))
+        return start, tok
 
     def prefill(self, slot: int, prompt: np.ndarray) -> int:
         """Run the whole prompt through prefill chunks back-to-back;
@@ -630,17 +656,35 @@ class ServeEngine:
         start, token = 0, None
         while token is None:
             start, token = self.prefill_chunk(slot, prompt, start)
-        return token
+        with trace.span(
+                "engine.prefill.wait", cat=trace.CAT_SERVE,
+                attrs={"slot": slot} if trace.enabled() else None):
+            return int(token)
 
-    def decode_step(self, tokens: np.ndarray,
-                    active: Optional[np.ndarray] = None) -> np.ndarray:
-        """One batched decode step: ``tokens[s]`` is slot s's input token
-        (ignored for inactive slots). ``active`` masks the slots actually
+    def decode_step(self, tokens: Optional[np.ndarray] = None,
+                    active: Optional[np.ndarray] = None
+                    ) -> Union[np.ndarray, jax.Array]:
+        """One batched decode step. ``active`` masks the slots actually
         decoding — slots outside it (empty, or MID-PREFILL under the
         chunk interleave) are presented to the compiled step with a
         scratch block table and length 0, so their garbage write can
         never land in pages a concurrent prefill owns. Cached lengths of
-        active slots advance by one."""
+        active slots advance by one when the step is enqueued; the step
+        reads a snapshot of the tables taken then.
+
+        What the caller hands in decides when it sees the result:
+
+        - ``tokens`` from the host (``tokens[s]`` is slot s's input
+          token, ignored for inactive slots): the step's next tokens,
+          read back before the call returns.
+        - ``None``: every active slot continues from the newest token a
+          program of the engine produced for it (its prompt's first, or
+          the last step's), which never left the device. The step is
+          enqueued, THEN the step before it, if the host has not read
+          that yet, is waited for, so the device holds a step while the
+          host reads and schedules; the result is this step's next
+          tokens as a device array nobody has read (``np.asarray`` of it
+          waits; the next such call, or :meth:`drain`, reads it)."""
         if active is None:
             # length 0 means the slot is reserved but its prompt has not
             # finished prefilling (lengths is set at the FINAL chunk) —
@@ -648,28 +692,43 @@ class ServeEngine:
             # the default excludes them too, not just empty slots.
             active = (np.array([p is not None for p in self.slot_pages])
                       & (self.tables.lengths > 0))
+        late = self._unread
         with trace.span(
                 "engine.decode.dispatch", cat=trace.CAT_SERVE,
                 attrs=({"active": int(active.sum())} if trace.enabled()
                        else None)):
-            bt_np = self.tables.tables
-            ln_np = self.tables.lengths
-            if not active.all():
-                bt_np = bt_np.copy()
-                ln_np = ln_np.copy()
-                bt_np[~active] = self.pool.scratch_page
-                ln_np[~active] = 0
-            nxt, _ = self._step(
-                self._decode, jnp.asarray(bt_np), jnp.asarray(ln_np),
-                jnp.asarray(np.asarray(tokens, np.int32)))
-        # Read the result back BEFORE touching the host tables: the
-        # dispatch is asynchronous and jnp.asarray may alias the NumPy
-        # buffers it was given (zero-copy on the CPU backend), so
-        # advancing the lengths first races the step that is reading them.
-        with trace.span("engine.decode.wait", cat=trace.CAT_SERVE):
-            nxt = np.asarray(nxt)
-        self.tables.lengths[active] += 1
+            bt, ln = self.tables.device_views(active)
+            feed = (self._newest if tokens is None
+                    else jnp.asarray(np.asarray(tokens, np.int32)))
+            with (trace.span("engine.decode.ahead", cat=trace.CAT_SERVE)
+                  if late is not None else contextlib.nullcontext()):
+                nxt, _ = self._step(self._decode, bt, ln, feed)
+            self.tables.lengths[active] += 1
+        self.decode_counts["steps"] += 1
+        self.decode_counts["dispatched_ahead"] += late is not None
+        self._newest = self._unread = nxt
+        if tokens is not None:
+            return self.drain("direct")
+        if late is not None:
+            with trace.span("engine.decode.wait", cat=trace.CAT_SERVE):
+                np.asarray(late)
         return nxt
+
+    def drain(self, reason: str) -> Optional[np.ndarray]:
+        """Read the decode step in flight with nothing queued behind it
+        (None where the host has read every step): the device runs dry
+        while the host goes on. ``reason`` says who had to, for
+        ``stats()["decode"]["drained"]``."""
+        late, self._unread = self._unread, None
+        if late is None:
+            return None
+        self._count_drain(reason)
+        with trace.span("engine.decode.wait", cat=trace.CAT_SERVE):
+            return np.asarray(late)
+
+    def _count_drain(self, reason: str) -> None:
+        drained = self.decode_counts["drained"]
+        drained[reason] = drained.get(reason, 0) + 1
 
     # -- speculative decode (draft K, verify all K in one step) --------------
     def propose_drafts(self, tokens: np.ndarray,
@@ -746,6 +805,7 @@ class ServeEngine:
                 self._verify, jnp.asarray(bt),
                 jnp.asarray(ln.astype(np.int32)), jnp.asarray(toks))
         self.tables.lengths[active] += k + 1
+        self._count_drain("speculation")
         with trace.span("engine.verify.wait", cat=trace.CAT_SERVE):
             return np.asarray(nxt).reshape(self.slots, k + 1)
 
@@ -793,6 +853,8 @@ class ServeEngine:
             "prefix_index": (self.prefix.stats()
                              if self.prefix is not None else None),
             "cow_copies": self.cow_copies,
+            "decode": {**self.decode_counts,
+                       "drained": dict(self.decode_counts["drained"])},
             "draft": self.draft_spec,
             "spec_k": self.spec_k,
             "builds": self.builds,

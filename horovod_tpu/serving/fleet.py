@@ -328,6 +328,8 @@ class ServingFleet:
         req.error = None
         req._prefill_pos = 0
         req._last_token_t = 0.0
+        req._dispatched = 0
+        req._first = None
 
     # -- dispatch bookkeeping (called by the router) -------------------------
     def submit_on(self, rep: EngineReplica, req: Request) -> None:

@@ -446,8 +446,18 @@ class BlockTables:
         self.tables[slot, :] = self.scratch_page
         self.lengths[slot] = 0
 
-    def device_views(self) -> Tuple[jax.Array, jax.Array]:
-        return (jnp.asarray(self.tables), jnp.asarray(self.lengths))
+    def device_views(self, active: Optional[np.ndarray] = None
+                     ) -> Tuple[jax.Array, jax.Array]:
+        """The tables and lengths as a step takes them, uploaded from
+        COPIES: an upload may alias the NumPy buffer it is given, and the
+        host moves its tables on while a dispatched step has yet to read
+        them. Slots outside ``active`` show the scratch page and length 0,
+        so their row's write lands where nobody reads."""
+        if active is None:
+            active = np.ones((self.n_slots,), bool)
+        return (jnp.asarray(np.where(active[:, None], self.tables,
+                                     np.int32(self.scratch_page))),
+                jnp.asarray(np.where(active, self.lengths, np.int32(0))))
 
 
 # ---------------------------------------------------------------------------

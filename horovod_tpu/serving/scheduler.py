@@ -12,11 +12,31 @@ of tensors: every engine *step boundary* is a scheduling point —
    request's chunked prefill (bounded by HOROVOD_SERVE_PREFILL_CHUNK,
    so in-flight decodes stall at most one chunk) and records TTFT at
    its first generated token;
-3. **decode** one batched step across all occupied slots.
+3. **decode** one batched step across all occupied slots — enqueued
+   BEFORE the step before it has been read: tokens go from step to step
+   on the device, and the host reads a step's tokens while the device
+   runs the next one (below).
 
 When every slot is idle the scheduler polls the queue with the
 HOROVOD_SERVE_QUEUE_DEADLINE timeout (the cycle-time analogue); while
 anything is decoding, admission happens at every step with no wait.
+
+**One step late.** The programs are greedy and a step's input is the
+last step's output, so the host needs no token's VALUE to schedule the
+next step: cycle c enqueues step c, then waits for step c-1 and appends
+its tokens to the requests that were in it. A request therefore ends by
+what has been DISPATCHED for it: once its dispatched tokens reach
+``max_new_tokens`` it is masked out of the next step, so no request
+decodes past its cap. An ``eos_token`` is seen when its step is read,
+one step late: the step dispatched meanwhile wrote inside the request's
+own reservation, its token is discarded, and the request's tokens end
+with the EOS. A prompt's first token is read with the decode step queued
+behind its last chunk. ``ttft`` / ``tpot`` are stamped when the host
+sees a token. A slot and its pages are released when the request's last
+token has been read; a step still queued over them runs before any later
+program, and the next dispatch shows the scratch page in that row.
+Speculation needs the values to accept a draft and reads every step
+before it goes on.
 
 Per-request output is bitwise-identical to the same request run alone:
 prefill is per-request by construction, and the batched decode computes
@@ -81,10 +101,20 @@ class Request:
     error: Optional[str] = None             # rejected requests carry why
     _last_token_t: float = 0.0
     _prefill_pos: int = 0                   # next prompt offset to prefill
+    _dispatched: int = 0                    # tokens made or queued to be made
+    _first: Any = None                      # the first token, while unread
 
     @property
     def done(self) -> bool:
         return self.finished_at is not None
+
+    @property
+    def ended(self) -> bool:
+        """The tokens the host has seen complete the request: its cap is
+        met, or the last of them is its EOS."""
+        return (len(self.tokens) >= self.max_new_tokens
+                or (self.eos_token is not None and bool(self.tokens)
+                    and self.tokens[-1] == self.eos_token))
 
 
 def _span_attrs() -> Optional[Dict[str, Any]]:
@@ -202,6 +232,13 @@ class ServeScheduler:
         self.prefilling: Dict[int, Request] = {}    # slot -> request
         self.active: Dict[int, Request] = {}        # slot -> request
         self.completed: List[Request] = []
+        # the decode step the device owes the host: (its next tokens,
+        # unread; the (slot, request) pairs in it; the requests whose
+        # prompt's last chunk ran just before it)
+        self._in_flight: Optional[Tuple[Any, List[Tuple[int, Request]],
+                                        List[Request]]] = None
+        # requests whose last chunk ran since the last step was enqueued
+        self._firsts: List[Request] = []
         self._m = _metrics()
         self._decode_steps = 0
         self._cycles = 0
@@ -234,9 +271,7 @@ class ServeScheduler:
         with trace.span("serve.retire", cat=trace.CAT_SERVE, attrs=attrs):
             retired = 0
             for slot, req in list(self.active.items()):
-                hit_eos = (req.eos_token is not None and req.tokens
-                           and req.tokens[-1] == req.eos_token)
-                if len(req.tokens) >= req.max_new_tokens or hit_eos:
+                if req._first is None and req.ended:
                     req.finished_at = now
                     self.engine.release(slot)   # eviction-on-finish
                     del self.active[slot]
@@ -341,19 +376,57 @@ class ServeScheduler:
                 if first is None:
                     continue
                 del self.prefilling[slot]
-                req.tokens.append(first)
-                t = time.perf_counter()
-                req.ttft = (t - req.arrival if req.arrival is not None
-                            else 0.0)
-                req._last_token_t = t
                 self.active[slot] = req
-                self._m["ttft"].observe(max(req.ttft, 0.0))
-                self._m["tokens"].labels(kind="decode").inc()
+                req._first, req._dispatched = first, 1
+                if self._spec:
+                    self._see_first(req)    # a draft starts from its value
+                else:
+                    self._firsts.append(req)
             if attrs is not None:
                 attrs.update(chunks=chunks, prompt_tokens=prefilled)
 
+    def _see_first(self, req: Request) -> None:
+        """The host reads a prompt's first token (waiting for the last
+        chunk where nothing has yet): the request's first, ``ttft``."""
+        req.tokens.append(int(req._first))
+        req._first = None
+        t = time.perf_counter()
+        req.ttft = t - req.arrival if req.arrival is not None else 0.0
+        req._last_token_t = t
+        self._m["ttft"].observe(max(req.ttft, 0.0))
+        self._m["tokens"].labels(kind="decode").inc()
+
+    def _see(self) -> int:
+        """Take what the device owed the host — the decode step before
+        the one just enqueued, read by now — to the requests that were in
+        it; how many tokens that appended. A request the host has
+        meanwhile seen end (its EOS came out of an earlier step) gets
+        nothing: the token is discarded."""
+        if self._in_flight is None:
+            return 0
+        tokens, step, firsts = self._in_flight
+        self._in_flight = None
+        for req in firsts:
+            self._see_first(req)
+        if tokens is None:
+            return 0
+        nxt = np.asarray(tokens)
+        t = time.perf_counter()
+        seen = 0
+        for slot, req in step:
+            if req.ended:
+                continue
+            dt = t - req._last_token_t
+            req.tokens.append(int(nxt[slot]))
+            req.tpot.append(dt)
+            req._last_token_t = t
+            self._m["tpot"].observe(dt)
+            self._m["tokens"].labels(kind="decode").inc()
+            seen += 1
+        return seen
+
     def _decode(self) -> None:
-        if not self.active:
+        if not self.active and self._in_flight is None:
             return
         attrs = {"active": len(self.active)} if trace.enabled() else None
         with trace.span("serve.decode", cat=trace.CAT_SERVE, attrs=attrs):
@@ -363,26 +436,31 @@ class ServeScheduler:
                 self._decode_plain(attrs)
 
     def _decode_plain(self, attrs: Optional[Dict[str, Any]]) -> None:
-        tokens = np.zeros((self.engine.slots,), np.int32)
-        active = np.zeros((self.engine.slots,), bool)
-        for slot, req in self.active.items():
-            tokens[slot] = req.tokens[-1]
-            active[slot] = True
-        nxt = self.engine.decode_step(tokens, active=active)
-        t = time.perf_counter()
-        self._decode_steps += 1
-        occ = self.engine.occupancy()
-        self._occ_sum += occ
-        self._m["occupancy"].set(occ)
-        for slot, req in self.active.items():
-            dt = t - req._last_token_t
-            req.tokens.append(int(nxt[slot]))
-            req.tpot.append(dt)
-            req._last_token_t = t
-            self._m["tpot"].observe(dt)
-            self._m["tokens"].labels(kind="decode").inc()
+        """Enqueue this cycle's step, then take the one before it. What
+        is in a step is decided by counts alone: every active request
+        with tokens left to dispatch, unless the host has seen its EOS."""
+        eng = self.engine
+        step = [(slot, req) for slot, req in self.active.items()
+                if req._dispatched < req.max_new_tokens and not req.ended]
+        firsts, self._firsts = self._firsts, []
+        tokens = None
+        if step:
+            active = np.zeros((eng.slots,), bool)
+            active[[slot for slot, _ in step]] = True
+            tokens = eng.decode_step(None, active=active)
+            for _, req in step:
+                req._dispatched += 1
+            self._decode_steps += 1
+            occ = eng.occupancy()
+            self._occ_sum += occ
+            self._m["occupancy"].set(occ)
+        else:
+            eng.drain("idle")       # nothing to queue behind it
+        seen = self._see()
+        if step or firsts:
+            self._in_flight = (tokens, step, firsts)
         if attrs is not None:
-            attrs["tokens"] = len(self.active)
+            attrs["tokens"] = seen
 
     def _decode_spec(self, attrs: Optional[Dict[str, Any]]) -> None:
         """Draft-then-verify decode point. Accept-prefix per slot:
@@ -481,6 +559,10 @@ class ServeScheduler:
                     time.sleep(wait)
                 continue
             self.step(now)
+        # every request has ended; a step dispatched before the host saw
+        # the last EOS may still be queued, its tokens for nobody
+        self.engine.drain("idle")
+        self._see()
         self._m["occupancy"].set(self.engine.occupancy())
         return self.completed
 

@@ -318,6 +318,52 @@ def test_routing_counters_add_up_to_rows_times_k():
     assert out[:, 1].tolist() == [[1, 9], [1, 1]] and not out[:, 0].any()
 
 
+def test_a_step_ahead_routes_no_row_that_no_request_keeps():
+    """The scheduler enqueues a step before it has read the one before;
+    a request still ends by count, so the device's routing counters (kept
+    in the state the queued programs hand from one to the next) count
+    exactly the rows of the direct loop that reads every step: prompt
+    rows, and one row for each token after a request's first."""
+    cfg = _cfg(expert_first=2, expert_count=4)
+    params = _params(cfg)
+    rng = np.random.default_rng(8)
+    sizes = [(6, 1), (37, 4), (11, 7), (20, 3)]     # prompt tokens, cap
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n, _ in sizes]
+    direct = _engine(cfg, params, slots=3)
+    want = []
+    for prompt, (_, cap) in zip(prompts, sizes):
+        slot = direct.reserve(len(prompt) + cap)
+        tokens = np.zeros((direct.slots,), np.int32)
+        out = [direct.prefill(slot, prompt)]
+        for _ in range(cap - 1):
+            tokens[slot] = out[-1]
+            out.append(int(direct.decode_step(tokens)[slot]))
+        direct.release(slot)
+        want.append(out)
+    eng = _engine(cfg, params, slots=3)
+    done = ServeScheduler(eng, queue_deadline=0.0).run(
+        [Request(rid=i, prompt=p, max_new_tokens=cap)
+         for i, (p, (_, cap)) in enumerate(zip(prompts, sizes))])
+    assert [r.tokens for r in sorted(done, key=lambda r: r.rid)] == want
+    got, ref_ = eng.stats(), direct.stats()
+    for part in ("decode", "prefill"):
+        for key in ("assignments_held", "assignments_zero",
+                    "assignments_absent"):
+            assert got["moe"][part][key] == ref_["moe"][part][key], (part, key)
+    rows = sum(cap - 1 for _, cap in sizes)
+    assert sum(got["moe"]["decode"][k] for k in got["moe"]["decode"]
+               if k.startswith("assign")) == rows * cfg.top_k * cfg.n_layers
+    # three slots for four requests: fewer steps than rows, all but the
+    # first of a stretch enqueued ahead, and nothing left in flight
+    assert got["decode"]["steps"] < rows
+    assert got["decode"]["dispatched_ahead"] >= got["decode"]["steps"] - 2
+    assert set(got["decode"]["drained"]) == {"idle"}
+    assert ref_["decode"] == {"steps": rows, "dispatched_ahead": 0,
+                              "drained": {"direct": rows}}
+    assert eng._unread is None
+
+
 @pytest.mark.parametrize("draft", ["ngram:2", "truncate:1"])
 def test_draft_modes_are_refused_for_this_model(draft):
     cfg = _cfg()

@@ -268,7 +268,8 @@ def test_continuous_batching_outputs_bitwise_equal_solo():
 def test_prefill_interleaves_one_chunk_per_cycle():
     """A long prompt prefills ONE chunk per scheduling cycle — decode
     steps run between its chunks, so co-tenants' TPOT never stalls for
-    the whole prompt."""
+    the whole prompt. A cycle dispatches the co-tenant's next step and
+    shows the host the step before."""
     eng, _ = _engine(prefill_chunk=32)
     sched = ServeScheduler(eng, queue_deadline=0.0)
     rng = np.random.default_rng(7)
@@ -277,25 +278,29 @@ def test_prefill_interleaves_one_chunk_per_cycle():
     sched.submit(short)
     sched.step()                                 # short admitted+decoding
     assert sched.active and not sched.prefilling
+    assert short._dispatched == 2                # first token + one step
     long = Request(rid=1,
                    prompt=rng.integers(0, 256, 90).astype(np.int32),
                    max_new_tokens=4)
     sched.submit(long)
-    tokens_before = len(short.tokens)
     sched.step()                                 # chunk 1 of 3 (32 toks)
     assert long.slot in sched.prefilling
     assert long._prefill_pos == 32 and long.tokens == []
-    assert len(short.tokens) == tokens_before + 1   # decode ran anyway
+    assert short._dispatched == 3                # decode ran anyway
+    tokens_before = len(short.tokens)
     sched.step()                                 # chunk 2 of 3
     assert long.slot in sched.prefilling
     assert long._prefill_pos == 64
-    assert len(short.tokens) == tokens_before + 2
+    assert short._dispatched == 4
+    assert len(short.tokens) == tokens_before + 1
     sched.step()                                 # chunk 3 -> first token
     assert long.slot not in sched.prefilling
-    assert len(long.tokens) >= 1
-    assert len(short.tokens) == tokens_before + 3
+    assert long._dispatched == 2                 # it joined this very step
+    assert len(short.tokens) == tokens_before + 2
     sched.run()                                  # drain
     assert {r.rid for r in sched.completed} == {0, 1}
+    assert len(long.tokens) == 4 and len(short.tokens) == 10
+    assert long.tokens == _greedy_solo(eng, long.prompt, 4)
 
 
 def test_max_new_tokens_cap_is_exact_and_eos_stops_at_prefill():
@@ -312,6 +317,138 @@ def test_max_new_tokens_cap_is_exact_and_eos_stops_at_prefill():
     done2 = sched2.run([Request(rid=0, prompt=prompt, max_new_tokens=50,
                                 eos_token=first)])
     assert done2[0].tokens == [first]
+
+
+# ---------------------------------------------------------------------------
+# one step late: step c is enqueued before step c-1 is read
+# ---------------------------------------------------------------------------
+
+def _late_engine(model, **kw):
+    """A small engine of either served model, 3 slots of 64 tokens."""
+    kw = {"slots": 3, "page": 8, "max_seq": 64, "prefill_chunk": 32, **kw}
+    if model == "dense":
+        return _engine(**kw)[0]
+    from test_longcat_flash import _cfg as lc_cfg, _params as lc_params
+    cfg = lc_cfg()
+    return ServeEngine(cfg, lc_params(cfg), None, prefix_cache=False,
+                       draft="off", **kw)
+
+
+# (prompt tokens, max_new_tokens, index of the token taken as EOS or None)
+LATE_CASES = {
+    # met by the prefill token: never decodes
+    "cap_of_one": [(9, 1, None), (20, 1, None), (13, 5, None)],
+    # caps met mid-flight, at different steps, a fourth request queued
+    "cap_mid_flight": [(9, 2, None), (12, 3, None), (17, 7, None),
+                       (11, 4, None)],
+    # an EOS mid-stream, one that the prefill itself emits, none
+    "eos_mid_stream": [(9, 12, 3), (14, 12, 0), (11, 6, None)],
+    # 8 requests over 3 slots: a request is admitted into a slot released
+    # a cycle earlier, while a step dispatched before the release is queued
+    "slot_reuse": [(5 + 3 * i, 2 + i % 5, None) for i in range(8)],
+    # prompts of two chunks (32 + 13, 32 + 18) beside a running batch
+    "chunks_interleaved": [(9, 12, None), (45, 5, None), (50, 6, None)],
+}
+
+
+@pytest.mark.parametrize("mode", ["continuous", "static"])
+@pytest.mark.parametrize("case", sorted(LATE_CASES))
+@pytest.mark.parametrize("model", ["dense", "longcat"])
+def test_one_step_late_equals_the_synchronous_loop(model, case, mode):
+    """Through the scheduler (step c enqueued before step c-1 is read,
+    tokens fed from step to step on the device) every request's tokens
+    are the direct loop's (``prefill`` + ``decode_step(<NumPy>)``, each
+    step read before the next). A request ends by what was dispatched
+    for it: exactly ``max_new_tokens`` tokens and no decode step past
+    them; an EOS is seen one step late and what was dispatched
+    meanwhile is discarded."""
+    eng = _late_engine(model)
+    rng = np.random.default_rng(32)
+    reqs, want = [], {}
+    for rid, (n_prompt, cap, eos_at) in enumerate(LATE_CASES[case]):
+        prompt = rng.integers(0, eng.cfg.vocab_size, n_prompt).astype(
+            np.int32)
+        solo = _greedy_solo(eng, prompt, cap)
+        eos = None
+        if eos_at is not None:
+            # the first token from there on that did not occur before
+            eos_at = next(k for k in range(eos_at, cap)
+                          if solo[k] not in solo[:k])
+            eos, solo = solo[eos_at], solo[:eos_at + 1]
+        reqs.append(Request(rid=rid, prompt=prompt, max_new_tokens=cap,
+                            eos_token=eos))
+        want[rid] = solo
+    sched = ServeScheduler(eng, mode=mode, queue_deadline=0.0)
+    steps_in = {r.rid: 0 for r in reqs}      # decode steps it was part of
+    real = eng.decode_step
+
+    def counting(tokens, active=None):
+        assert tokens is None                # fed on the device
+        for slot in np.flatnonzero(active):
+            steps_in[sched.active[int(slot)].rid] += 1
+        return real(tokens, active=active)
+
+    eng.decode_step = counting
+    done = sched.run(reqs)
+    assert {r.rid: r.tokens for r in done} == want
+    for r in done:
+        assert r.error is None and len(r.tpot) == len(r.tokens) - 1
+        if r.eos_token is None:
+            assert steps_in[r.rid] == r.max_new_tokens - 1
+        else:       # the steps dispatched before the host saw the EOS
+            assert len(r.tokens) - 1 <= steps_in[r.rid] <= len(r.tokens) + 1
+    # nothing in flight, every slot and page back
+    assert eng._unread is None and sched._in_flight is None
+    assert eng.occupancy() == 0.0
+    assert eng.allocator.free_pages == eng.pool.n_pages
+    counts = eng.stats()["decode"]
+    assert counts["steps"] >= max(steps_in.values())
+    assert set(counts["drained"]) <= {"idle", "direct"}
+
+
+@pytest.mark.parametrize("model", ["dense", "longcat"])
+def test_a_steady_run_never_drains(model):
+    """While every slot decodes, each step but the first is enqueued with
+    the step before it unread, and the host never reads a step that has
+    nothing queued behind it."""
+    eng = _late_engine(model)
+    rng = np.random.default_rng(3)
+    sched = ServeScheduler(eng, queue_deadline=0.0)
+    for rid in range(eng.slots):
+        sched.submit(Request(
+            rid=rid, prompt=rng.integers(0, eng.cfg.vocab_size, 9 + rid)
+            .astype(np.int32), max_new_tokens=20))
+    for _ in range(12):
+        sched.step()
+    counts = eng.stats()["decode"]
+    assert counts == {"steps": 12, "dispatched_ahead": 11, "drained": {}}
+    # the host has seen every step but the one in flight
+    assert [len(r.tokens) for r in sched.active.values()] == [12] * eng.slots
+    assert [r._dispatched for r in sched.active.values()] == [13] * eng.slots
+    assert eng._unread is not None
+    sched.run()
+    counts = eng.stats()["decode"]
+    assert counts["steps"] == 19 and counts["dispatched_ahead"] == 18
+    assert counts["drained"] == {"idle": 1}     # the last step of the run
+    assert eng._unread is None and sched._in_flight is None
+
+
+def test_speculation_reads_every_step_before_it_goes_on():
+    """Acceptance needs the values: a speculating scheduler never runs
+    ahead, and its tokens are the sequential sequence all the same."""
+    solo_eng, params = _engine(slots=2)
+    rng = np.random.default_rng(18)
+    prompts = [rng.integers(0, 256, n).astype(np.int32) for n in (9, 40)]
+    solo = [_greedy_solo(solo_eng, p, 9) for p in prompts]
+    eng, _ = _engine(slots=2, params=params, draft="ngram", spec_k=3)
+    sched = ServeScheduler(eng, queue_deadline=0.0)
+    done = sched.run([Request(rid=i, prompt=p, max_new_tokens=9)
+                      for i, p in enumerate(prompts)])
+    assert [r.tokens for r in sorted(done, key=lambda r: r.rid)] == solo
+    counts = eng.stats()["decode"]
+    assert counts["steps"] == counts["dispatched_ahead"] == 0
+    assert counts["drained"] == {"speculation": sched.stats()["decode_steps"]}
+    assert eng._unread is None and sched._in_flight is None
 
 
 def test_requests_clamped_or_rejected_at_context_ceiling():
@@ -357,11 +494,12 @@ def test_request_larger_than_pool_rejected_not_livelocked():
     assert by_rid[1].error is None and len(by_rid[1].tokens) == 4
 
 
-def test_decode_step_advances_lengths_only_after_the_result_is_read():
-    """The dispatch is asynchronous and may alias the host tables it was
-    handed (zero-copy jnp.asarray on the CPU backend, every slot active):
-    the lengths must not move until the step's result has been read
-    back, or the step races its own inputs."""
+def test_decode_step_advances_lengths_at_dispatch_over_a_snapshot():
+    """The dispatch is asynchronous and an upload may alias the NumPy
+    buffer it is handed (zero-copy jnp.asarray on the CPU backend): the
+    step gets COPIES of the tables and lengths, so the host may advance
+    its lengths as soon as the step is enqueued, before anything is read
+    back, and the step still reads the lengths it was dispatched with."""
     eng, _ = _engine(slots=2)
     rng = np.random.default_rng(21)
     tokens = np.zeros((eng.slots,), np.int32)
@@ -369,6 +507,7 @@ def test_decode_step_advances_lengths_only_after_the_result_is_read():
         prompt = rng.integers(0, 256, 9).astype(np.int32)
         slot = eng.reserve(len(prompt) + 4)
         tokens[slot] = eng.prefill(slot, prompt)
+    before = eng.tables.lengths.copy()
     seen = {}
 
     class Readback:
@@ -382,15 +521,22 @@ def test_decode_step_advances_lengths_only_after_the_result_is_read():
     real = eng._decode
 
     def spy(*args):
-        k, v, nxt, logits = real(*args)
+        seen["tables"], seen["lengths"] = args[-3], args[-2]
         seen["at_dispatch"] = eng.tables.lengths.copy()
+        k, v, nxt, logits = real(*args)
         return k, v, Readback(nxt), logits
 
     eng._decode = spy
     eng.decode_step(tokens)
-    np.testing.assert_array_equal(seen["at_readback"], seen["at_dispatch"])
-    np.testing.assert_array_equal(eng.tables.lengths,
-                                  seen["at_dispatch"] + 1)
+    np.testing.assert_array_equal(seen["at_dispatch"], before)
+    np.testing.assert_array_equal(seen["at_readback"], before + 1)
+    np.testing.assert_array_equal(eng.tables.lengths, before + 1)
+    # what the step was handed is still what it was dispatched with
+    np.testing.assert_array_equal(np.asarray(seen["lengths"]), before)
+    assert not np.shares_memory(np.asarray(seen["lengths"]),
+                                eng.tables.lengths)
+    assert not np.shares_memory(np.asarray(seen["tables"]),
+                                eng.tables.tables)
 
 
 def test_decode_step_default_mask_protects_mid_prefill_slots():
@@ -459,7 +605,8 @@ def test_warm_boot_is_compile_free(tmp_path, monkeypatch):
     artifact_store.reset_for_tests()
     try:
         cold, params = _engine()
-        assert cold.builds == len(cold.buckets) + 1
+        # decode + the first-token program + one prefill a bucket
+        assert cold.builds == len(cold.buckets) + 2
         assert set(cold.store_outcomes.values()) == {"miss"}
         warm, _ = _engine(cfg=cold.cfg, params=params)
         assert warm.builds == 0
@@ -842,8 +989,8 @@ def test_warm_boot_compile_free_with_spec_and_prefix(tmp_path, monkeypatch):
     try:
         cold, params = _engine(prefix_cache=True, draft="truncate:1",
                                spec_k=3)
-        # decode + prefill buckets + verify + draft + cow
-        assert cold.builds == len(cold.buckets) + 4
+        # decode + first token + prefill buckets + verify + draft + cow
+        assert cold.builds == len(cold.buckets) + 5
         assert {"serve_verify_k3", "serve_draft_l1",
                 "serve_cow_copy"} <= set(cold.store_outcomes)
         assert set(cold.store_outcomes.values()) == {"miss"}

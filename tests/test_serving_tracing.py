@@ -1,6 +1,7 @@
 """Spans inside the serve loop (docs/tracing.md, docs/serving.md): one
 ``serve.cycle`` per scheduling cycle with its phases as children, the
-engine's dispatch / wait boundary inside ``serve.decode``, a retired
+engine's dispatch / wait boundary inside ``serve.decode`` (a step is
+enqueued, then the step BEFORE it is waited for), a retired
 request's life as four spans, identical tokens with the recorder on and
 off, the spans on a profiler trace's host plane with the recorder off,
 and the names of the engine's device programs and of the train step's
@@ -124,12 +125,20 @@ def test_one_cycle_is_one_span_with_its_phases_as_children():
         assert inner["parent_id"] == decode["span_id"]
         assert _inside(inner, decode)
     assert dispatch["attrs"] == {"active": 2}
+    # this cycle's step is enqueued before anything is waited for, and it
+    # was enqueued with the step before it unread: the wait is for THAT
+    # step, whose two tokens this cycle shows the requests
     assert dispatch["ts_us"] + dispatch["dur_us"] <= wait["ts_us"] + 0.5
+    (ahead,) = rows["engine.decode.ahead"]
+    assert ahead["parent_id"] == dispatch["span_id"]
+    assert _inside(ahead, dispatch)
+    counts = sched.engine.stats()["decode"]
+    assert counts == {"steps": 2, "dispatched_ahead": 1, "drained": {}}
     assert rows["serve.admit"][0]["attrs"] == {
         "admitted": 0, "rejected": 0, "queued": 0}
 
 
-def test_prefill_spans_name_the_chunk_and_wait_only_on_the_last():
+def test_prefill_spans_name_the_chunk_and_nothing_waits_on_the_last():
     sched = _scheduler()
     trace.enable(buffer_spans=256)
     (req,) = _requests(1)
@@ -148,12 +157,48 @@ def test_prefill_spans_name_the_chunk_and_wait_only_on_the_last():
     assert d0["parent_id"] == first["span_id"]
     assert d0["attrs"] == {"slot": 0, "start": 0, "tokens": 32, "bucket": 32}
     assert d1["attrs"] == {"slot": 0, "start": 32, "tokens": 8, "bucket": 32}
-    (wait,) = rows["engine.prefill.wait"]       # the last chunk's readback
-    assert wait["parent_id"] == last["span_id"] and _inside(wait, last)
+    # the last chunk's token goes to the decode step on the device: a
+    # decode step follows the chunk in the same cycle and nothing waits
+    # for the chunk
+    assert "engine.prefill.wait" not in rows
+    (decode,) = rows["serve.decode"]
+    assert decode["parent_id"] == cycles[1]["span_id"]
+    (dispatch,) = rows["engine.decode.dispatch"]
+    assert dispatch["parent_id"] == decode["span_id"]
+    assert dispatch["ts_us"] >= d1["ts_us"] + d1["dur_us"] - 0.5
+    # the first step of a run has no step before it: nothing to wait for
+    assert "engine.decode.wait" not in rows
+    assert "engine.decode.ahead" not in rows
     assert rows["serve.admit"][0]["attrs"] == {
         "admitted": 1, "rejected": 0, "queued": 0}
-    # the first token is out, so the second cycle decoded
-    assert len(rows["serve.decode"]) == 1
+
+
+def test_the_direct_api_waits_where_it_did():
+    """``engine.prefill`` and ``decode_step(<NumPy tokens>)`` hand back
+    values, so each waits for its own program: the prefill's wait is the
+    last chunk's readback, the decode's follows its own dispatch, and
+    every such step counts as drained."""
+    engine = _scheduler().engine
+    trace.enable(buffer_spans=256)
+    (req,) = _requests(1)
+    slot = engine.reserve(int(req.prompt.size) + 4)
+    tokens = np.zeros((engine.slots,), np.int32)
+    tokens[slot] = engine.prefill(slot, req.prompt)
+    tokens[slot] = engine.decode_step(tokens)[slot]
+    engine.decode_step(tokens)
+    trace.disable()
+    rows = _by_name(trace.snapshot())
+    (wait,) = rows["engine.prefill.wait"]
+    assert wait["attrs"] == {"slot": slot}
+    assert wait["ts_us"] >= max(
+        d["ts_us"] + d["dur_us"] for d in rows["engine.prefill.dispatch"]
+    ) - 0.5
+    for dispatch, wait in zip(rows["engine.decode.dispatch"],
+                              rows["engine.decode.wait"], strict=True):
+        assert dispatch["ts_us"] + dispatch["dur_us"] <= wait["ts_us"] + 0.5
+    assert "engine.decode.ahead" not in rows
+    assert engine.stats()["decode"] == {
+        "steps": 2, "dispatched_ahead": 0, "drained": {"direct": 2}}
 
 
 def test_a_finished_request_is_four_spans_that_add_up():
@@ -277,16 +322,22 @@ def test_a_profiler_session_gets_the_spans_with_the_recorder_off(
     names = {n for n, _, _ in events}
     assert {"hvd.serve.cycle", "hvd.serve.retire", "hvd.serve.admit",
             "hvd.serve.prefill", "hvd.serve.decode",
-            "hvd.engine.prefill.dispatch", "hvd.engine.prefill.wait",
-            "hvd.engine.decode.dispatch", "hvd.engine.decode.wait"} <= names
+            "hvd.engine.prefill.dispatch", "hvd.engine.decode.dispatch",
+            "hvd.engine.decode.ahead", "hvd.engine.decode.wait"} <= names
+    # nothing waits for a prompt's last chunk while a decode step follows
+    assert "hvd.engine.prefill.wait" not in names
     # a request's spans are written after the fact: ring only
     assert not [n for n in names if n.startswith("hvd.serve.request")]
     cycles = sorted((s, s + d) for n, s, d in events
                     if n == "hvd.serve.cycle")
     assert len(cycles) == sched._cycles
+    dispatches = sorted((s, s + d) for n, s, d in events
+                        if n == "hvd.engine.decode.dispatch")
     for n, s, d in events:
         if n == "hvd.engine.decode.dispatch":
             assert any(lo <= s and s + d <= hi for lo, hi in cycles)
+        if n == "hvd.engine.decode.ahead":
+            assert any(lo <= s and s + d <= hi for lo, hi in dispatches)
     # after the session the off path is the shared no-op again
     assert trace.span("a") is trace.span("b")
 
@@ -313,6 +364,7 @@ def test_the_engines_device_programs_carry_names():
               for label in engine.store_outcomes}
     assert module == {
         "serve_decode": "jit_hvd_serve_decode",
+        "serve_first_token": "jit_hvd_serve_token",
         "serve_prefill_32": "jit_hvd_serve_prefill",
         "serve_verify_k2": "jit_hvd_serve_decode",
         "serve_draft_l1": "jit_hvd_serve_draft",

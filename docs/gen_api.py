@@ -42,6 +42,12 @@ SECTIONS = [
      "allocator and block tables, prefill/decode engine served "
      "compile-free from the artifact store, iteration-level scheduler, "
      "train->serve checkpoint handoff; see docs/serving.md."),
+    ("horovod_tpu.models.granite_hybrid",
+     "Hybrid state-space / attention model",
+     "Mamba-2 layers with a grouped-query attention layer among every few, "
+     "routed experts plus a shared expert in each; served through "
+     "`ServeEngine` with a per-slot recurrent state beside the paged KV "
+     "pool (`GraniteHybridConfig.serve_model()`); see docs/serving.md."),
     ("horovod_tpu.callbacks", "Callbacks",
      "Keras-style training callbacks (broadcast, metric averaging, LR "
      "schedules, best-model checkpoint)."),

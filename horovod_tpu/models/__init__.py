@@ -15,6 +15,11 @@ first-class package because the driver benchmarks the framework through them:
                     blocks, two dense SwiGLU FFNs and a top-k expert block
                     with zero-compute experts a layer), served through
                     ``ServeEngine`` as one chip's share of its experts.
+- ``granite_hybrid`` — layers of two kinds in one stack (Mamba-2 state-space
+                    layers, one grouped-query attention layer without
+                    positional embedding among every few), each with routed
+                    experts and a shared expert; served with a per-slot
+                    recurrent state beside the paged KV pool.
 """
 
 from horovod_tpu.models.mlp import MLP, MnistCNN  # noqa: F401
@@ -29,3 +34,4 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     TransformerLM,
 )
 from horovod_tpu.models.longcat_flash import LongCatFlashConfig  # noqa: F401
+from horovod_tpu.models.granite_hybrid import GraniteHybridConfig  # noqa: F401

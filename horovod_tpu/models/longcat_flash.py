@@ -52,7 +52,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.models.transformer import _rmsnorm, rope, visible_softmax
+from horovod_tpu.models.transformer import (
+    _rmsnorm, rope, swiglu, visible_softmax)
 from horovod_tpu.parallel import moe as moe_lib
 
 Params = Dict[str, Any]
@@ -264,14 +265,6 @@ def mla_attend_expanded(cfg: LongCatFlashConfig, bp: Params,
     return o.reshape(o.shape[0], -1).astype(dt)
 
 
-def swiglu(cfg: LongCatFlashConfig, fp: Params, u: jax.Array) -> jax.Array:
-    dt = cfg.dtype
-    g = jax.nn.silu((u @ fp["w_gate"].astype(dt)).astype(jnp.float32))
-    a = (g * (u @ fp["w_up"].astype(dt)).astype(jnp.float32)).astype(dt)
-    return jnp.dot(a, fp["w_down"].astype(dt),
-                   preferred_element_type=jnp.float32)
-
-
 def moe_share(cfg: LongCatFlashConfig, mp: Params, u: jax.Array,
               valid: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, jax.Array]:
@@ -351,65 +344,17 @@ def _cache_rows(cfg: LongCatFlashConfig):
     return (CacheRows("latent", 2 * cfg.n_layers, (cfg.cache_row,)),)
 
 
-DECODE, PREFILL = 0, 1      # which program a routing counter counted in
+DECODE, PREFILL = moe_lib.DECODE, moe_lib.PREFILL
 
 
 def _counter_state(cfg: LongCatFlashConfig):
-    """The routing counters on the device: ``[2, 2, 4 + held]`` uint32 —
-    the low words and the high, so a total only grows past 2**32; the decode
-    program's and the prefill program's; ``moe.share_counts``' layout."""
-    return (jax.ShapeDtypeStruct(
-        (2, 2, moe_lib.N_SHARE_TOTALS + cfg.held_experts), jnp.uint32),)
-
-
-def _add_counts(counters: jax.Array, added: jax.Array, program: int
-                ) -> jax.Array:
-    """``counters`` with ``added`` (one call's counts, int32) on the totals
-    of ``program``, the carry out of the low word taken into the high."""
-    was = counters[0, program]
-    low = was + added.astype(jnp.uint32)
-    high = counters[1, program] + (low < was).astype(jnp.uint32)
-    return counters.at[:, program].set(jnp.stack([low, high]))
+    return (moe_lib.share_counter_state(cfg.held_experts),)
 
 
 def routing_stats(cfg: LongCatFlashConfig, state: Tuple[jax.Array, ...]
                   ) -> Dict[str, Any]:
-    """``engine.stats()["moe"]``: the counters read back (the one place),
-    and published as ``hvd_serve_moe_*`` gauges."""
-    import numpy as np
-
-    from horovod_tpu import metrics as M
-    words = np.asarray(state[0]).astype(np.uint64)
-    by_program = (words[1] << np.uint64(32)) + words[0]  # [2, 4 + held]
-    n = moe_lib.N_SHARE_TOTALS
-
-    def named(totals):
-        totals = [int(v) for v in totals]
-        return {"assignments_held": totals[0], "assignments_zero": totals[1],
-                "assignments_absent": totals[2], "experts_active": totals[3],
-                "rows_per_expert": totals[n:]}
-
-    out = {**named(by_program.sum(axis=0)),
-           "decode": named(by_program[DECODE]),
-           "prefill": named(by_program[PREFILL]),
-           "expert_first": cfg.expert_first,
-           "experts_held": cfg.held_experts}
-    for key, what in (
-            ("assignments_held", "to routed experts held on this chip"),
-            ("assignments_zero", "to zero-compute (identity) experts"),
-            ("assignments_absent", "to routed experts held elsewhere")):
-        M.gauge(f"hvd_serve_moe_{key}",
-                f"Token-to-expert assignments {what}, all layers and "
-                f"steps").set(out[key])
-    M.gauge("hvd_serve_moe_experts_active",
-            "Held experts that got at least one row, summed over layers "
-            "and steps").set(out["experts_active"])
-    rows = M.gauge("hvd_serve_moe_expert_rows",
-                   "Rows routed to each held expert, all layers and steps",
-                   labelnames=("expert",))
-    for j, v in enumerate(out["rows_per_expert"]):
-        rows.labels(expert=str(cfg.expert_first + j)).set(v)
-    return {"moe": out}
+    return moe_lib.share_routing_stats(state[0], cfg.expert_first,
+                                       cfg.held_experts)
 
 
 def _serve_step(cfg: LongCatFlashConfig, params: Params, pool: jax.Array,
@@ -458,7 +403,8 @@ def _serve_step(cfg: LongCatFlashConfig, params: Params, pool: jax.Array,
         h = jnp.take(h, out_row, axis=0)                            # [D]
     logits = logits_of(cfg, params, h)
     next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return (flat.reshape(pool.shape), _add_counts(counters, total, program),
+    return (flat.reshape(pool.shape),
+            moe_lib.add_share_counts(counters, total, program),
             next_tokens, logits)
 
 

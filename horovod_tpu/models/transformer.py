@@ -244,6 +244,17 @@ def visible_softmax(s: jax.Array, visible: jax.Array) -> jax.Array:
     return p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
 
 
+def swiglu(cfg: Any, fp: Params, u: jax.Array) -> jax.Array:
+    """``(silu(u Wg) * (u Wu)) Wd`` on rows u in ``cfg.dtype``, the gate in
+    float32, the result float32: the one SwiGLU of the package (the dense
+    FFNs of the shortcut-MoE layer, the shared expert of the hybrid one)."""
+    dt = cfg.dtype
+    g = jax.nn.silu((u @ fp["w_gate"].astype(dt)).astype(jnp.float32))
+    a = (g * (u @ fp["w_up"].astype(dt)).astype(jnp.float32)).astype(dt)
+    return jnp.dot(a, fp["w_down"].astype(dt),
+                   preferred_element_type=jnp.float32)
+
+
 def _dense_mlp(cfg: TransformerConfig, h: jax.Array, w_in: jax.Array,
                w_out: jax.Array) -> jax.Array:
     """Dense FFN on local shards. The two d_ff-wide intermediates are

@@ -20,12 +20,18 @@ per token, the chip is told which routed experts it holds (``first``,
 ``count``), computes their terms and every zero-compute (identity) term of
 its own tokens, and leaves out the absent experts' terms. No token is
 dropped: an expert's rows are bounded by the tokens present, not by a
-capacity factor. On one chip there is no exchange.
+capacity factor. On one chip there is no exchange. Two gate rules give the
+same :class:`TopKRouting`: :func:`topk_route` (softmax over every router
+output, the chosen not renormalised) and :func:`topk_softmax_route` (top-k
+of the logits, softmax over the chosen). The counters of a served share
+(:func:`share_counter_state`, :func:`add_share_counts`,
+:func:`share_routing_stats`) are what a model's serve bodies keep on the
+device and its ``ServeModel.stats`` reads back.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -159,6 +165,19 @@ def topk_route(x: jax.Array, router_w: jax.Array, bias: jax.Array, k: int,
     return TopKRouting(experts.astype(jnp.int32), gates)
 
 
+def topk_softmax_route(x: jax.Array, router_w: jax.Array, k: int
+                       ) -> TopKRouting:
+    """``l = float32(x) Wr``; the ``k`` chosen are the top-k of the logits
+    ``l``; ``g = softmax(l_chosen)`` over the chosen alone, so a token's
+    gates sum to 1. float32 at ``highest``, for :func:`topk_route`'s
+    reason."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    chosen, experts = lax.top_k(logits, k)
+    return TopKRouting(experts.astype(jnp.int32),
+                       jax.nn.softmax(chosen, axis=-1))
+
+
 def share_gates(routing: TopKRouting, n_routed: int, first: int, count: int
                 ) -> Tuple[jax.Array, jax.Array]:
     """(``[T, count]`` gate of each held expert, 0 where the token did not
@@ -224,3 +243,71 @@ def expert_share_ffn(x: jax.Array, routing: TopKRouting, w_gate: jax.Array,
                        preferred_element_type=jnp.float32)
     with jax.named_scope("hvd_moe_combine"):
         return s + g_zero[:, None] * x.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# a served share's routing counters, kept on the device
+# ---------------------------------------------------------------------------
+
+DECODE, PREFILL = 0, 1      # which program a routing counter counted in
+
+
+def share_counter_state(held: int) -> jax.ShapeDtypeStruct:
+    """The routing counters on the device: ``[2, 2, 4 + held]`` uint32 —
+    the low words and the high, so a total only grows past 2**32; the decode
+    program's and the prefill program's; :func:`share_counts`' layout."""
+    return jax.ShapeDtypeStruct((2, 2, N_SHARE_TOTALS + held), jnp.uint32)
+
+
+def add_share_counts(counters: jax.Array, added: jax.Array, program: int
+                     ) -> jax.Array:
+    """``counters`` with ``added`` (one call's counts, int32) on the totals
+    of ``program``, the carry out of the low word taken into the high."""
+    was = counters[0, program]
+    low = was + added.astype(jnp.uint32)
+    high = counters[1, program] + (low < was).astype(jnp.uint32)
+    return counters.at[:, program].set(jnp.stack([low, high]))
+
+
+def counter_totals(counters: Any):
+    """The two words of a counter array ``[2, ...]`` read back as one
+    ``uint64`` array ``[...]``."""
+    import numpy as np
+    words = np.asarray(counters).astype(np.uint64)
+    return (words[1] << np.uint64(32)) + words[0]
+
+
+def share_routing_stats(counters: jax.Array, first: int, held: int
+                        ) -> Dict[str, Any]:
+    """``engine.stats()["moe"]``: the counters read back (the one place),
+    and published as ``hvd_serve_moe_*`` gauges."""
+    from horovod_tpu import metrics as M
+    by_program = counter_totals(counters)               # [2, 4 + held]
+    n = N_SHARE_TOTALS
+
+    def named(totals):
+        totals = [int(v) for v in totals]
+        return {"assignments_held": totals[0], "assignments_zero": totals[1],
+                "assignments_absent": totals[2], "experts_active": totals[3],
+                "rows_per_expert": totals[n:]}
+
+    out = {**named(by_program.sum(axis=0)),
+           "decode": named(by_program[DECODE]),
+           "prefill": named(by_program[PREFILL]),
+           "expert_first": first, "experts_held": held}
+    for key, what in (
+            ("assignments_held", "to routed experts held on this chip"),
+            ("assignments_zero", "to zero-compute (identity) experts"),
+            ("assignments_absent", "to routed experts held elsewhere")):
+        M.gauge(f"hvd_serve_moe_{key}",
+                f"Token-to-expert assignments {what}, all layers and "
+                f"steps").set(out[key])
+    M.gauge("hvd_serve_moe_experts_active",
+            "Held experts that got at least one row, summed over layers "
+            "and steps").set(out["experts_active"])
+    rows = M.gauge("hvd_serve_moe_expert_rows",
+                   "Rows routed to each held expert, all layers and steps",
+                   labelnames=("expert",))
+    for j, v in enumerate(out["rows_per_expert"]):
+        rows.labels(expert=str(first + j)).set(v)
+    return {"moe": out}

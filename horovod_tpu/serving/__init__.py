@@ -1,14 +1,16 @@
 """Serving subsystem (ROADMAP item 1, docs/serving.md): AOT
 continuous-batching inference for the models under ``horovod_tpu/models``
 that describe themselves to it (``cfg.serve_model()``: the flagship
-TransformerLM, the shortcut-MoE latent-attention model).
+TransformerLM, the shortcut-MoE latent-attention model, the hybrid
+state-space / attention model).
 
 - :mod:`~horovod_tpu.serving.kv_cache` — paged KV cache: fixed page
   pool, refcounted allocator, block tables, the shared-prefix
   hash-chain index (copy-on-write divergence), how a step body addresses
   a block of the flat pool, page writes, paged-attention reference.
 - :mod:`~horovod_tpu.serving.model` — ``ServeModel``, what the engine
-  asks of a model; the models import it, never the engine.
+  asks of a model (step bodies, cache rows, device state, per-slot
+  recurrent state); the models import it, never the engine.
 - :mod:`~horovod_tpu.serving.engine` — AOT prefill/decode engine over
   the page pool: slots, pages, programs; it holds no model.
   Artifact-store-served (``serve`` kind) so warm boots compile nothing;
@@ -41,6 +43,7 @@ from horovod_tpu.serving.kv_cache import (  # noqa: F401
     paged_attention_reference,
     paged_decode_attention,
 )
+from horovod_tpu.serving.model import ServeModel  # noqa: F401
 from horovod_tpu.serving.scheduler import (  # noqa: F401
     NGramDrafter,
     Request,

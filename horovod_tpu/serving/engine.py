@@ -1,9 +1,10 @@
 """The serving engine: slots, pages, block tables and AOT-compiled step
 programs for whatever model hands it step bodies and cache rows
 (``cfg.serve_model()`` -> :class:`~horovod_tpu.serving.model.ServeModel`;
-the dense ``TransformerConfig`` and ``LongCatFlashConfig`` both do, from
-``horovod_tpu/models/``). The engine holds no model: it names no parameter
-leaf and no device scope, and computes nothing of a layer.
+the dense ``TransformerConfig``, ``LongCatFlashConfig`` and
+``GraniteHybridConfig`` do, from ``horovod_tpu/models/``). The engine holds
+no model: it names no parameter leaf and no device scope, and computes
+nothing of a layer.
 
 Around a paged cache (:mod:`serving.kv_cache`) it builds exactly TWO
 compiled program families from the model's bodies —
@@ -121,7 +122,8 @@ def serve_model(cfg: Any) -> ServeModel:
     if own is None:
         raise TypeError(
             f"serving needs a config with a serve_model() of its own (as "
-            f"TransformerConfig and LongCatFlashConfig have), got "
+            f"TransformerConfig, LongCatFlashConfig and GraniteHybridConfig "
+            f"have), got "
             f"{type(cfg).__name__}")
     return own()
 
@@ -167,16 +169,17 @@ def serve_programs(cfg: Any, fmt: Union[Format, Sequence[Format]],
     over ``mesh`` when ``cfg.tp_axis`` is set, plain otherwise."""
     model = serve_model(cfg)
     n_pool = len(model.cache_rows(cfg))
-    n_state = len(model.state(cfg))
-    held = n_pool + n_state
+    n_slot = len(model.slot_state(cfg, 1))
+    held = n_pool + len(model.state(cfg)) + n_slot
     fmts = (fmt,) * n_pool if isinstance(fmt, Format) else tuple(fmt)
     # name -> (function, where the pool's first array stands among its
     # arguments (the others and the state follow; the parameters lead
     # when that is 1), arguments, results)
     table = {"decode": (functools.partial(model.decode, cfg), 1,
                         held + 4, held + 2),
+             # a model with per-slot state has its prefill told the slot
              "prefill": (functools.partial(model.prefill, cfg), 1,
-                         held + 5, held + 2),
+                         held + 5 + bool(n_slot), held + 2),
              "cow": (kvc.copy_page, 0, n_pool + 2, n_pool)}
     if draft_layers:
         table["draft"] = (functools.partial(model.draft, cfg, draft_layers),
@@ -243,6 +246,8 @@ class ServeEngine:
         self.draft_mode, self.draft_n = _parse_draft(
             self.draft_spec, cfg.n_layers)
         model.check(cfg, self.draft_mode)
+        # recurrent state a slot: neither shared by prefix nor rolled back
+        self.slot_stateful = bool(model.slot_state(cfg, 1))
         # a replica need not go through hvd.init(): HOROVOD_TRACE=1 turns
         # the recorder on here too (a no-op when it is on already)
         trace.init_from_env()
@@ -273,6 +278,17 @@ class ServeEngine:
                 f"HOROVOD_SERVE_DRAFT={self.draft_spec!r} needs "
                 f"HOROVOD_SERVE_SPEC_K >= 1 drafts per step, got "
                 f"{self.spec_k}")
+        if self.slot_stateful and (self.prefix_cache
+                                   or self.draft_mode != "off"):
+            raise ValueError(
+                f"{type(cfg).__name__} keeps recurrent state per slot, "
+                f"which serves with the prefix cache off and plain decode "
+                f"only; got prefix_cache={self.prefix_cache}, draft "
+                f"{self.draft_spec!r}. Adopted pages would skip prompt "
+                f"tokens the recurrent layers have to see, and a rejected "
+                f"draft would have advanced a state nothing can roll back. "
+                f"Set HOROVOD_SERVE_PREFIX_CACHE=0 and "
+                f"HOROVOD_SERVE_DRAFT=off.")
 
         tp = cfg.tp_axis
         self._tp_size = int(mesh.shape[tp]) if (tp and mesh) else 1
@@ -282,7 +298,7 @@ class ServeEngine:
                 f"{self._tp_size}")
 
         rows = model.cache_rows(cfg)
-        self.pool = kvc.PagePool(cfg.n_layers, pool_pages, self.page,
+        self.pool = kvc.PagePool(rows[0].blocks, pool_pages, self.page,
                                  dtype=cfg.dtype, rows=rows)
         self.allocator = kvc.PageAllocator(pool_pages)
         self.tables = kvc.BlockTables(self.slots, self.n_max_pages,
@@ -416,9 +432,12 @@ class ServeEngine:
         sharding = self.pool_format.sharding
         if isinstance(sharding, NamedSharding):
             sharding = NamedSharding(sharding.mesh, P())
+        # and its per-slot state, one buffer each for the engine's life:
+        # every step takes it donated and returns it updated in place
         self.state: Tuple[jax.Array, ...] = tuple(
             jax.device_put(jnp.zeros(s.shape, s.dtype), sharding)
-            for s in self.model.state(self.cfg))
+            for s in (*self.model.state(self.cfg),
+                      *self.model.slot_state(self.cfg, self.slots)))
         # every slot's newest token, where the next decode step reads it;
         # and the decode step whose tokens the host has not read yet
         self._newest: jax.Array = jax.device_put(
@@ -466,7 +485,15 @@ class ServeEngine:
 
     def _prefill_args(self, bucket: int) -> Tuple:
         return (jax.tree.map(_abstract, self.params), *self._held_args(),
-                _i32(self.n_max_pages), _i32(), _i32(), _i32(bucket))
+                _i32(self.n_max_pages), *self._slot_arg(),
+                _i32(), _i32(), _i32(bucket))
+
+    def _slot_arg(self, slot: Optional[int] = None) -> Tuple:
+        """The slot a prefill chunk fills (its shape, for lowering), for
+        the model that is told."""
+        if not self.slot_stateful:
+            return ()
+        return (_i32() if slot is None else jnp.asarray(slot, jnp.int32),)
 
     def _cow_args(self) -> Tuple:
         return (*self._pool_args(), _i32(), _i32())
@@ -635,7 +662,8 @@ class ServeEngine:
             chunk = np.zeros((bucket,), np.int32)
             chunk[:n_real] = prompt[start:start + n_real]
             tok, _ = self._step(
-                self._prefill[bucket], bt_row, jnp.asarray(start, jnp.int32),
+                self._prefill[bucket], bt_row, *self._slot_arg(slot),
+                jnp.asarray(start, jnp.int32),
                 jnp.asarray(n_real, jnp.int32), jnp.asarray(chunk))
         start += n_real
         if start < prompt.size:
@@ -818,6 +846,11 @@ class ServeEngine:
         covers the request's future growth, so its COW/tail pages
         return through the normal retire decref, never mid-flight."""
         n = int(n_rejected)
+        if self.slot_stateful:
+            raise ValueError(
+                f"rollback of slot {slot}: {type(self.cfg).__name__} keeps "
+                f"recurrent state per slot, and a state that has taken a "
+                f"token in cannot give it back")
         if not (0 <= n <= int(self.tables.lengths[slot])):
             raise ValueError(
                 f"rollback of {n} tokens on slot {slot} with length "

@@ -36,6 +36,20 @@ class ServeModel:
     it is read back). ``draft`` (the decode body over the first
     ``n_layers`` layers) only where the model offers ``truncate:N``.
 
+    ``slot_state(cfg, slots)``: what a model with recurrent layers caches
+    per SLOT instead of per token, as shapes over the number of slots. The
+    engine allocates it zeroed where the pool lives, donates it to every
+    step and hands it on after ``state`` (``stats`` is given both); the
+    bodies update it in place. A model that declares any has its prefill
+    told the slot: ``prefill(cfg, params, *pool, *state, *slot_state,
+    block_table, slot, start, n_real, tokens)``, which starts from zeros
+    at ``start == 0`` (nobody clears a released slot) and from what the
+    chunk before stored otherwise; its ``decode`` advances the slots with
+    ``lengths > 0`` and keeps every other slot's state (a slot mid-prefill
+    is shown with length 0). Such state cannot be shared by prefix or
+    rolled back: the engine refuses the prefix cache, the draft modes and
+    ``rollback`` for the model.
+
     ``served_params(cfg, placed)`` is the tree the programs read, made
     once at engine build from the tree as placed on the device: the
     same structure, each leaf either the placed array itself or a copy
@@ -48,6 +62,8 @@ class ServeModel:
     param_specs: Callable[[Any], Any]
     served_params: Optional[Callable[[Any, Any], Any]] = None
     state: Callable[[Any], Tuple[jax.ShapeDtypeStruct, ...]] = lambda cfg: ()
+    slot_state: Callable[[Any, int], Tuple[jax.ShapeDtypeStruct, ...]] = (
+        lambda cfg, slots: ())
     stats: Optional[Callable[[Any, Tuple], Dict[str, Any]]] = None
     draft: Optional[Callable[..., Tuple]] = None
 

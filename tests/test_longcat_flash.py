@@ -313,7 +313,7 @@ def test_routing_counters_add_up_to_rows_times_k():
     # the low word carries into the high one, each program's own totals
     full = jnp.zeros((2, 2, 2), jnp.uint32).at[0, 1].set(
         jnp.array([2 ** 32 - 2, 5], jnp.uint32)).at[1, 1, 1].set(1)
-    out = np.asarray(lc._add_counts(full, jnp.array([3, 4], jnp.int32),
+    out = np.asarray(moe.add_share_counts(full, jnp.array([3, 4], jnp.int32),
                                     lc.PREFILL))
     assert out[:, 1].tolist() == [[1, 9], [1, 1]] and not out[:, 0].any()
 

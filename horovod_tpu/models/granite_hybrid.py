@@ -420,12 +420,11 @@ def mamba_prefill(cfg: GraniteHybridConfig, mp: Params, u: jax.Array,
 # ---------------------------------------------------------------------------
 
 def attention_project(cfg: GraniteHybridConfig, ap: Params, u: jax.Array):
-    """q ``[N, H, d]``, k and v ``[N, KVH, d]`` of rows u ``[N, D]``; no
-    rotary."""
-    dt, n, d = cfg.dtype, u.shape[0], cfg.head_dim
-    return ((u @ ap["wq"].astype(dt)).reshape(n, cfg.n_heads, d),
-            (u @ ap["wk"].astype(dt)).reshape(n, cfg.n_kv_heads, d),
-            (u @ ap["wv"].astype(dt)).reshape(n, cfg.n_kv_heads, d))
+    """q ``[N, H, d]``, k and v ``[N, KVH*d]`` (the pool's row: a token's
+    KV heads side by side) of rows u ``[N, D]``; no rotary."""
+    dt, n = cfg.dtype, u.shape[0]
+    return ((u @ ap["wq"].astype(dt)).reshape(n, cfg.n_heads, cfg.head_dim),
+            u @ ap["wk"].astype(dt), u @ ap["wv"].astype(dt))
 
 
 def attend_gathered(cfg: GraniteHybridConfig, q: jax.Array,
@@ -435,9 +434,10 @@ def attend_gathered(cfg: GraniteHybridConfig, q: jax.Array,
     at positions ``pos`` over ONE sequence's pages in block-table order,
     each seeing the cached positions up to its own; ``[C, H, d]``."""
     from horovod_tpu.serving import kv_cache as kvc
-    kg = kvc.gather_pages(k_pages, block_table)              # [T, KVH, d]
-    vg = kvc.gather_pages(v_pages, block_table)
     n, kvh = q.shape[0], cfg.n_kv_heads
+    by_head = (-1, kvh, cfg.head_dim)                        # [T, KVH, d]
+    kg = kvc.gather_pages(k_pages, block_table).reshape(by_head)
+    vg = kvc.gather_pages(v_pages, block_table).reshape(by_head)
     qg = q.reshape(n, kvh, cfg.n_heads // kvh, -1)
     s = jnp.einsum("nkgd,tkd->nkgt", qg, kg,
                    preferred_element_type=jnp.float32) \
@@ -673,7 +673,7 @@ def prefill_body(cfg: GraniteHybridConfig, params: Params, *args):
     def attention(ap, u, flat, bt, scratch):
         q, k, v = attention_project(cfg, ap, u)
         with jax.named_scope("hvd_kv_write"):
-            flat = kvc.write_chunk_rows(flat, (k, v), bt, start, n_real,
+            flat = kvc.write_chunk_pages(flat, (k, v), bt, start, n_real,
                                         scratch=scratch)
         with jax.named_scope("hvd_attention"):
             o = attend_gathered(cfg, q, *flat, bt, pos)

@@ -469,7 +469,7 @@ def loss_fn(cfg: TransformerConfig, params: Params, tokens: jax.Array,
 # the serving engine's step bodies (serving.model.ServeModel): the block
 # over a paged KV cache. They run per shard, inside shard_map when tp_axis
 # is set: heads, FFN and vocabulary are split exactly as in training, and
-# the page pool over its KV-head axis.
+# the page pool by whole KV heads of its row.
 # ---------------------------------------------------------------------------
 
 def _check_serve(cfg: TransformerConfig, draft_mode: str) -> None:
@@ -512,11 +512,13 @@ def attend_gathered(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     ) -> jax.Array:
     """Prefill's attention, in float32: the chunk's queries q ``[C, H, D]``
     at positions ``pos`` over ONE sequence's pages in block-table order
-    (``k_pages`` / ``v_pages`` ``[n_phys, page, H, D]``), each query seeing
+    (``k_pages`` / ``v_pages`` ``[n_phys, page, H*D]``), each query seeing
     the cached positions up to its own: the prefix and the chunk."""
     from horovod_tpu.serving import kv_cache as kvc
+    by_head = (-1,) + q.shape[1:]
     kg = kvc.gather_pages(k_pages, block_table).astype(jnp.float32)
     vg = kvc.gather_pages(v_pages, block_table).astype(jnp.float32)
+    kg, vg = kg.reshape(by_head), vg.reshape(by_head)
     s = jnp.einsum("chd,shd->chs", q.astype(jnp.float32), kg) * scale
     ctx = jnp.arange(kg.shape[0], dtype=jnp.int32)
     visible = ctx[None, :] <= pos[:, None]           # causal + prefix
@@ -530,7 +532,8 @@ def _serve_step(cfg: TransformerConfig, params: Params, k_pages: jax.Array,
                 out_row: Optional[jax.Array] = None):
     """What a decode step and a prefill chunk share: embed ``tokens``
     ``[N]``, the block at positions ``pos`` ``[N]`` over the layers
-    (the first ``n_layers``), each writing its K/V through ``write(pages,
+    (the first ``n_layers``), each writing its K/V rows ``[N, H*D]`` (a
+    token's heads side by side: the pool's row) through ``write(pages,
     new, block_tables, scratch)`` and then attending through ``attend(q,
     k_pages, v_pages, block_tables)``, final norm, head (of row
     ``out_row`` only, if given), argmax. Returns ``(k_pages, v_pages,
@@ -555,7 +558,8 @@ def _serve_step(cfg: TransformerConfig, params: Params, k_pages: jax.Array,
         def attend_cached(q, k, v):
             nonlocal pool
             with jax.named_scope("hvd_kv_write"):
-                pool = write(pool, (k, v), bt, scratch)
+                rows = tuple(t.reshape(t.shape[0], -1) for t in (k, v))
+                pool = write(pool, rows, bt, scratch)
             with jax.named_scope("hvd_attention"):
                 return attend(q, *pool, bt)
 
@@ -639,7 +643,7 @@ def prefill_body(cfg: TransformerConfig, params: Params,
     pos = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
 
     def write(pages, new, bt, scratch):
-        return kvc.write_chunk_rows(pages, new, bt, start, n_real,
+        return kvc.write_chunk_pages(pages, new, bt, start, n_real,
                                     scratch=scratch)
 
     def attend(q, kp, vp, bt):

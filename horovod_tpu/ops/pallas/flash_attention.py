@@ -483,29 +483,60 @@ flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 # Paged single-position decode attention (serving hot path).
 #
 # The serving engine (horovod_tpu/serving) keeps each sequence's K/V in
-# fixed-size pages of a shared pool ``[n_pages, page, n_kv_heads, d]``
-# (PagedAttention, vLLM SOSP '23); at decode, every request contributes ONE
-# query position that must attend over its pages in block-table order. The
-# kernel below is the decode form of the flash kernel above: grid
-# ``(B, n_max_pages)`` with the page dimension arbitrary-order, the flash
-# (m, l, acc) recurrence in VMEM scratch, and the page -> physical-block
-# indirection done by the BlockSpec index_map reading the scalar-prefetched
-# block table (``pltpu.PrefetchScalarGridSpec``) — K/V pages stream straight
-# from their pool slots, no gather materializes a contiguous copy in HBM.
+# fixed-size pages of a shared pool (PagedAttention, vLLM SOSP '23); at
+# decode, every request contributes ONE query position that must attend over
+# its pages in block-table order. The kernel below is the decode form of the
+# flash kernel above: grid ``(B, n_max_pages)`` with the page dimension
+# arbitrary-order, the flash (m, l, acc) recurrence in VMEM scratch, and the
+# page -> physical-block indirection done by the BlockSpec index_map reading
+# the scalar-prefetched block table (``pltpu.PrefetchScalarGridSpec``) — K/V
+# pages stream straight from their pool slots, no gather materializes a
+# contiguous copy in HBM.
 #
-# One grid step holds a WHOLE page — every KV head — so each block's last
-# two dimensions are the array's own ``(KVH, D)``: Mosaic accepts a
-# trailing block only when it is the full dimension or (8, 128)-aligned,
-# and one head out of ``(KVH, D)`` is neither. With one query row per
-# head there is no matrix to feed the MXU; scores and the value sum are
-# VPU multiplies with a lane (D) and a leading-dimension (page) reduction.
-# Grouped queries (GQA) arrive regrouped ``[B, qpk, KVH, D]`` and share
-# the resident page.
+# A page is ``[page, KVH*D]``: one row a token, every KV head's ``D`` numbers
+# side by side on the lanes (``kv_cache.dense_rows``). A head of 64 fills its
+# half of a 128-lane tile, its neighbour the other half, so a page in HBM and
+# in VMEM is whole tiles and a grid step's DMA moves the keys' own bytes.
+# One grid step holds a WHOLE page — every KV head — and takes the per-head
+# sums over the heads' lane segments on the MXU: the queries arrive as ONE
+# block-diagonal matrix ``[H, KVH*D]`` (row ``g*KVH + kv`` holds query head
+# ``kv*qpk + g`` in KV head ``kv``'s lanes, zeros elsewhere), so
+# ``Qbd . K^T`` ``[H, page]`` is every head's scores against its own KV head
+# (products of the pool's dtype, accumulated in float32: for bfloat16 each
+# product is exact) and ``P . V`` ``[H, KVH*D]`` holds head ``h``'s value sum
+# in its KV head's lanes (the other lanes are other heads' values under this
+# head's probabilities: never read). The probabilities stay float32: against
+# a bfloat16 page they go through the MXU as three bfloat16 terms whose sum
+# is the float32 number. Grouped queries (GQA) share the resident page.
 # ---------------------------------------------------------------------------
 
+def _weighted_rows(p: jax.Array, v: jax.Array) -> jax.Array:
+    """``p @ v`` in float32 with ``p`` ``[H, page]`` float32 kept whole:
+    against float32 rows one product at the highest precision; against
+    bfloat16 rows ``p`` split into three bfloat16 terms (8 + 8 + 8 bits of
+    its 24) stacked into one product, each term's products exact."""
+    if v.dtype == jnp.float32:
+        return jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    h = p.shape[0]
+    terms, rest = [], p
+    for _ in range(3):
+        t = rest.astype(v.dtype)
+        terms.append(t)
+        rest = rest - t.astype(jnp.float32)
+    out = jax.lax.dot_general(
+        jnp.concatenate(terms, axis=0), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return out[:h] + out[h:2 * h] + out[2 * h:]
+
+
 def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, scale: float, page: int):
-    qpk, kvh = q_ref.shape[1], q_ref.shape[2]
+                         m_scr, l_scr, acc_scr, *, scale: float, page: int,
+                         kvh: int):
+    h, w = q_ref.shape[1], q_ref.shape[2]
+    qpk, d = h // kvh, w // kvh
     b = pl.program_id(0)
     j = pl.program_id(1)
     n_j = pl.num_programs(1)
@@ -523,22 +554,23 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # causal block skip in the training kernel.
     @pl.when(base < length)
     def _run():
-        k = k_ref[0].astype(jnp.float32)       # [page, KVH, D]
-        v = v_ref[0].astype(jnp.float32)
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, (page, kvh, 1), 0)
-        live = pos < length
-        for g in range(qpk):                   # static: query heads per KV
-            q = q_ref[0, g].astype(jnp.float32)            # [KVH, D]
-            s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
-            s = jnp.where(live, s, NEG_INF)                # [page, KVH, 1]
-            m_prev = m_scr[g]                              # [KVH, 1]
-            m_next = jnp.maximum(m_prev, jnp.max(s, axis=0))
-            # base < length: at least one live row, so m_next is finite.
-            p = jnp.where(live, jnp.exp(s - m_next[None]), 0.0)
-            alpha = jnp.exp(m_prev - m_next)   # first page: exp(-1e30) = 0
-            l_scr[g] = l_scr[g] * alpha + jnp.sum(p, axis=0)
-            m_scr[g] = m_next
-            acc_scr[g] = acc_scr[g] * alpha + jnp.sum(p * v, axis=0)
+        k = k_ref[0]                                       # [page, KVH*D]
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            precision=(jax.lax.Precision.HIGHEST
+                       if k.dtype == jnp.float32 else None),
+            preferred_element_type=jnp.float32) * scale    # [H, page]
+        live = base + jax.lax.broadcasted_iota(
+            jnp.int32, (h, page), 1) < length
+        s = jnp.where(live, s, NEG_INF)
+        m_prev = m_scr[...]                                # [H, 1]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # base < length: at least one live key, so m_next is finite.
+        p = jnp.where(live, jnp.exp(s - m_next), 0.0)
+        alpha = jnp.exp(m_prev - m_next)       # first page: exp(-1e30) = 0
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[...] = m_next
+        acc_scr[...] = acc_scr[...] * alpha + _weighted_rows(p, v_ref[0])
 
     @pl.when(j == n_j - 1)
     def _finalize():
@@ -546,13 +578,20 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         # stats merge at a single query position (unlike the training
         # kernel's ring-attention contract). A fully-masked row (an empty
         # slot, length 0) finalizes to exact zeros via the l floor.
-        o_ref[0] = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+        o = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)  # [H, KVH*D]
+        # row g*KVH + kv keeps KV head kv's lanes; the KVH rows of one g
+        # then add up to one lane-dense row of qpk's output
+        row = jax.lax.broadcasted_iota(jnp.int32, (h, w), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (h, w), 1)
+        o = jnp.where(lane // d == row % kvh, o, 0.0)
+        for g in range(qpk):                   # static: query heads per KV
+            o_ref[0, g] = jnp.sum(o[g * kvh:(g + 1) * kvh], axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def flash_paged_decode(
     q: jax.Array,                     # [B, H, D] one position per sequence
-    k_pages: jax.Array,               # [n_pages, page, KVH, D]
+    k_pages: jax.Array,               # [n_pages, page, KVH*D]
     v_pages: jax.Array,
     block_tables: jax.Array,          # [B, n_max] i32 physical page ids
     lengths: jax.Array,               # [B] i32 valid tokens per sequence
@@ -565,20 +604,23 @@ def flash_paged_decode(
     (``serving.kv_cache.paged_attention_reference``) covers the rest.
     """
     b, h, d = q.shape
-    n_pages, page, kvh, _ = k_pages.shape
+    n_pages, page, w = k_pages.shape
+    kvh = w // d
     n_max = block_tables.shape[1]
     qpk = h // kvh
-    # Head h reads KV head h // qpk: regroup so every query of one KV head
-    # sits on that head's sublane row of the resident page.
+    # Head h reads KV head h // qpk. The block-diagonal queries: row
+    # g*KVH + kv is head kv*qpk + g on KV head kv's lanes of the page's row.
     qg = q.reshape(b, kvh, qpk, d).transpose(0, 2, 1, 3)   # [B, qpk, KVH, D]
+    qbd = (qg[:, :, :, None, :]
+           * jnp.eye(kvh, dtype=q.dtype)[:, :, None]).reshape(b, h, w)
     kernel = functools.partial(_paged_decode_kernel, scale=float(scale),
-                               page=page)
+                               page=page, kvh=kvh)
     bt = block_tables.astype(jnp.int32)
     ln = lengths.astype(jnp.int32)
     page_spec = pl.BlockSpec(
-        (1, page, kvh, d), lambda b_, j, bt_, ln_: (bt_[b_, j], 0, 0, 0))
-    head_spec = pl.BlockSpec(
-        (1, qpk, kvh, d), lambda b_, j, bt_, ln_: (b_, 0, 0, 0))
+        (1, page, w), lambda b_, j, bt_, ln_: (bt_[b_, j], 0, 0))
+    slot_spec = lambda rows: pl.BlockSpec(
+        (1, rows, w), lambda b_, j, bt_, ln_: (b_, 0, 0))
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
@@ -589,38 +631,40 @@ def flash_paged_decode(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, n_max),
-            in_specs=[head_spec, page_spec, page_spec],
-            out_specs=head_spec,
+            in_specs=[slot_spec(h), page_spec, page_spec],
+            out_specs=slot_spec(qpk),
             scratch_shapes=[
-                pltpu.VMEM((qpk, kvh, 1), jnp.float32),   # m
-                pltpu.VMEM((qpk, kvh, 1), jnp.float32),   # l
-                pltpu.VMEM((qpk, kvh, d), jnp.float32),   # acc
+                pltpu.VMEM((h, 1), jnp.float32),          # m
+                pltpu.VMEM((h, 1), jnp.float32),          # l
+                pltpu.VMEM((h, w), jnp.float32),          # acc
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, qpk, kvh, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, qpk, w), jnp.float32),
         interpret=interpret,
         **kwargs,
-    )(bt, ln, qg, k_pages, v_pages)
-    return out.transpose(0, 2, 1, 3).reshape(b, h, d)
+    )(bt, ln, qbd, k_pages, v_pages)
+    return out.reshape(b, qpk, kvh, d).transpose(0, 2, 1, 3).reshape(b, h, d)
 
 
 def paged_decode_supports(q: jax.Array, k_pages: jax.Array,
                           v_pages: Optional[jax.Array] = None) -> bool:
     """Static shape gate for paged-decode kernel dispatch (the decode
-    analogue of :func:`supports`): one native dtype, K and V pools of one
-    shape, and Q heads grouping evenly over KV heads. Page size and head
-    count are free — a block is a whole page, its trailing dimensions
-    the pool's own."""
-    if q.ndim != 3 or k_pages.ndim != 4:
+    analogue of :func:`supports`): one native dtype (float32 or bfloat16),
+    K and V pools of one shape whose row is whole KV heads of the query's
+    ``D``, and Q heads grouping evenly over them. Page size and head count
+    are free — a block is a whole page, its trailing dimensions the
+    pool's own."""
+    if q.ndim != 3 or k_pages.ndim != 3:
         return False
     b, h, d = q.shape
-    kvh = k_pages.shape[2]
     if v_pages is not None and (v_pages.shape != k_pages.shape
                                 or v_pages.dtype != k_pages.dtype):
         return False
-    if q.dtype != k_pages.dtype:
+    if q.dtype != k_pages.dtype or q.dtype not in (jnp.float32,
+                                                   jnp.bfloat16):
         return False
-    return kvh > 0 and h % kvh == 0 and k_pages.shape[3] == d
+    kvh, rest = divmod(k_pages.shape[2], d)
+    return rest == 0 and kvh > 0 and h % kvh == 0
 
 
 def supports(q: jax.Array, k: jax.Array, v: Optional[jax.Array] = None,

@@ -375,6 +375,10 @@ class ServeEngine:
         # temporaries of each compiled program (memory_analysis): a
         # program that copied or re-laid the pool would read pool-sized
         self.program_temp_bytes: Dict[str, int] = {}
+        # and its arguments as they lie on one device: what the decode
+        # program's hold beyond weights and state is the pool, padding
+        # of its rows' lanes included (``stats()["pool"]``)
+        self.program_argument_bytes: Dict[str, int] = {}
         self._dispatch: Dict[str, Callable] = {}
         # decode steps enqueued; those enqueued while the step before was
         # still unread; and how often the host read a step with nothing
@@ -385,14 +389,19 @@ class ServeEngine:
               else compile_cache.uncached()):
             self._build()
         _register_engine(self)
+        # fixed once the decode program is built: read again by stats()
+        self.pool_held = held = self._pool_held()
         logger.info(
             "serve engine up: %d slots, %d+1 pages x %d tokens "
-            "(%.1f MiB KV pool), prefill buckets %s, tp=%d, builds=%d, "
+            "(%.1f MiB KV pool of rows %s, %.1f MiB a device as the "
+            "decode program holds it: x %s), prefill buckets %s, tp=%d, "
+            "builds=%d, "
             "decode temporaries %.1f MiB, weights %.1f MiB resident "
             "(%d leaves cast once from %.1f MiB)",
             self.slots, pool_pages, self.page,
-            self.pool.nbytes() / 2 ** 20, self.buckets, self._tp_size,
-            self.builds,
+            self.pool.nbytes() / 2 ** 20, held["rows"],
+            (held["resident_bytes"] or 0) / 2 ** 20, held["padding"],
+            self.buckets, self._tp_size, self.builds,
             self.program_temp_bytes.get("serve_decode", 0) / 2 ** 20,
             self.weights["resident_bytes"] / 2 ** 20,
             self.weights["cast_leaves"],
@@ -521,8 +530,10 @@ class ServeEngine:
         self.store_outcomes[label] = outcome
         compiled = getattr(wrapped, "hvd_store_compiled", None)
         if compiled is not None:
-            self.program_temp_bytes[label] = int(
-                compiled.memory_analysis().temp_size_in_bytes)
+            memory = compiled.memory_analysis()
+            self.program_temp_bytes[label] = int(memory.temp_size_in_bytes)
+            self.program_argument_bytes[label] = int(
+                memory.argument_size_in_bytes)
         self._dispatch[label] = wrapped
         return wrapped
 
@@ -857,6 +868,34 @@ class ServeEngine:
                 f"{int(self.tables.lengths[slot])}")
         self.tables.lengths[slot] -= n
 
+    def _pool_held(self) -> Dict[str, Any]:
+        """The pool as its rows describe it against the pool as it lies
+        in memory: each array's row, the arrays' bytes (``bytes``, every
+        device's), and what the decode program's arguments hold on one
+        device once the weights, the model's state and the step's
+        integers are taken off (``resident_bytes``, from the compiled
+        program's ``memory_analysis()``). ``padding`` is that over the
+        same device's share of ``bytes``: 1.0 where a row fills the
+        lanes, 2.0 where a head of 64 stood alone on 128 of them."""
+        def on_device(arrays) -> int:
+            return sum(
+                int(np.prod(a.sharding.shard_shape(a.shape)))
+                * a.dtype.itemsize for a in jax.tree.leaves(arrays))
+
+        out: Dict[str, Any] = {
+            "rows": {r.name: list(r.row) for r in self.pool.rows},
+            "bytes": self.pool.nbytes(),
+            "resident_bytes": None, "padding": None}
+        arguments = self.program_argument_bytes.get("serve_decode")
+        if arguments is not None:
+            integers = 4 * self.slots * (self.n_max_pages + 2)
+            out["resident_bytes"] = (
+                arguments - on_device(self.params) - on_device(self.state)
+                - integers)
+            out["padding"] = round(
+                out["resident_bytes"] / float(on_device(self.pools)), 4)
+        return out
+
     def occupancy(self) -> float:
         used = sum(1 for p in self.slot_pages if p is not None)
         return used / float(self.slots)
@@ -878,6 +917,7 @@ class ServeEngine:
                 "shared": self.allocator.shared_pages,
                 "utilization": round(
                     1.0 - free / float(self.pool.n_pages), 4),
+                **self.pool_held,
             },
             "kv_pool_bytes": self.pool.nbytes(),
             "weights": dict(self.weights),
@@ -893,6 +933,7 @@ class ServeEngine:
             "builds": self.builds,
             "store_outcomes": dict(self.store_outcomes),
             "program_temp_bytes": dict(self.program_temp_bytes),
+            "program_argument_bytes": dict(self.program_argument_bytes),
             # executables that rejected their inputs and now dispatch
             # through the jit fall-back (wrap_compiled); empty is healthy
             "store_rejected": sorted(

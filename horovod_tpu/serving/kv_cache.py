@@ -3,7 +3,7 @@
 PagedAttention's memory model (vLLM, SOSP '23) applied to the TPU
 runtime: instead of one contiguous ``[B, max_seq, H, D]`` cache whose
 slots are mostly padding, K/V live in a fixed pool of fixed-size pages
-``[n_pages, page, n_kv_heads, head_dim]`` shared by every request. Each
+``[n_pages, page, n_kv_heads * head_dim]`` shared by every request. Each
 request owns an ordered *block table* of physical page ids; attention
 follows the table (``ops/pallas/flash_attention.flash_paged_decode`` on
 TPU, :func:`paged_attention_reference` elsewhere), so HBM held per
@@ -13,16 +13,20 @@ the fragmentation that caps batch size in the contiguous layout is gone.
 Split of responsibilities:
 
 - **Device state** (inside the AOT-compiled steps): the page pool
-  arrays, ``[n_layers, n_pages + 1, page, n_kv_heads, head_dim]`` for K
-  and for V — or, for a model that describes a cache of its own
-  (:class:`CacheRows`: what one token stores in each cached block),
-  ``[blocks, n_pages + 1, page, *row]`` — donated to every step. The
+  arrays, ``[blocks, n_pages + 1, page, *row]`` by what one token stores
+  in each cached block (:class:`CacheRows`) — for the dense block
+  ``[n_layers, n_pages + 1, page, n_kv_heads * head_dim]`` for K and for
+  V: ONE row a token, every KV head's numbers side by side, so that a
+  page is ``[page, n_kv_heads * head_dim]`` with the lanes full whatever
+  ``head_dim`` is (:func:`dense_rows`) — donated to every step. The
   engine keeps them in ONE row-major layout
   (:func:`pool_format`) and the models' step bodies address them as one
   flat run of pages (:func:`flat_pool`, :func:`block_pages`: block
   ``b``'s page ``p`` at ``b * (n_pages + 1) + p``), so a step's only
-  pool-shaped instructions are in-place scatters (docs/serving.md,
-  "Pool layout"). One extra *scratch page* per layer
+  pool-shaped instructions are in-place scatters: a row a slot in
+  decode (:func:`write_token_rows`), whole pages in the dense bodies'
+  prefill (:func:`write_chunk_pages`; docs/serving.md, "Pool
+  layout"). One extra *scratch page* per layer
   (physical id ``n_pages``) absorbs the writes of padded positions and
   empty slots — every store the compiled step issues targets a valid
   physical page, no predication needed.
@@ -58,10 +62,10 @@ import jax.numpy as jnp
 from jax.experimental.layout import Format, Layout
 
 
-def pool_format(sharding: jax.sharding.Sharding, ndim: int = 5) -> Format:
+def pool_format(sharding: jax.sharding.Sharding, ndim: int = 4) -> Format:
     """The one layout a page-pool array lives in, from allocation to the
-    decode kernel's DMA: row-major over ``sharding`` (``ndim`` axes: 5
-    for a K or V array, 3 + the axes of a model's own cache row). A
+    decode kernel's DMA: row-major over ``sharding`` (``ndim`` axes: 3 +
+    the axes of the cache row, so 4 for a K or V array). A
     Pallas operand and a scatter are row-major on TPU, while the
     compiler's own choice for the pool's shape puts a page's tokens on
     the lanes — left free, every step converts each layer's pool there
@@ -74,7 +78,7 @@ class CacheRows:
     """One array of a page pool, by what ONE token stores in it: ``row``
     numbers (a shape) in each of ``blocks`` cached blocks — an attention
     block that caches; a dense layer is one block of K rows
-    ``(n_kv_heads, head_dim)`` and one of V, a layer of two
+    ``(n_kv_heads * head_dim,)`` and one of V, a layer of two
     latent-attention blocks is two blocks of one ``(576,)`` row and no
     V. The array is ``[blocks, n_pages + 1, page, *row]``. ``tp_axis``:
     which axis of ``row`` tensor parallelism splits, if any."""
@@ -86,9 +90,13 @@ class CacheRows:
 
 def dense_rows(n_layers: int, n_kv_heads: int, head_dim: int
                ) -> Tuple[CacheRows, ...]:
-    """The dense block's pool: a K and a V array, each one
-    ``(n_kv_heads, head_dim)`` row a token and layer, heads over tp."""
-    kv = (int(n_kv_heads), int(head_dim))
+    """The dense block's pool: a K and a V array, each ONE row of
+    ``n_kv_heads * head_dim`` numbers a token and layer, head after head.
+    The row's last (and only) axis is what the device tiles onto its 128
+    lanes: heads of 64 fill them in pairs, where a ``(n_kv_heads, 64)``
+    row left every tile half padding, in HBM as in the kernel. Heads are
+    contiguous in the row, so tp splits it into whole KV heads."""
+    kv = (int(n_kv_heads) * int(head_dim),)
     return (CacheRows("k", int(n_layers), kv, tp_axis=0),
             CacheRows("v", int(n_layers), kv, tp_axis=0))
 
@@ -96,8 +104,8 @@ def dense_rows(n_layers: int, n_kv_heads: int, head_dim: int
 class PagePool:
     """Static geometry of the paged cache (all sizes fixed at engine
     build time — they key the compiled serve executables). The dense
-    block's pool is a K and a V array of ``(n_kv_heads, head_dim)`` rows
-    in each of ``n_layers`` blocks; a model with a cache of its own
+    block's pool is a K and a V array of ``(n_kv_heads * head_dim,)``
+    rows in each of ``n_layers`` blocks; a model with a cache of its own
     describes it with ``rows=`` (:class:`CacheRows`, one per array) and
     the allocator, block tables and prefix index serve it unchanged."""
 
@@ -133,10 +141,11 @@ class PagePool:
                      ) -> Tuple[jax.Array, ...]:
         """The zeroed arrays of the pool, one per :class:`CacheRows` (the
         dense block's: ``(k_pages, v_pages)``, each
-        ``[n_layers, n_pages + 1, page, n_kv_heads, head_dim]``), made in
+        ``[n_layers, n_pages + 1, page, n_kv_heads * head_dim]``), made in
         place in ``fmt`` (layout and sharding — the engine's
         :func:`pool_format`, one for all or one each; under tensor
-        parallelism its sharding splits the KV-head axis) so no second
+        parallelism its sharding splits the row into whole KV heads) so
+        no second
         pool-sized buffer exists even while allocating."""
         shapes = self.shapes()
         if fmt is None:
@@ -541,11 +550,52 @@ def write_chunk_rows(pages: Sequence[jax.Array], new: Sequence[jax.Array],
     return tuple(p.at[phys, offs].set(n) for p, n in zip(pages, new))
 
 
+def write_chunk_pages(pages: Sequence[jax.Array], new: Sequence[jax.Array],
+                      block_table: jax.Array, start: jax.Array,
+                      n_real: jax.Array, scratch: Optional[jax.Array] = None
+                      ) -> Tuple[jax.Array, ...]:
+    """:func:`write_chunk_rows` a page at a time, for rows that fill the
+    lanes (the dense block's: :func:`dense_rows`). There a page is whole
+    tiles and ONE token's row a single sublane of each of them, so a
+    chunk's 256 rows are 256 small scatters one after another, where the
+    chunk lies in at most ``ceil(C / page) + 1`` pages wherever it
+    starts: those are read, the real rows laid over them, and written back
+    whole. Rows at or past ``start + n_real`` are written nowhere; a page
+    the real rows do not reach is the scratch page, which gets its own
+    rows back. (Not for a row that leaves lanes empty, such as a latent
+    row of 576: with whole pages read and written the TPU compiler lays
+    such a pool page-first inside the program and converts all of it on
+    the way in and out.)"""
+    page = pages[0].shape[1]
+    if scratch is None:
+        scratch = pages[0].shape[0] - 1
+    c = new[0].shape[0]
+    n = -(-c // page) + 1
+    first = start // page
+    logical = first + jnp.arange(n, dtype=jnp.int32)
+    phys = jnp.where(logical * page < start + n_real,
+                     jnp.take(block_table, logical, mode="clip"), scratch)
+    pos = first * page + jnp.arange(n * page, dtype=jnp.int32)
+    real = (pos >= start) & (pos < start + n_real)
+
+    def write(p, rows):
+        lead = (n * page,) + rows.shape[1:]
+        placed = jax.lax.dynamic_update_slice(
+            jnp.zeros(lead, rows.dtype), rows,
+            (start - first * page,) + (0,) * (rows.ndim - 1))
+        held = jnp.take(p, phys, axis=0)              # [n, page, *row]
+        merged = jnp.where(real.reshape((-1,) + (1,) * (rows.ndim - 1)),
+                           placed, held.reshape(lead))
+        return p.at[phys].set(merged.reshape(held.shape))
+
+    return tuple(write(p, rows) for p, rows in zip(pages, new))
+
+
 def copy_page(*pages_src_dst: jax.Array) -> Tuple[jax.Array, ...]:
     """Device-side copy-on-write body, ``copy_page(*pages, src, dst)``:
     duplicate ONE physical page across every block of every array of the
-    pool (the dense block's: k_pages/v_pages ``[L, n_phys, page, KVH,
-    D]``; src/dst scalar int32). One executable covers every (src, dst)
+    pool (the dense block's: k_pages/v_pages ``[L, n_phys, page,
+    KVH*D]``; src/dst scalar int32). One executable covers every (src, dst)
     pair — the ids are runtime operands, so admission-time COW never
     compiles. Donated by the engine and jitted in the pool's one layout:
     the update is in place."""
@@ -555,7 +605,7 @@ def copy_page(*pages_src_dst: jax.Array) -> Tuple[jax.Array, ...]:
 
 def gather_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
     """Contiguous ``[n_max*page, *row]`` view of one sequence's pages
-    (one block of the pool; the dense block's rows are ``KVH, D``) in
+    (one block of the pool; the dense block's row is ``KVH*D``) in
     block-table order — the prefill attention context (prefill is
     compute-bound; the gather copy is irrelevant there, unlike at decode
     where the kernel follows the table in place). With block tables
@@ -572,16 +622,19 @@ def paged_attention_reference(q: jax.Array, k_pages: jax.Array,
     """jnp fallback of ``flash_paged_decode`` (single layer): gather each
     sequence's pages, mask past its length, plain stable softmax. The
     behavioral spec the kernel is pinned against — and the dispatch
-    target for shapes/backends the kernel does not support. Output
-    ``[B, H, D]`` f32; empty sequences (length 0) return zeros."""
+    target for shapes/backends the kernel does not support. q
+    ``[B, H, D]``, pages ``[n_phys, page, KVH*D]`` (``KVH`` from q's
+    ``D``). Output ``[B, H, D]`` f32; empty sequences (length 0) return
+    zeros."""
     b, h, d = q.shape
-    page, kvh = k_pages.shape[1], k_pages.shape[2]
+    page, kvh = k_pages.shape[1], k_pages.shape[2] // d
     n_max = block_tables.shape[1]
     qpk = h // kvh
 
     def one(qb, table, ln):
         k = gather_pages(k_pages, table).astype(jnp.float32)
         v = gather_pages(v_pages, table).astype(jnp.float32)
+        k, v = k.reshape(-1, kvh, d), v.reshape(-1, kvh, d)
         if qpk > 1:                              # GQA: group heads
             k = jnp.repeat(k, qpk, axis=1)
             v = jnp.repeat(v, qpk, axis=1)

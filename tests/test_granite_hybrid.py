@@ -185,7 +185,7 @@ def test_engine_prefill_and_decode_through_pages_and_slot_state_match_the_refere
     # two kinds of cache side by side: pages for the one attention layer,
     # a state a slot for the three Mamba layers
     assert [p.shape for p in eng.pools] == [
-        (1, eng.pool.n_pages + 1, 8, cfg.n_kv_heads, cfg.head_dim)] * 2
+        (1, eng.pool.n_pages + 1, 8, cfg.n_kv_heads * cfg.head_dim)] * 2
     conv, ssm = eng.state[-2:]
     assert conv.shape == (3, cfg.mamba_d_conv - 1, eng.slots, cfg.conv_dim)
     assert ssm.shape == (3, eng.slots, cfg.mamba_d_state, cfg.d_inner)

@@ -426,10 +426,12 @@ def _normal(text):
 
 def test_dense_programs_are_unchanged_by_the_model_described_pool():
     """The dense block's decode, prefill and copy-on-write programs, compiled
-    for a small config on the CPU, against what the tree before the bodies
-    moved out of the engine and onto the one block (PR 30's) compiled: the
+    for a small config on the CPU, against what the tree that gave the dense
+    pool its flat row (PR 34's: a page is ``[page, KVH * D]``) compiled: the
     same text once source lines and instruction names are taken out, so the
-    same pool arrays, the same layout pin and no new instruction.
+    same pool arrays, the same layout pin and no new instruction. (Until
+    then the readings were PR 30's, and PR 31 and 33 moved the bodies under
+    them without moving a character.)
     ``tests/data/serve_dense_programs.json`` holds that tree's readings."""
     with open(os.path.join(ROOT, "tests", "data",
                            "serve_dense_programs.json")) as f:
@@ -440,7 +442,7 @@ def test_dense_programs_are_unchanged_by_the_model_described_pool():
     eng = ServeEngine(cfg, tfm.init_params(cfg, jax.random.PRNGKey(0)), None,
                       slots=2, page=8, max_seq=64, prefill_chunk=32,
                       prefix_cache=True, draft="off")
-    assert [a.shape for a in eng.pools] == [(2, 17, 8, 4, 16)] * 2
+    assert [a.shape for a in eng.pools] == [(2, 17, 8, 4 * 16)] * 2
     assert eng.k_pages is eng.pools[0] and eng.v_pages is eng.pools[1]
     assert eng.state == ()
     for label, was in before.items():

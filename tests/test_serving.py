@@ -64,30 +64,38 @@ def _engine(cfg=None, params=None, **kw):
 # paged decode attention: kernel (interpret) == jnp reference == dense
 # ---------------------------------------------------------------------------
 
-def _rand_paged(rng, b, h, kvh, d, page, n_max, n_pages):
-    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((n_pages + 1, page, kvh, d)),
-                     jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_pages + 1, page, kvh, d)),
-                     jnp.float32)
+def _rand_paged(rng, b, h, kvh, d, page, n_max, n_pages,
+                dtype=jnp.float32):
+    """Queries ``[b, h, d]`` and a K and a V pool of the dense row: a
+    token's ``kvh`` heads side by side, ``[n_pages + 1, page, kvh * d]``."""
+    q = jnp.asarray(rng.standard_normal((b, h, d)), dtype)
+    kp = jnp.asarray(rng.standard_normal((n_pages + 1, page, kvh * d)),
+                     dtype)
+    vp = jnp.asarray(rng.standard_normal((n_pages + 1, page, kvh * d)),
+                     dtype)
     bt = jnp.asarray(
         rng.permutation(n_pages)[:b * n_max].reshape(b, n_max), jnp.int32)
     lengths = jnp.asarray(rng.integers(1, page * n_max + 1, b), jnp.int32)
     return q, kp, vp, bt, lengths
 
 
-@pytest.mark.parametrize("b,h,kvh,d,page,n_max", [
-    (2, 4, 4, 128, 128, 3),       # lane-aligned page, MHA
-    (3, 4, 2, 64, 128, 2),        # GQA grouping, short head dim
-    (1, 2, 2, 128, 256, 2),       # multi-lane page
-    (4, 8, 4, 16, 32, 3),         # small page and head dim, GQA
+@pytest.mark.parametrize("b,h,kvh,d,page,n_max,dtype", [
+    (2, 4, 4, 128, 128, 3, jnp.float32),      # lane-aligned page, MHA
+    (3, 4, 2, 64, 128, 2, jnp.float32),       # GQA grouping, short head dim
+    (1, 2, 2, 128, 256, 2, jnp.float32),      # multi-lane page
+    (4, 8, 4, 16, 32, 3, jnp.float32),        # small page and head dim, GQA
+    (2, 16, 16, 64, 128, 3, jnp.float32),     # Pythia-410m's heads and page
+    (2, 32, 8, 128, 128, 2, jnp.float32),     # granite-4.0-h-small's
+    (2, 16, 16, 64, 128, 3, jnp.bfloat16),    # the pool as it is served
 ])
-def test_paged_kernel_matches_reference(b, h, kvh, d, page, n_max):
+def test_paged_kernel_matches_reference(b, h, kvh, d, page, n_max, dtype):
     """The interpret-mode kernel is pinned against the jnp paged
-    reference across page sizes, GQA grouping, and ragged lengths."""
+    reference across page sizes, GQA grouping, and ragged lengths, on
+    the pool's flat row. A bfloat16 pool meets the same tolerances: its
+    products are exact and the probabilities stay float32."""
     rng = np.random.default_rng(0)
     q, kp, vp, bt, lengths = _rand_paged(rng, b, h, kvh, d, page, n_max,
-                                         b * n_max + 2)
+                                         b * n_max + 2, dtype)
     scale = d ** -0.5
     out = fa.flash_paged_decode(q, kp, vp, bt, lengths, scale,
                                 interpret=True)
@@ -108,6 +116,7 @@ def test_paged_reference_matches_dense():
     for i in range(b):
         k = np.asarray(kvc.gather_pages(kp, bt[i]))[:int(lengths[i])]
         v = np.asarray(kvc.gather_pages(vp, bt[i]))[:int(lengths[i])]
+        k, v = k.reshape(-1, h, d), v.reshape(-1, h, d)
         s = np.einsum("hd,shd->hs", np.asarray(q[i]), k) * d ** -0.5
         p = np.exp(s - s.max(-1, keepdims=True))
         p /= p.sum(-1, keepdims=True)
@@ -131,16 +140,19 @@ def test_paged_kernel_empty_slot_returns_zeros():
 
 def test_paged_decode_supports_gates_non_dividing_shapes():
     q = jnp.zeros((2, 4, 128))
-    ok = jnp.zeros((8, 128, 4, 128))
+    ok = jnp.zeros((8, 128, 4 * 128))
     assert fa.paged_decode_supports(q, ok)
     # a block is a whole page: the page size is free
-    assert fa.paged_decode_supports(q, jnp.zeros((8, 16, 4, 128)))
-    assert not fa.paged_decode_supports(q, jnp.zeros((8, 128, 3, 128)))
-    assert not fa.paged_decode_supports(q, jnp.zeros((8, 128, 4, 96)))
+    assert fa.paged_decode_supports(q, jnp.zeros((8, 16, 4 * 128)))
+    # 3 KV heads under 4 query heads; a row that is no whole heads of 128
+    assert not fa.paged_decode_supports(q, jnp.zeros((8, 128, 3 * 128)))
+    assert not fa.paged_decode_supports(q, jnp.zeros((8, 128, 4 * 96 + 64)))
+    # the row by head is the old pool, not this kernel's
+    assert not fa.paged_decode_supports(q, jnp.zeros((8, 128, 4, 128)))
     assert not fa.paged_decode_supports(
         q.astype(jnp.bfloat16), ok)              # dtype mismatch
     # GQA grouping IS supported when heads divide
-    assert fa.paged_decode_supports(q, jnp.zeros((8, 128, 2, 128)))
+    assert fa.paged_decode_supports(q, jnp.zeros((8, 128, 2 * 128)))
 
 
 # ---------------------------------------------------------------------------
@@ -1044,8 +1056,10 @@ def _stacked_pool_bodies(cfg):
     """The step bodies over a STACKED pool, the reference the engine's
     flat pool is held to: the one dense block (``tfm.block``, with the
     attentions the engine's bodies use) under an ``attend`` that takes
-    layer ``l``'s pool ``[P+1, page, KVH, D]`` as a slice of the scan,
-    writes it through ``write_token_rows`` / ``write_chunk_rows`` with
+    layer ``l``'s pool ``[P+1, page, KVH*D]`` as a slice of the scan,
+    writes the heads' K and V side by side as one row a token through
+    ``write_token_rows`` / ``write_chunk_rows`` (row by row, where the
+    engine's prefill writes page by page: ``write_chunk_pages``) with
     plain block tables and its own last page as the scratch page, and
     stacks the results back."""
     from jax import lax
@@ -1060,7 +1074,8 @@ def _stacked_pool_bodies(cfg):
             lp, *pages = xs
 
             def attend_sliced(q, k, v):
-                pages[:] = write(pages, (k, v))
+                pages[:] = write(pages, tuple(
+                    t.reshape(t.shape[0], -1) for t in (k, v)))
                 return attend(q, *pages)
 
             x = tfm.block(cfg, lp, x, pos, attend_sliced,
@@ -1108,7 +1123,7 @@ def _pool_engine(tp, **kw):
 
 def _assert_pool_in_format(eng):
     for pages in (eng.k_pages, eng.v_pages):
-        assert pages.format.layout.major_to_minor == (0, 1, 2, 3, 4)
+        assert pages.format.layout.major_to_minor == (0, 1, 2, 3)
         assert pages.sharding.is_equivalent_to(eng.pool_format.sharding,
                                                pages.ndim)
         assert pages.committed
@@ -1117,10 +1132,12 @@ def _assert_pool_in_format(eng):
 @pytest.mark.parametrize("tp", [False, True], ids=["one_device", "tp2"])
 def test_flat_pool_equals_per_layer_writes(tp):
     """After chunked prefills (a bucket-padded remainder among them) and
-    decode steps with slots empty and mid-prefill, the 5-D pool is
+    decode steps with slots empty and mid-prefill, the 4-D pool is
     bitwise what the stacked-pool bodies produce from the same calls:
-    every real write in its layer's page, every padded or masked write
-    in THAT layer's scratch page, nothing anywhere else."""
+    every real write in its layer's page, every masked write in THAT
+    layer's scratch page (a chunk's padded rows there or nowhere: the
+    reference writes them row by row, the engine leaves them out of the
+    pages it writes), nothing anywhere else."""
     eng, mesh = _pool_engine(tp)
     decode, prefill = _stacked_pool_bodies(eng.cfg)
     if tp:
@@ -1163,10 +1180,10 @@ def test_flat_pool_equals_per_layer_writes(tp):
 
     for got, ref in zip((eng.k_pages, eng.v_pages), want):
         got, ref = np.asarray(got), np.asarray(ref)
-        assert got.shape == (3, eng.pool.n_pages + 1, 16, 4, 16)
-        np.testing.assert_array_equal(got, ref)
-        held = set(eng.slot_pages[a]) | set(eng.slot_pages[b])
+        assert got.shape == (3, eng.pool.n_pages + 1, 16, 4 * 16)
         scratch = eng.pool.scratch_page
+        np.testing.assert_array_equal(got[:, :scratch], ref[:, :scratch])
+        held = set(eng.slot_pages[a]) | set(eng.slot_pages[b])
         for layer in range(3):
             assert np.any(got[layer, scratch])      # its own scratch page
             for page in range(eng.pool.n_pages):
@@ -1291,7 +1308,106 @@ def test_reload_probe_runs_on_one_device_of_the_pools_sharding(tp):
     eng, _ = _pool_engine(tp)
     assert eng.reload_keeps_layout is True
     assert artifact_store.reload_keeps_layout(
-        eng.pool_format, (1, 1, 16, 2, 16), jnp.bfloat16) is True
+        eng.pool_format, (1, 1, 16, 2 * 16), jnp.bfloat16) is True
+
+
+@pytest.mark.parametrize("start,n_real,c", [
+    (0, 64, 64),          # aligned, whole pages
+    (0, 6, 32),           # a bucket-padded remainder
+    (16, 40, 64),         # aligned start, padding inside its last page
+    (21, 64, 64),         # unaligned (a copy-on-write prefix): 5 pages
+    (37, 1, 32),          # one real row
+    (96, 32, 64),         # the table's last pages, the window past its end
+])
+def test_chunk_written_by_pages_is_the_chunk_written_by_rows(start, n_real,
+                                                             c):
+    """``write_chunk_pages`` (read the chunk's pages, lay the real rows
+    over them, write them back whole) leaves every page but the scratch
+    page as ``write_chunk_rows`` (one scatter a row) does, wherever the
+    chunk starts and however much of it is padding, for both arrays of a
+    block; rows it does not write keep what they held."""
+    rng = np.random.default_rng(27)
+    page, n_max, row = 16, 8, 24
+    pools = tuple(jnp.asarray(rng.standard_normal((n_max + 3, page, row)),
+                              jnp.float32) for _ in range(2))
+    new = tuple(jnp.asarray(rng.standard_normal((c, row)), jnp.float32)
+                for _ in range(2))
+    table = jnp.asarray(rng.permutation(n_max + 2)[:n_max], jnp.int32)
+    args = (table, jnp.int32(start), jnp.int32(n_real))
+    by_rows = jax.jit(kvc.write_chunk_rows)(pools, new, *args)
+    by_pages = jax.jit(kvc.write_chunk_pages)(pools, new, *args)
+    for was, want, got in zip(pools, by_rows, by_pages):
+        np.testing.assert_array_equal(np.asarray(got)[:-1],
+                                      np.asarray(want)[:-1])
+        # the scratch page got its own rows back
+        np.testing.assert_array_equal(np.asarray(got)[-1],
+                                      np.asarray(was)[-1])
+        pos = np.arange(start, start + n_real)
+        np.testing.assert_array_equal(
+            np.asarray(got)[np.asarray(table)[pos // page], pos % page],
+            np.asarray(new[0] if was is pools[0] else new[1])[:n_real])
+
+
+# ---------------------------------------------------------------------------
+# the dense row: a token's KV heads side by side
+# ---------------------------------------------------------------------------
+
+def test_dense_rows_is_one_row_a_token_that_tp_splits_by_whole_heads():
+    """``dense_rows`` describes K and V as ONE row of ``KVH * D`` numbers a
+    token and layer (the last axis fills the lanes whatever ``D`` is), and
+    tensor parallelism splits that row into whole KV heads in head order:
+    a 2-way engine's pool, shard beside shard, is the one-device pool."""
+    rows = kvc.dense_rows(3, 4, 16)
+    assert [(r.name, r.blocks, r.row, r.tp_axis) for r in rows] == [
+        ("k", 3, (64,), 0), ("v", 3, (64,), 0)]
+    pool = kvc.PagePool(3, 8, 16, 4, 16)
+    assert pool.rows == rows
+    assert pool.shapes() == ((3, 9, 16, 64),) * 2
+    assert pool.nbytes() == 2 * 3 * 9 * 16 * 64 * 4
+
+    one, _ = _pool_engine(False)
+    two, _ = _pool_engine(True)
+    prompt = np.random.default_rng(25).integers(0, 256, 40).astype(np.int32)
+    assert _greedy_solo(one, prompt, 4) == _greedy_solo(two, prompt, 4)
+    for whole, split in zip(one.pools, two.pools):
+        shards = sorted(split.addressable_shards, key=lambda s: s.index[3])
+        assert [s.data.shape for s in shards] == [(3, 33, 16, 2 * 16)] * 2
+        assert [s.index[3] for s in shards] == [slice(0, 32), slice(32, 64)]
+        # page 0 is not handed out first (the free list is LIFO from the
+        # top), so compare every page but each layer's scratch
+        np.testing.assert_allclose(np.asarray(split)[:, :-1],
+                                   np.asarray(whole)[:, :-1],
+                                   rtol=1e-4, atol=1e-5)
+        assert np.any(np.asarray(whole)[:, :-1, :, 32:])    # heads 2, 3
+
+
+def test_engine_through_the_kernel_serves_the_reference_paths_tokens():
+    """The decode program with the paged-decode kernel in it (interpret
+    mode on the CPU, over the float32 pool's flat row) serves the tokens
+    the same engine serves through the jnp reference, prompt chunks,
+    ragged slots and all."""
+    from horovod_tpu.config import knobs
+    cfg = _cfg()
+    params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(26)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (70, 9, 33)]
+
+    def served():
+        eng, _ = _engine(cfg, params)
+        reqs = [Request(rid=i, prompt=p, max_new_tokens=6)
+                for i, p in enumerate(prompts)]
+        done = ServeScheduler(eng).run(reqs)
+        return {r.rid: list(r.tokens) for r in done}
+
+    want = served()
+    knobs.set_override("HOROVOD_TPU_PALLAS", "interpret")
+    try:
+        assert fa.enabled() == "interpret"
+        assert served() == want
+    finally:
+        knobs.clear_override("HOROVOD_TPU_PALLAS")
+    assert len(want) == 3 and all(len(t) == 6 for t in want.values())
 
 
 # ---------------------------------------------------------------------------

@@ -1,8 +1,10 @@
 """Compile-only checks of the serve engine's programs at the serve cell's
 real size (pythia-410m widths, 16 and 24 slots of 2048 context, pages of
 128, bf16) for a described ``v5e:2x2``: the KV pool stays in one layout and
-one buffer, and the programs the engine lowers from the tree it prepared
-hold no float32 buffer the shape of a weight. The programs are built the
+one buffer, its pages are whole tiles (a token's 16 heads of 64 side by side
+on 1024 lanes: the arguments hold the pool's own bytes, no padding), and the
+programs the engine lowers from the tree it prepared hold no float32 buffer
+the shape of a weight. The programs are built the
 way the engine builds them (``serve_programs`` over ``pool_format``,
 lowered from shapes); nothing executes. Bytes are printed (``pytest -s``)
 for PERF.md."""
@@ -20,7 +22,7 @@ from horovod_tpu.models import transformer as tfm
 from horovod_tpu.serving import engine as eng, kv_cache as kvc
 
 PAGE, CTX, BUCKET = 128, 2048, 256
-ROW_MAJOR = (0, 1, 2, 3, 4)
+ROW_MAJOR = (0, 1, 2, 3)
 
 
 @pytest.fixture(scope="module")
@@ -122,6 +124,16 @@ def test_pool_stays_in_one_layout_and_one_buffer(topo, compiled_kernels,
         from horovod_tpu.ops.pallas.flash_attention import \
             compiled_kernels as ck
         assert "hvd_paged_decode" in ck(text)
+        # the kernel reads the flat run of pages itself, row-major: a
+        # page is [128, 16 * 64] bfloat16, whole (16, 128) tiles
+        calls = [line for line in text.splitlines()
+                 if "%hvd_paged_decode" in line
+                 and "tpu_custom_call" in line]
+        assert len(calls) == 1
+        operands = calls[0].split("operand_layout_constraints=")[1]
+        pages = pool.n_layers * (pool.n_pages + 1)
+        assert operands.count(
+            f"bf16[{pages},{PAGE},1024]{{2,1,0}}") == 2, operands[:400]
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -129,9 +141,10 @@ def test_prepared_programs_hold_no_float32_weight(topo, compiled_kernels,
                                                   program):
     """The cell's decode and 256-token prefill programs as the engine
     lowers them, from the shapes of the tree it prepared: 0.81 GB of
-    bfloat16 weights beside the 6.47 GB pool where the float32 tree made
-    8.09 GB of arguments, and no float32 buffer the shape of a weight
-    stack or of one layer's slice of it."""
+    bfloat16 weights beside the 3.23 GB pool, which lies there at its own
+    size (4.04 GB of arguments; 7.28 while a head of 64 stood alone on
+    128 lanes), and no float32 buffer the shape of a weight stack or of
+    one layer's slice of it."""
     cfg = _cfg()
     given = jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
     served = eng.serve_model(cfg).served_params(cfg, given)
@@ -152,7 +165,9 @@ def test_prepared_programs_hold_no_float32_weight(topo, compiled_kernels,
           f"temporaries {m.temp_size_in_bytes / 1e9:.3f} GB")
     assert nbytes(given) == pytest.approx(1.62e9, rel=5e-3)
     assert nbytes(served) == pytest.approx(0.81e9, rel=5e-3)
-    assert m.argument_size_in_bytes == pytest.approx(7.28e9, rel=1e-2)
+    assert pool.nbytes() == pytest.approx(3.234e9, rel=1e-3)
+    assert m.argument_size_in_bytes == pytest.approx(
+        nbytes(served) + pool.nbytes(), rel=2e-3)      # 4.044 GB
     assert m.temp_size_in_bytes < 0.05e9     # 0.605 were the cast copies
     shapes = set()                  # the norm scales stay float32
     for a in (named(given)[n] for n in cast):
@@ -170,3 +185,25 @@ def test_prepared_programs_hold_no_float32_weight(topo, compiled_kernels,
     widened = [dims for dims in wide
                if tuple(int(d) for d in dims.split(",")) in shapes]
     assert not widened, widened
+
+
+def test_engine_counts_the_pool_as_it_lies_in_memory():
+    """``engine.stats()["pool"]``: each array's row, the arrays' bytes,
+    and what the decode program's arguments hold beyond weights, state
+    and integers. On the CPU nothing is tiled, so the ratio is 1.0 (on
+    the chip it is what the lanes leave: 1.0 for the flat row, 2.0 while
+    a head of 64 was a row of its own)."""
+    cfg = tfm.TransformerConfig(
+        vocab_size=256, d_model=64, n_heads=4, head_dim=16, n_layers=2,
+        d_ff=128, max_seq=256, dtype=jnp.float32, dp_axis=None, remat=False)
+    engine = eng.ServeEngine(
+        cfg, tfm.init_params(cfg, jax.random.PRNGKey(0)), mesh=None,
+        slots=4, page=16, max_seq=128, prefill_chunk=64)
+    held = engine.stats()["pool"]
+    assert held["rows"] == {"k": [4 * 16], "v": [4 * 16]}
+    assert held["bytes"] == engine.pool.nbytes() \
+        == 2 * 2 * (4 * 8 + 1) * 16 * 64 * 4
+    assert held["resident_bytes"] == held["bytes"]
+    assert held["padding"] == 1.0
+    assert engine.stats()["program_argument_bytes"]["serve_decode"] > \
+        held["bytes"]

@@ -48,6 +48,13 @@ SECTIONS = [
      "routed experts plus a shared expert in each; served through "
      "`ServeEngine` with a per-slot recurrent state beside the paged KV "
      "pool (`GraniteHybridConfig.serve_model()`); see docs/serving.md."),
+    ("horovod_tpu.models.solar_open2",
+     "Delta-rule hybrid model",
+     "Gated delta-rule linear-attention (KDA) layers with a gated "
+     "grouped-query attention layer among every few, sigmoid-routed "
+     "experts plus a shared expert in each; served through `ServeEngine` "
+     "on the hybrid stack's step with a matrix state a head and slot "
+     "(`SolarOpen2Config.serve_model()`); see docs/serving.md."),
     ("horovod_tpu.callbacks", "Callbacks",
      "Keras-style training callbacks (broadcast, metric averaging, LR "
      "schedules, best-model checkpoint)."),

@@ -20,6 +20,11 @@ first-class package because the driver benchmarks the framework through them:
                     positional embedding among every few), each with routed
                     experts and a shared expert; served with a per-slot
                     recurrent state beside the paged KV pool.
+- ``solar_open2`` — gated delta-rule linear-attention layers (KDA: a decay a
+                    key channel and a rank-one correction of a matrix state
+                    a head and slot) with one gated grouped-query attention
+                    layer among every few, sigmoid-routed experts and a
+                    shared expert; served on ``granite_hybrid``'s step.
 """
 
 from horovod_tpu.models.mlp import MLP, MnistCNN  # noqa: F401
@@ -35,3 +40,4 @@ from horovod_tpu.models.transformer import (  # noqa: F401
 )
 from horovod_tpu.models.longcat_flash import LongCatFlashConfig  # noqa: F401
 from horovod_tpu.models.granite_hybrid import GraniteHybridConfig  # noqa: F401
+from horovod_tpu.models.solar_open2 import SolarOpen2Config  # noqa: F401

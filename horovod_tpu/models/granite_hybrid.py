@@ -73,8 +73,40 @@ PUBLISHED_LAYER_TYPES = tuple(
 STATE_DTYPE = jnp.float32       # the recurrent state and the conv tail
 
 
+class LayerStack:
+    """What a config of a stack whose layers differ in kind answers, from
+    its ``layer_types`` and its share of the routed experts: what
+    :func:`_serve_step`, :func:`experts` and the engine's records ask of it
+    (Granite's here; ``models/solar_open2.py``'s too)."""
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def held_experts(self) -> int:
+        return (self.n_routed_experts if self.expert_count is None
+                else self.expert_count)
+
+    def count(self, kind: str) -> int:
+        return sum(1 for t in self.layer_types if t == kind)
+
+    def runs(self) -> List[Tuple[str, int, int, int]]:
+        """The stack as runs of like layers: (kind, the run's first layer,
+        that layer's index among its kind, how many)."""
+        out: List[Tuple[str, int, int, int]] = []
+        seen: Dict[str, int] = {}
+        for i, kind in enumerate(self.layer_types):
+            if out and out[-1][0] == kind:
+                out[-1] = (*out[-1][:3], out[-1][3] + 1)
+            else:
+                out.append((kind, i, seen.get(kind, 0), 1))
+            seen[kind] = seen.get(kind, 0) + 1
+        return out
+
+
 @dataclasses.dataclass(frozen=True)
-class GraniteHybridConfig:
+class GraniteHybridConfig(LayerStack):
     """Sizes of the hybrid stack (defaults: granite-4.0-h-small as
     published) and the share of its routed experts held here;
     ``serve_model()`` is what ``ServeEngine`` asks for."""
@@ -106,17 +138,8 @@ class GraniteHybridConfig:
     tp_axis: Optional[str] = None   # not offered: one chip's share is served
 
     @property
-    def n_layers(self) -> int:
-        return len(self.layer_types)
-
-    @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
-
-    @property
-    def held_experts(self) -> int:
-        return (self.n_routed_experts if self.expert_count is None
-                else self.expert_count)
 
     @property
     def d_inner(self) -> int:
@@ -127,21 +150,9 @@ class GraniteHybridConfig:
         """Channels of the convolution: x, B and C."""
         return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
-    def count(self, kind: str) -> int:
-        return sum(1 for t in self.layer_types if t == kind)
-
-    def runs(self) -> List[Tuple[str, int, int, int]]:
-        """The stack as runs of like layers: (kind, the run's first layer,
-        that layer's index among its kind, how many)."""
-        out: List[Tuple[str, int, int, int]] = []
-        seen = {MAMBA: 0, ATTENTION: 0}
-        for i, kind in enumerate(self.layer_types):
-            if out and out[-1][0] == kind:
-                out[-1] = (*out[-1][:3], out[-1][3] + 1)
-            else:
-                out.append((kind, i, seen[kind], 1))
-            seen[kind] += 1
-        return out
+    def route(self, v: jax.Array, ep: Params) -> moe_lib.TopKRouting:
+        """Top-k of the logits, softmax over the chosen."""
+        return moe_lib.topk_softmax_route(v, ep["router"], self.top_k)
 
     def serve_model(self):
         """What :class:`horovod_tpu.serving.ServeEngine` asks of this
@@ -183,16 +194,16 @@ def _is_shape(x: Any) -> bool:
     return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
 
 
-def init_params(cfg: GraniteHybridConfig, rng: jax.Array,
-                dtype: Any = None) -> Params:
-    """Products ~ N(0, 1/fan_in) in ``dtype`` (the config's by default), the
-    router float32; norm scales 1; the state-space leaves as Mamba-2
-    initialises them (``A`` uniform in [1, 16], the step log-uniform in
-    [1e-3, 1e-1] through the inverse softplus, ``D`` = 1, the convolution
-    N(0, 1/K) with a zero bias), all float32."""
-    dtype = dtype or cfg.dtype
+def init_tree(shapes: Params, rng: jax.Array, dtype: Any, conv_taps: int,
+              zeros: Tuple[str, ...] = ()) -> Params:
+    """A tree of ``(shape, fan-in)`` leaves drawn: products ~ N(0,
+    1/fan_in) in ``dtype``, the router float32; float32 the rest: the decay
+    as Mamba-2 initialises it (``A_log``: ``A`` uniform in [1, 16];
+    ``dt_bias``: the step log-uniform in [1e-3, 1e-1] through the inverse
+    softplus), a convolution N(0, 1/K), the leaves named in ``zeros`` 0,
+    every other (norm scales, a skip) 1."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg), is_leaf=_is_shape)
+        shapes, is_leaf=_is_shape)
 
     def leaf(key, path, shape, fan_in):
         name = jax.tree_util.keystr(path)
@@ -208,14 +219,22 @@ def init_params(cfg: GraniteHybridConfig, rng: jax.Array,
             return step + jnp.log(-jnp.expm1(-step))
         if name.endswith("['conv_w']"):
             return jax.random.normal(key, shape, jnp.float32) \
-                * cfg.mamba_d_conv ** -0.5
-        if name.endswith("['conv_b']"):
+                * conv_taps ** -0.5
+        if name.endswith(tuple(f"['{z}']" for z in zeros)):
             return jnp.zeros(shape, jnp.float32)
-        return jnp.ones(shape, jnp.float32)         # norm scales, D
+        return jnp.ones(shape, jnp.float32)
 
     return jax.tree.unflatten(treedef, [
         leaf(k, path, *shape_fan_in) for k, (path, shape_fan_in)
         in zip(jax.random.split(rng, len(flat)), flat)])
+
+
+def init_params(cfg: GraniteHybridConfig, rng: jax.Array,
+                dtype: Any = None) -> Params:
+    """:func:`init_tree` of this model's leaves: ``D`` = 1, the
+    convolution's bias 0."""
+    return init_tree(param_shapes(cfg), rng, dtype or cfg.dtype,
+                     cfg.mamba_d_conv, zeros=("conv_b",))
 
 
 def param_specs(cfg: GraniteHybridConfig) -> Params:
@@ -244,12 +263,13 @@ def ssm_project(cfg: GraniteHybridConfig, mp: Params, u: jax.Array
 
 
 def conv_window(mp: Params, window: jax.Array) -> jax.Array:
-    """The causal depthwise convolution and its silu on a window of inputs
+    """The causal depthwise convolution (``conv_w`` ``[K, C]``, a bias
+    ``conv_b`` where the model has one) and its silu on a window of inputs
     ``[K - 1 + R, ..., C]`` (the K - 1 inputs before the rows, then the R
     rows, along the first axis): ``[R, ..., C]``, float32."""
     k = mp["conv_w"].shape[0]
     rows = window.shape[0] - k + 1
-    acc = mp["conv_b"].astype(jnp.float32)
+    acc = mp["conv_b"].astype(jnp.float32) if "conv_b" in mp else 0.0
     for j in range(k):
         acc = acc + mp["conv_w"][j] * window[j:j + rows]
     return jax.nn.silu(acc)
@@ -261,6 +281,38 @@ def conv_tail(window: jax.Array, n_real: jax.Array, k1: int) -> jax.Array:
     ``k1`` rows are the tail before the chunk (so a chunk of fewer real rows
     than ``k1`` keeps what is left of that). Never the bucket's padding."""
     return lax.dynamic_slice_in_dim(window, n_real, k1, axis=0)
+
+
+def conv_decode(mp: Params, conv: jax.Array, layer: jax.Array, x: jax.Array,
+                live: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """One row a slot through a recurrent layer's convolution: x ``[S, C]``
+    behind the tails conv ``[L, K-1, S, C]`` of layer ``layer``. Returns
+    (the convolved rows ``[S, C]``, conv with that layer's tails moved on
+    for the slots ``live`` and untouched for the others)."""
+    tail = lax.dynamic_index_in_dim(conv, layer, 0, keepdims=False)
+    window = jnp.concatenate([tail, x[None]], axis=0)       # [K, S, C]
+    conv = lax.dynamic_update_index_in_dim(
+        conv, jnp.where(live[None, :, None], window[1:], tail)
+        .astype(conv.dtype), layer, 0)
+    return conv_window(mp, window)[0], conv
+
+
+def conv_prefill(mp: Params, conv: jax.Array, layer: jax.Array,
+                 slot: jax.Array, x: jax.Array, carried: jax.Array,
+                 n_real: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """A chunk of ONE sequence through a recurrent layer's convolution: x
+    ``[C, channels]`` behind slot ``slot``'s stored tail of layer ``layer``
+    (``carried``) or behind zeros. Returns (the convolved rows, conv with
+    the tail of the last REAL rows stored)."""
+    k1 = conv.shape[1]
+    at = (layer, 0, slot, 0)
+    tail = lax.dynamic_slice(conv, at, (1, k1, 1, conv.shape[-1]))
+    tail = jnp.where(carried, tail.reshape(k1, -1), 0.0)
+    window = jnp.concatenate([tail, x], axis=0)             # [K-1 + C, ch]
+    conv = lax.dynamic_update_slice(
+        conv, conv_tail(window, n_real, k1).reshape(1, k1, 1, -1)
+        .astype(conv.dtype), at)
+    return conv_window(mp, window), conv
 
 
 def ssm_inputs(cfg: GraniteHybridConfig, mp: Params, xbc: jax.Array,
@@ -365,12 +417,7 @@ def mamba_decode(cfg: GraniteHybridConfig, mp: Params, u: jax.Array,
     with jax.named_scope("hvd_ssm"):
         z, xbc, dt = ssm_project(cfg, mp, u)
         with jax.named_scope("hvd_ssm_conv"):
-            tail = lax.dynamic_index_in_dim(conv, layer, 0, keepdims=False)
-            window = jnp.concatenate([tail, xbc[None]], axis=0)  # [K, S, C]
-            xbc = conv_window(mp, window)[0]
-            conv = lax.dynamic_update_index_in_dim(
-                conv, jnp.where(live[None, :, None], window[1:], tail)
-                .astype(conv.dtype), layer, 0)
+            xbc, conv = conv_decode(mp, conv, layer, xbc, live)
         with jax.named_scope("hvd_ssm_scan"):
             x, b, c, step, a = ssm_inputs(cfg, mp, xbc, dt, live)
             y, s = ssm_step(
@@ -388,19 +435,12 @@ def mamba_prefill(cfg: GraniteHybridConfig, mp: Params, u: jax.Array,
     u ``[C, D]`` (bucket-padded, ``n_real`` of them real), from zeros when
     ``start == 0`` and from slot ``slot``'s stored state otherwise; the
     state after the last REAL row is stored."""
-    k1 = cfg.mamba_d_conv - 1
     carried = start > 0
     with jax.named_scope("hvd_ssm"):
         z, xbc, dt = ssm_project(cfg, mp, u)
         with jax.named_scope("hvd_ssm_conv"):
-            at = (layer, 0, slot, 0)
-            tail = lax.dynamic_slice(conv, at, (1, k1, 1, conv.shape[-1]))
-            tail = jnp.where(carried, tail.reshape(k1, -1), 0.0)
-            window = jnp.concatenate([tail, xbc], axis=0)    # [K-1 + C, C]
-            xbc = conv_window(mp, window)
-            conv = lax.dynamic_update_slice(
-                conv, conv_tail(window, n_real, k1).reshape(1, k1, 1, -1)
-                .astype(conv.dtype), at)
+            xbc, conv = conv_prefill(mp, conv, layer, slot, xbc, carried,
+                                     n_real)
         with jax.named_scope("hvd_ssm_scan"):
             x, b, c, step, a = ssm_inputs(
                 cfg, mp, xbc, dt, jnp.arange(u.shape[0]) < n_real)
@@ -450,17 +490,18 @@ def attend_gathered(cfg: GraniteHybridConfig, q: jax.Array,
     return o.reshape(q.shape)
 
 
-def experts(cfg: GraniteHybridConfig, ep: Params, h: jax.Array,
+def experts(cfg: LayerStack, ep: Params, h: jax.Array,
             valid: Optional[jax.Array]) -> Tuple[jax.Array, jax.Array]:
     """The expert half of a layer on the residual stream h ``[N, D]``
-    (float32): this chip's routed experts' terms plus the shared expert.
-    Returns (h, the routing counters of the call over the rows ``valid``).
-    The router reads the float32 rows, the products the config's dtype."""
+    (float32): this chip's routed experts' terms, chosen by the config's
+    gate rule (``cfg.route``), plus the shared expert. Returns (h, the
+    routing counters of the call over the rows ``valid``). The router reads
+    the float32 rows, the products the config's dtype."""
     v = _norm(cfg, h, ep["norm"])
     share = dict(n_routed=cfg.n_routed_experts, first=cfg.expert_first)
     with jax.named_scope("hvd_moe"):
         with jax.named_scope("hvd_moe_router"):
-            routing = moe_lib.topk_softmax_route(v, ep["router"], cfg.top_k)
+            routing = cfg.route(v, ep)
             counts = moe_lib.share_counts(
                 routing, count=cfg.held_experts, valid=valid, **share)
         s = moe_lib.expert_share_ffn(
@@ -471,10 +512,12 @@ def experts(cfg: GraniteHybridConfig, ep: Params, h: jax.Array,
     return h + cfg.residual_multiplier * s, counts
 
 
-def logits_of(cfg: GraniteHybridConfig, params: Params, h: jax.Array
-              ) -> jax.Array:
+def logits_of(cfg: LayerStack, params: Params, h: jax.Array) -> jax.Array:
+    """Over the rows of the vocabulary held here: an untied ``head`` where
+    the model has one, else the tied embedding."""
     x = _norm(cfg, h, params["final_norm"]).astype(cfg.dtype)
-    return jnp.einsum("...d,vd->...v", x, params["embed"].astype(cfg.dtype),
+    head = params["head"] if "head" in params else params["embed"]
+    return jnp.einsum("...d,vd->...v", x, head.astype(cfg.dtype),
                       preferred_element_type=jnp.float32) \
         / cfg.logits_scaling
 
@@ -509,7 +552,7 @@ def _check_serve(cfg: GraniteHybridConfig, draft_mode: str) -> None:
             f"in the {cfg.n_routed_experts} routed experts")
 
 
-def _cache_rows(cfg: GraniteHybridConfig):
+def _cache_rows(cfg: LayerStack):
     from horovod_tpu.serving.kv_cache import dense_rows
     return dense_rows(cfg.count(ATTENTION), cfg.n_kv_heads, cfg.head_dim)
 
@@ -519,7 +562,7 @@ def _cache_rows(cfg: GraniteHybridConfig):
 RESETS, CARRIED, DECODE_ROWS = 0, 1, 2
 
 
-def _counter_state(cfg: GraniteHybridConfig):
+def _counter_state(cfg: LayerStack):
     return (moe_lib.share_counter_state(cfg.held_experts),
             jax.ShapeDtypeStruct((2, 1, 3), jnp.uint32))
 
@@ -537,11 +580,13 @@ def slot_state(cfg: GraniteHybridConfig, slots: int):
                 (lm, slots, cfg.mamba_d_state, cfg.d_inner), STATE_DTYPE))
 
 
-def serve_stats(cfg: GraniteHybridConfig, state: Tuple[jax.Array, ...]
+def serve_stats(cfg: LayerStack, state: Tuple[jax.Array, ...]
                 ) -> Dict[str, Any]:
     """``engine.stats()["moe"]`` and ``["ssm"]``: the counters read back
     (the one place), published as ``hvd_serve_moe_*`` / ``hvd_serve_ssm_*``
-    gauges."""
+    gauges. ``state``: the routing counters, the recurrent layers' counters,
+    the convolution tails ``[L, K-1, slots, C]`` and the recurrent state
+    ``[L, slots, ...]``."""
     from horovod_tpu import metrics as M
     routing, counted, conv, ssm = state
     totals = [int(v) for v in moe_lib.counter_totals(counted)[0]]
@@ -553,7 +598,7 @@ def serve_stats(cfg: GraniteHybridConfig, state: Tuple[jax.Array, ...]
             ("state_bytes", "Bytes of per-slot recurrent state and "
              "convolution tail resident on the device"),
             ("slots", "Slots that hold a recurrent state"),
-            ("layers", "State-space layers that keep a state a slot"),
+            ("layers", "Recurrent layers that keep a state a slot"),
             ("resets", "Prefill chunks that opened a prompt from a zero "
              "state"),
             ("chunks_carried", "Prefill chunks that continued from the "
@@ -564,17 +609,18 @@ def serve_stats(cfg: GraniteHybridConfig, state: Tuple[jax.Array, ...]
                                           cfg.held_experts), "ssm": out}
 
 
-def _serve_step(cfg: GraniteHybridConfig, params: Params, held: Tuple,
+def _serve_step(cfg: LayerStack, params: Params, held: Tuple,
                 block_tables: jax.Array, tokens: jax.Array,
-                counted: jax.Array, mamba, attention, program: int,
+                counted: jax.Array, recurrent, attention, program: int,
                 ssm_counts: jax.Array, out_row: Optional[jax.Array] = None):
     """What a decode step and a prefill chunk share: embed ``tokens``
-    ``[N]``, every run of like layers in a scan of its own — a Mamba layer
-    through ``mamba(mp, u, conv, ssm, i)``, an attention layer through
-    ``attention(ap, u, flat pool, block tables, scratch)``, ``i`` the
-    layer's index among its kind — each followed by the expert block, then
-    the head (of row ``out_row`` only, if given) and argmax. ``held``: the
-    K and V pools, the routing and state-space counters, the slot state."""
+    ``[N]``, every run of like layers in a scan of its own — an attention
+    layer through ``attention(ap, u, flat pool, block tables, scratch)``, a
+    layer of the other kind (Mamba-2 here) through ``recurrent(mp, u, conv,
+    ssm, i)``, ``i`` the layer's index among its kind — each followed by the
+    expert block, then the head (of row ``out_row`` only, if given) and
+    argmax. ``held``: the K and V pools, the routing and state-space
+    counters, the slot state."""
     from horovod_tpu.serving import kv_cache as kvc
     k_pages, v_pages, routing, ssm_counters, conv, ssm = held
     dt, layers = cfg.dtype, params["layers"]
@@ -587,12 +633,12 @@ def _serve_step(cfg: GraniteHybridConfig, params: Params, held: Tuple,
             li, ki = index
             mp = jax.tree.map(lambda a: a[ki], layers[kind])
             u = _norm(cfg, h, mp["norm"]).astype(dt)
-            if kind == MAMBA:
-                o, conv, ssm = mamba(mp, u, conv, ssm, ki)
-            else:
+            if kind == ATTENTION:
                 bt, scratch = kvc.block_pages(k_pages.shape, ki,
                                               block_tables)
                 o, flat = attention(mp, u, flat, bt, scratch)
+            else:
+                o, conv, ssm = recurrent(mp, u, conv, ssm, ki)
             h = h + cfg.residual_multiplier * o
             h, counts = experts(
                 cfg, jax.tree.map(lambda a: a[li], layers["moe"]), h,
@@ -617,26 +663,39 @@ def _serve_step(cfg: GraniteHybridConfig, params: Params, held: Tuple,
             conv, ssm, next_tokens, logits)
 
 
+def _gated(cfg, ap, u, o):
+    """What the attention gives, under the layer's output gate where it has
+    one (``wz``): ``o * sigmoid(u Wz)``, a gate a channel, one multiply
+    before ``Wo``."""
+    if "wz" not in ap:
+        return o
+    z = (u @ ap["wz"].astype(cfg.dtype)).reshape(o.shape)
+    return (o.astype(jnp.float32)
+            * jax.nn.sigmoid(z.astype(jnp.float32))).astype(o.dtype)
+
+
 def _attention_out(cfg, ap, o):
     return jnp.dot(o.reshape(o.shape[0], -1).astype(cfg.dtype),
                    ap["wo"].astype(cfg.dtype),
                    preferred_element_type=jnp.float32)
 
 
-def decode_body(cfg: GraniteHybridConfig, params: Params, *args):
+def decode_body(cfg: LayerStack, params: Params, *args, recurrent=None):
     """One decode step over all slots: ``(k_pages, v_pages, routing
     counters, state-space counters, conv, ssm, block_tables, lengths,
     tokens)``. The slots with ``lengths > 0`` decode: their K and V rows go
     to their pages, their recurrent state advances by one token. Every
     other slot (empty, or mid-prefill) writes to the scratch page and keeps
-    its state bit for bit."""
+    its state bit for bit. ``recurrent``: the other kind of layer's decode
+    (``mamba_decode``'s signature; that by default)."""
     from horovod_tpu.serving import kv_cache as kvc
     *held, block_tables, lengths, tokens = args
     live = lengths > 0
     valid = lengths < block_tables.shape[1] * held[0].shape[2]
+    recurrent = recurrent or mamba_decode
 
-    def mamba(mp, u, conv, ssm, i):
-        return mamba_decode(cfg, mp, u, conv, ssm, i, live)
+    def layer(mp, u, conv, ssm, i):
+        return recurrent(cfg, mp, u, conv, ssm, i, live)
 
     def attention(ap, u, flat, bt, scratch):
         q, k, v = attention_project(cfg, ap, u)
@@ -644,31 +703,33 @@ def decode_body(cfg: GraniteHybridConfig, params: Params, *args):
             flat = kvc.write_token_rows(flat, (k, v), bt, lengths,
                                         valid=valid, scratch=scratch)
         with jax.named_scope("hvd_attention"):
-            o = kvc.paged_decode_attention(
-                q, *flat, bt, lengths + 1, cfg.attention_multiplier)
+            o = _gated(cfg, ap, u, kvc.paged_decode_attention(
+                q, *flat, bt, lengths + 1, cfg.attention_multiplier))
         return _attention_out(cfg, ap, o), flat
 
     counts = jnp.zeros((3,), jnp.int32).at[DECODE_ROWS].set(
         jnp.sum(live, dtype=jnp.int32))
     return _serve_step(cfg, params, tuple(held), block_tables, tokens, live,
-                       mamba, attention, moe_lib.DECODE, counts)
+                       layer, attention, moe_lib.DECODE, counts)
 
 
-def prefill_body(cfg: GraniteHybridConfig, params: Params, *args):
+def prefill_body(cfg: LayerStack, params: Params, *args, recurrent=None):
     """One prefill chunk of ONE sequence, told its slot: ``(k_pages,
     v_pages, routing counters, state-space counters, conv, ssm,
     block_table, slot, start, n_real, tokens)``; tokens ``[C]``
     (bucket-padded) at positions ``start ..``. The attention layers write
     the chunk's K and V rows to the pages and attend over the cached prefix
-    and the chunk; the Mamba layers go on from the slot's stored state (from
+    and the chunk; the recurrent layers (``recurrent``: ``mamba_prefill``'s
+    signature; that by default) go on from the slot's stored state (from
     zeros at ``start == 0``) and store the state after the last real row."""
     from horovod_tpu.serving import kv_cache as kvc
     *held, block_table, slot, start, n_real, tokens = args
     c = tokens.shape[0]
     pos = start + jnp.arange(c, dtype=jnp.int32)
+    recurrent = recurrent or mamba_prefill
 
-    def mamba(mp, u, conv, ssm, i):
-        return mamba_prefill(cfg, mp, u, conv, ssm, i, slot, start, n_real)
+    def layer(mp, u, conv, ssm, i):
+        return recurrent(cfg, mp, u, conv, ssm, i, slot, start, n_real)
 
     def attention(ap, u, flat, bt, scratch):
         q, k, v = attention_project(cfg, ap, u)
@@ -676,13 +737,13 @@ def prefill_body(cfg: GraniteHybridConfig, params: Params, *args):
             flat = kvc.write_chunk_pages(flat, (k, v), bt, start, n_real,
                                         scratch=scratch)
         with jax.named_scope("hvd_attention"):
-            o = attend_gathered(cfg, q, *flat, bt, pos)
+            o = _gated(cfg, ap, u, attend_gathered(cfg, q, *flat, bt, pos))
         return _attention_out(cfg, ap, o), flat
 
     opened = (start == 0).astype(jnp.int32)
     counts = jnp.zeros((3,), jnp.int32).at[RESETS].set(opened) \
         .at[CARRIED].set(1 - opened)
     return _serve_step(cfg, params, tuple(held), block_table, tokens,
-                       jnp.arange(c) < n_real, mamba, attention,
+                       jnp.arange(c) < n_real, layer, attention,
                        moe_lib.PREFILL,
                        counts, out_row=jnp.maximum(n_real - 1, 0))

@@ -20,10 +20,12 @@ per token, the chip is told which routed experts it holds (``first``,
 ``count``), computes their terms and every zero-compute (identity) term of
 its own tokens, and leaves out the absent experts' terms. No token is
 dropped: an expert's rows are bounded by the tokens present, not by a
-capacity factor. On one chip there is no exchange. Two gate rules give the
-same :class:`TopKRouting`: :func:`topk_route` (softmax over every router
-output, the chosen not renormalised) and :func:`topk_softmax_route` (top-k
-of the logits, softmax over the chosen). The counters of a served share
+capacity factor. On one chip there is no exchange. Three gate rules give the
+same :class:`TopKRouting` from one product and one top-k (``_route_scores``,
+``_choose``): :func:`topk_route` (softmax over every router output, the
+chosen not renormalised), :func:`topk_softmax_route` (top-k of the logits,
+softmax over the chosen) and :func:`topk_sigmoid_route` (a sigmoid an
+output, the chosen renormalised to sum to the scaling). The counters of a served share
 (:func:`share_counter_state`, :func:`add_share_counts`,
 :func:`share_routing_stats`) are what a model's serve bodies keep on the
 device and its ``ServeModel.stats`` reads back.
@@ -140,7 +142,7 @@ def moe_ffn(
 
 class TopKRouting(NamedTuple):
     experts: jax.Array        # [T, k] int32, ids over ALL router outputs
-    gates: jax.Array          # [T, k] float32, scaling * p_e (not renormalised)
+    gates: jax.Array          # [T, k] float32, as the gate rule weighs them
 
 
 # Layout of the counters :func:`expert_share_ffn` returns, ``[4 + count]``:
@@ -149,33 +151,56 @@ class TopKRouting(NamedTuple):
 N_SHARE_TOTALS = 4
 
 
+def _route_scores(x: jax.Array, router_w: jax.Array) -> jax.Array:
+    """``float32(x) Wr`` at ``highest``: on a TPU a float32 product
+    otherwise runs in one bfloat16 pass, and a rounded score flips a
+    choice."""
+    return jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST)
+
+
+def _choose(scores: jax.Array, k: int, bias: Optional[jax.Array] = None
+            ) -> Tuple[jax.Array, jax.Array]:
+    """(ids ``[T, k]`` int32, their scores ``[T, k]``) of the top-k of
+    ``scores + bias``: a bias (a learned correction) moves the choice,
+    never the score that becomes the weight."""
+    if bias is None:
+        chosen, experts = lax.top_k(scores, k)
+    else:
+        _, experts = lax.top_k(scores + bias.astype(jnp.float32), k)
+        chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    return experts.astype(jnp.int32), chosen
+
+
 def topk_route(x: jax.Array, router_w: jax.Array, bias: jax.Array, k: int,
                scaling: float) -> TopKRouting:
     """``p = softmax(float32(x) Wr)`` over every router output; the ``k``
-    chosen are the top-k of ``p + bias`` (a learned correction that moves the
-    choice, never the weight); ``g_e = scaling * p_e``, not renormalised
-    over the chosen. float32 at ``highest``: on a TPU a float32 product
-    otherwise runs in one bfloat16 pass, and a rounded score flips a choice.
-    """
-    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
-    p = jax.nn.softmax(logits, axis=-1)
-    _, experts = lax.top_k(p + bias.astype(jnp.float32), k)
-    gates = jnp.take_along_axis(p, experts, axis=-1) * scaling
-    return TopKRouting(experts.astype(jnp.int32), gates)
+    chosen are the top-k of ``p + bias``; ``g_e = scaling * p_e``, not
+    renormalised over the chosen."""
+    p = jax.nn.softmax(_route_scores(x, router_w), axis=-1)
+    experts, chosen = _choose(p, k, bias)
+    return TopKRouting(experts, chosen * scaling)
 
 
 def topk_softmax_route(x: jax.Array, router_w: jax.Array, k: int
                        ) -> TopKRouting:
     """``l = float32(x) Wr``; the ``k`` chosen are the top-k of the logits
     ``l``; ``g = softmax(l_chosen)`` over the chosen alone, so a token's
-    gates sum to 1. float32 at ``highest``, for :func:`topk_route`'s
-    reason."""
-    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
-    chosen, experts = lax.top_k(logits, k)
-    return TopKRouting(experts.astype(jnp.int32),
-                       jax.nn.softmax(chosen, axis=-1))
+    gates sum to 1."""
+    experts, chosen = _choose(_route_scores(x, router_w), k)
+    return TopKRouting(experts, jax.nn.softmax(chosen, axis=-1))
+
+
+def topk_sigmoid_route(x: jax.Array, router_w: jax.Array, bias: jax.Array,
+                       k: int, scaling: float) -> TopKRouting:
+    """``s = sigmoid(float32(x) Wr)``, each output on its own; the ``k``
+    chosen are the top-k of ``s + bias``; ``g = scaling * s_chosen /
+    sum(s_chosen)``: renormalised over the chosen, so a token's gates sum to
+    ``scaling``."""
+    s = jax.nn.sigmoid(_route_scores(x, router_w))
+    experts, chosen = _choose(s, k, bias)
+    return TopKRouting(
+        experts, chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling)
 
 
 def share_gates(routing: TopKRouting, n_routed: int, first: int, count: int

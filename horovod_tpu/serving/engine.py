@@ -122,9 +122,8 @@ def serve_model(cfg: Any) -> ServeModel:
     if own is None:
         raise TypeError(
             f"serving needs a config with a serve_model() of its own (as "
-            f"TransformerConfig, LongCatFlashConfig and GraniteHybridConfig "
-            f"have), got "
-            f"{type(cfg).__name__}")
+            f"TransformerConfig, LongCatFlashConfig, GraniteHybridConfig and "
+            f"SolarOpen2Config have), got {type(cfg).__name__}")
     return own()
 
 

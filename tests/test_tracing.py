@@ -150,13 +150,20 @@ class TestSpans:
 
     def test_enabled_path_overhead_benchmark(self):
         trace.enable(buffer_spans=4096)
-        n = 2000
-        t0 = time.perf_counter()
-        for _ in range(n):
-            with trace.span("hot", cat="t"):
-                pass
-        per_op_us = (time.perf_counter() - t0) / n * 1e6
-        # ring-buffer append + two perf_counter reads; generous bound
+        n, batches = 400, 7
+
+        def batch_us():
+            t0 = time.perf_counter()
+            for _ in range(n):
+                with trace.span("hot", cat="t"):
+                    pass
+            return (time.perf_counter() - t0) / n * 1e6
+
+        # ring-buffer append + two perf_counter reads; generous bound. The
+        # LEAST of several batches: what a span costs, not what a core
+        # shared with five other test workers happened to add to one
+        # stretch of 2000 (the one failure of the tier-1 runs at PR 33 / 34)
+        per_op_us = min(batch_us() for _ in range(batches))
         assert per_op_us < 100.0, f"on-path span cost {per_op_us:.2f}us"
 
     def test_cross_thread_async_pair(self):

@@ -412,14 +412,21 @@ knobs.register("HOROVOD_ELASTIC_RESIZE_TIMEOUT", 60.0, float,
                     "OLD world — resize is retried at the next notice; "
                     "partial resizes never happen (the plan commits "
                     "atomically after the snapshot).")
-knobs.register("HOROVOD_FLASH_BLOCK_Q", 512, int,
-               help="Flash-attention Q block rows (Pallas kernel grid). "
-                    "Measured on v5e: 512/1024 beat the FlashAttention-"
-                    "paper-style 128/256 by 1.67x on the flagship LM step "
-                    "(per-grid-step overhead dominates at small blocks). "
-                    "Shrunk to the largest aligned divisor of the actual "
-                    "sequence length. Read at TRACE time — set before the "
-                    "first compile (not runtime-autotunable).")
+knobs.register("HOROVOD_FLASH_BLOCK_Q", 2048, int,
+               help="Flash-attention Q block rows (Pallas kernel grid): what "
+                    "one grid step holds in VMEM; the kernels cut it into "
+                    "compute tiles of their own (flash_attention._FWD_TILE, "
+                    "_BWD_TILE). Measured on v5e (PR 36; the three training "
+                    "kernels alone at [4, 2048, 16, 64] bfloat16, causal, "
+                    "ms a layer, Q x K blocks): 2048x1024 2.73, 1024x1024 "
+                    "2.88, 512x1024 3.02, 2048x512 3.21, 1024x512 3.45, "
+                    "512x512 3.55, 2048x2048 3.71 (3.73 before that PR, at "
+                    "512x1024): a grid step costs 0.3-0.7 us before its "
+                    "first pair, so blocks are large and the tiles inside "
+                    "them follow the diagonal. Shrunk to the largest "
+                    "lane-aligned divisor of the actual sequence length. "
+                    "Read at TRACE time — set before the first compile "
+                    "(not runtime-autotunable).")
 knobs.register("HOROVOD_FLASH_BLOCK_K", 1024, int,
                help="Flash-attention K/V block rows (see "
                     "HOROVOD_FLASH_BLOCK_Q).")

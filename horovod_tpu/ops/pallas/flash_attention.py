@@ -5,30 +5,55 @@ jnp fallback (``parallel/sequence._block_attend``) materializes a full
 ``[B, H, Sq, Sk]`` score matrix in HBM per ring step; this kernel keeps
 score tiles in VMEM, streaming K/V blocks through a pipelined grid
 dimension with the numerically-stable flash recurrence, so HBM traffic is
-O(Sq·D + Sk·D) instead of O(Sq·Sk) — and causally-dead K blocks are
-skipped entirely (≈2x on causal attention).
+O(Sq·D + Sk·D) instead of O(Sq·Sk). A grid step's block is cut into
+square compute tiles, and under the causal mask each tile is one of three
+kinds, told from the traced offsets: wholly above the diagonal (skipped),
+crossed by it (the body that builds the mask), wholly under it (the same
+body with no ``iota``, compare or select); ``causal_block_census`` counts
+them.
 
-Contract (identical to ``_block_attend``, so it drops into ring/local
-attention including the cross-shard merge): returns UNNORMALIZED
-``o = exp(s - m) @ v`` plus per-row stats ``m`` (running max) and ``l``
-(running sum), letting the caller merge partials across ring steps.
-Kernel structure follows the upstream pallas flash kernel
-(jax.experimental.pallas.ops.tpu.flash_attention): grid
-``(B·H, n_q, n_k)`` with VMEM scratch carrying (m, l, acc) across the
-``n_k`` (arbitrary-order) dimension, stats outputs padded to the 128-lane
-minimum block.
+Contract. ONE tile step for every entry point: grid ``(B·H, n_q, n_k)``
+with VMEM scratch carrying the running max ``m``, sum ``l`` and the
+accumulator across the ``n_k`` (arbitrary-order) dimension, the statistics
+as ``[block_q, 1]`` columns that broadcast in the arithmetic. Every product
+takes its operands in THEIR dtype and accumulates in float32: the
+probabilities are cast to the dtype of the tile they multiply (as
+``_block_attend`` casts ``p.astype(v.dtype)``), so bfloat16 inputs run
+bfloat16 products and float32 inputs float32 ones; ``exp``, ``m``, ``l``,
+``lse`` and ``dD`` are float32 always. (On the chip the cast moves no bit:
+at default precision the TPU compiler already rounds a float32 operand to
+bfloat16 for the matrix unit, one pass, so float32 ``p`` and ``dS`` against
+upcast tiles gave the same results and the same time, PERF.md §6 PR 36; the
+cast states the contract and keeps float32 copies of the tiles out of
+VMEM.) What a call writes when its last K
+block is done depends on who merges, so there are two finalisations:
+
+- ``flash_block_attend`` / ``flash_bwd_block`` (a partial block of ring
+  attention, whose caller merges across ring steps): UNNORMALIZED
+  ``o = exp(s - m) @ v`` in float32 plus the row statistics ``m`` and
+  ``l``, identical to ``_block_attend``; gradients in float32 (the ring
+  sums them over its steps).
+- ``flash_attention`` (the whole softmax, the training path): ``o = acc /
+  l`` in the input dtype and ONE statistic ``lse = m + log l``; its
+  backward kernels write dq, dk, dv in the input dtype.
+
+Row statistics travel between HBM and the kernels lane-dense, ``[B·H, 1,
+S]`` (a ``[1, block_q]`` row a block): the kernels that need a column turn
+the row in VMEM, the dk/dv kernel works on the transposed score tile
+``k·qᵀ`` where the row broadcasts as it lies.
 
 Offsets ``q_offset``/``k_offset`` position the local blocks in the global
 sequence for causal masking; they are traced scalars (ring step index ×
 shard length), shipped to the kernel through SMEM — this is what the
-upstream kernel lacks and ring attention needs.
+upstream kernel (jax.experimental.pallas.ops.tpu.flash_attention) lacks
+and ring attention needs.
 """
 
 from __future__ import annotations
 
 import functools
 import re
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +64,17 @@ _SMEM = pltpu.SMEM
 
 NEG_INF = -1e30
 _LANES = 128     # TPU lane width: min last-dim block size
+_NT = (((1,), (1,)), ((), ()))    # a @ b.T
+_NN = (((1,), (0,)), ((), ()))    # a @ b
+# Edge of a compute tile inside a grid step's block (PR 36, kernels alone on
+# v5e at [4, 2048, 16, 64] bfloat16): a tile is one straight-line body, and
+# the forward's, which carries the softmax statistics from tile to tile, is
+# fastest at 1024 (0.82 ms a layer; 1.17 at 512), the backward kernels',
+# which carry nothing, at 512 (dq 0.87, dkv 1.04; 1.07 and 1.21 on whole
+# 1024 blocks, 1.26 and 1.69 at 256): smaller tiles follow the diagonal
+# more closely and pay more per tile.
+_FWD_TILE = 1024
+_BWD_TILE = 512
 
 
 def _fit_block(s: int, cap: int, align: int):
@@ -58,18 +94,72 @@ def _resolve_blocks(s_q: int, s_k: int, block_q, block_k):
     if no aligned blocking exists — the ONE home of the resolution rule
     shared by the fwd/bwd entry points and supports(). Explicit arguments
     win; None picks the knob defaults; blocks shrink to the largest
-    aligned divisor of the actual lengths."""
+    aligned divisor of the actual lengths. Both are lane-aligned (a
+    statistic's ``[1, block_q]`` row is a block's last dimension), but a
+    short Sq of whole sublanes may be one block."""
     dbq, dbk = default_blocks()
-    return (_fit_block(s_q, block_q or dbq, 8),
+    cap_q = block_q or dbq
+    whole_q = s_q if s_q <= cap_q and s_q % 8 == 0 else None
+    return (_fit_block(s_q, cap_q, _LANES) or whole_q,
             _fit_block(s_k, block_k or dbk, _LANES))
 
 
+def _tile_runs(q_start, tq, k_start):
+    """A tile of ``tq`` queries from ``q_start`` has a visible pair under
+    the causal mask: its last query sees its first key, at ``k_start``
+    (ref: below_or_on_diag in the upstream kernel, generalized to
+    cross-shard offsets)."""
+    return q_start + tq - 1 >= k_start
+
+
+def _tile_unmasked(q_start, k_start, tk):
+    """Every pair of a tile is visible: its first query sees the last of
+    its ``tk`` keys, so the tile needs no mask."""
+    return q_start >= k_start + tk - 1
+
+
+def _tile_edge(blk: int, tile: Optional[int]) -> int:
+    """Edge of a compute tile along an axis of ``blk``: ``tile`` where it
+    divides the block, else the whole block."""
+    return tile if tile and blk % tile == 0 else blk
+
+
+def causal_block_census(s_q: int, s_k: int, block_q: Optional[int] = None,
+                        block_k: Optional[int] = None, q_offset: int = 0,
+                        k_offset: int = 0, tile: Optional[int] = None
+                        ) -> Dict[str, float]:
+    """What the causal kernels do for one head, by the predicates the
+    kernels use, counted in compute tiles (whole grid steps, or with
+    ``tile`` the squares a kernel cuts its block into: ``_FWD_TILE``,
+    ``_BWD_TILE``): ``skipped`` (wholly above the diagonal: no work),
+    ``masked`` (the diagonal crosses them: the body with the mask) and
+    ``unmasked`` (wholly under it: the body without), and
+    ``pairs_computed_over_needed``, the query-key pairs of the tiles that
+    run over the visible ones (1.0 where none is visible and none runs)."""
+    block_q, block_k = _resolve_blocks(s_q, s_k, block_q, block_k)
+    if block_q is None or block_k is None:
+        raise ValueError(f"no aligned blocking of Sq={s_q}, Sk={s_k}")
+    tq, tk = _tile_edge(block_q, tile), _tile_edge(block_k, tile)
+    count = {"skipped": 0, "masked": 0, "unmasked": 0}
+    for q_start in range(q_offset, q_offset + s_q, tq):
+        for k_start in range(k_offset, k_offset + s_k, tk):
+            if not _tile_runs(q_start, tq, k_start):
+                count["skipped"] += 1
+            elif _tile_unmasked(q_start, k_start, tk):
+                count["unmasked"] += 1
+            else:
+                count["masked"] += 1
+    needed = sum(min(max(q_offset + i - k_offset + 1, 0), s_k)
+                 for i in range(s_q))
+    computed = (count["masked"] + count["unmasked"]) * tq * tk
+    return {**count, "pairs_computed_over_needed":
+            computed / needed if needed else 1.0}
+
+
 def default_blocks() -> Tuple[int, int]:
-    """(block_q, block_k) from the knobs. Measured on v5e (PERF.md r5):
-    512/1024 cut the flagship TransformerLM step from 348 ms to 209 ms
-    (+67% tok/s) vs the original 128/256 — per-grid-step overhead
-    dominates at small blocks; the min()-clamp in the entry points keeps
-    short sequences valid."""
+    """(block_q, block_k) from the knobs (their help text holds the chip
+    measurements behind the defaults); the entry points shrink them to a
+    divisor of short sequences."""
     try:
         from horovod_tpu.config import knobs
         return (int(knobs.get("HOROVOD_FLASH_BLOCK_Q")),
@@ -77,16 +167,55 @@ def default_blocks() -> Tuple[int, int]:
     except (ImportError, KeyError):  # pragma: no cover - config absent
         # Parse errors in user-set values must SURFACE, not silently
         # fall back — only a missing config module uses the defaults.
-        return 512, 1024
+        return 2048, 1024
 
 
-def _kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-            m_scr, l_scr, acc_scr, *, causal: bool, scale: float):
-    blk_q, d = q_ref.shape[1], q_ref.shape[2]
-    blk_k = k_ref.shape[1]
-    qi = pl.program_id(1)
+def _causal_tiles(qoff_ref, koff_ref, qi, kb, blk_q: int, blk_k: int,
+                  causal: bool, q_axis: int, step: Callable,
+                  tile: Optional[int] = None) -> None:
+    """Run ``step(visible, q_rows, k_rows)`` for every compute tile of grid
+    step ``(qi, kb)`` (the whole block, or its ``tile``-edged squares;
+    ``q_rows`` / ``k_rows`` are the tile's static slices of the block): not
+    at all where no pair of the tile is visible, with ``visible=None``
+    where every pair is, and with ``visible()`` -> the boolean tile of
+    visible pairs (queries along ``q_axis``) only where the diagonal
+    crosses it. Offsets are the traced global positions of the call's
+    first query and key."""
+    tq, tk = _tile_edge(blk_q, tile), _tile_edge(blk_k, tile)
+    for r in range(blk_q // tq):
+        for c in range(blk_k // tk):
+            rows = slice(r * tq, (r + 1) * tq)
+            cols = slice(c * tk, (c + 1) * tk)
+            if not causal:
+                step(None, rows, cols)
+                continue
+            q_start = qoff_ref[0] + qi * blk_q + r * tq
+            k_start = koff_ref[0] + kb * blk_k + c * tk
+            unmasked = _tile_unmasked(q_start, k_start, tk)
+
+            def visible(q_start=q_start, k_start=k_start):
+                shape = (tq, tk) if q_axis == 0 else (tk, tq)
+                ahead = (
+                    jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+                    - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
+                return ahead >= k_start - q_start   # q position >= k position
+
+            pl.when(unmasked)(
+                functools.partial(step, None, rows, cols))
+            pl.when(jnp.logical_and(_tile_runs(q_start, tq, k_start),
+                                    jnp.logical_not(unmasked)))(
+                functools.partial(step, visible, rows, cols))
+
+
+def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, *refs,
+                causal: bool, scale: float, normalize: bool,
+                tile: Optional[int]):
+    """``normalize``: the whole-softmax finalisation (``o / l`` in the
+    input dtype, ``lse``) against the partial block's (``o``, ``m``,
+    ``l``); the tile step is the same."""
+    outs, (m_scr, l_scr, acc_scr) = refs[:-3], refs[-3:]
+    blk_q, blk_k = q_ref.shape[1], k_ref.shape[1]
     kb = pl.program_id(2)
-    n_k = pl.num_programs(2)
 
     @pl.when(kb == 0)
     def _init():
@@ -94,62 +223,119 @@ def _kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    q_start = qoff_ref[0] + qi * blk_q        # global positions (traced)
-    k_start = koff_ref[0] + kb * blk_k
-    # Causal block skip: the whole K block is in the future of every Q row
-    # iff q_start + blk_q - 1 < k_start (ref: below_or_on_diag in the
-    # upstream kernel, generalized to cross-shard offsets).
-    should_run = (q_start + blk_q - 1 >= k_start) if causal else True
-
-    @pl.when(should_run)
-    def _run():
-        # Tiles arrive in the model's native dtype (bf16 HBM traffic, bf16
-        # MXU fast path for q.kT); only f32-accumulated intermediates are
-        # cast, in VMEM.
-        q = q_ref[0]                           # [blk_q, D] native dtype
-        k = k_ref[0]                           # [blk_k, D] native dtype
+    def step(visible, rows, cols):
+        # Tiles arrive in the model's native dtype and go to the MXU as
+        # they are; scores and statistics are float32.
+        v = v_ref[0, cols, :]                          # [tile_k, D]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q_ref[0, rows, :], k_ref[0, cols, :], _NT,
             preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_k), 0)
-            cols = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+        m_prev = m_scr[rows, :]                        # [tile_q, 1]
+        if visible is None:
+            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            shift = m_next
+        else:
+            s = jnp.where(visible(), s, NEG_INF)
+            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # A row that has seen no key yet has m = NEG_INF, and
+            # exp(NEG_INF - NEG_INF) = 1 would attend uniformly: shift
+            # such a row by 0 so that its masked scores give exp = 0
+            # (the guard of the jnp fallback, on the column).
+            shift = jnp.where(m_next <= NEG_INF / 2, 0.0, m_next)
+        p = jnp.exp(s - shift)
+        # first visible tile of a row: exp(NEG_INF - m) = 0 drops the
+        # empty accumulator; a row still unseen keeps its zeros (1 * 0)
+        alpha = jnp.exp(m_prev - m_next)
+        l_scr[rows, :] = (l_scr[rows, :] * alpha
+                          + jnp.sum(p, axis=1, keepdims=True))
+        m_scr[rows, :] = m_next
+        acc_scr[rows, :] = acc_scr[rows, :] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
 
-        m_prev = m_scr[...]                    # [blk_q, LANES]
-        l_prev = l_scr[...]
-        m_curr = jnp.max(s, axis=1)[:, None]   # [blk_q, 1]
-        m_next = jnp.maximum(m_prev, m_curr)   # [blk_q, LANES]
-        reps = blk_k // _LANES
-        p = jnp.exp(s - jnp.tile(m_next, (1, reps)))
-        # Fully-masked rows: exp(NEG_INF - NEG_INF) = 1 would attend
-        # uniformly; zero them (same guard as the jnp fallback).
-        p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-        alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0,
-                          jnp.exp(m_prev - m_next))
-        l_scr[...] = l_prev * alpha + jnp.sum(p, axis=1)[:, None]
-        m_scr[...] = m_next
+    _causal_tiles(qoff_ref, koff_ref, pl.program_id(1), kb, blk_q, blk_k,
+                  causal, 0, step, tile)
 
-        v = v_ref[0].astype(jnp.float32)       # [blk_k, D]
-        d_reps = max(d // _LANES, 1)
-        a_scale = (jnp.tile(alpha, (1, d_reps)) if d >= _LANES
-                   else alpha[:, :d])
-        acc_scr[...] = acc_scr[...] * a_scale + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(kb == n_k - 1)
+    @pl.when(kb == pl.num_programs(2) - 1)
     def _finalize():
-        o_ref[0] = acc_scr[...]
-        m_ref[0] = m_scr[...]
-        l_ref[0] = l_scr[...]
+        if normalize:
+            o_ref, lse_ref = outs
+            l = jnp.maximum(l_scr[...], 1e-30)   # a row with no key: o = 0
+            o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+            lse_ref[0] = (m_scr[...] + jnp.log(l)).T
+        else:
+            o_ref, m_ref, l_ref = outs
+            o_ref[0] = acc_scr[...]
+            m_ref[0] = m_scr[...].T
+            l_ref[0] = l_scr[...].T
+
+
+def _heads_first(x: jax.Array) -> jax.Array:
+    """[B, S, H, D] -> [B*H, S, D], native dtype: the layout change is one
+    pass; no f32 upcast copies in HBM."""
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
+def _blocks_or_raise(s_q: int, s_k: int, block_q, block_k, what: str):
+    block_q, block_k = _resolve_blocks(s_q, s_k, block_q, block_k)
+    if block_q is None or block_k is None:
+        raise ValueError(
+            f"flash {what} cannot block shapes Sq={s_q}, Sk={s_k} "
+            f"(gate dispatch with supports())")
+    return block_q, block_k
+
+
+def _compiler_kwargs(interpret: bool) -> dict:
+    if interpret:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))}
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("causal", "scale", "block_q", "block_k", "interpret"))
+    jax.jit, static_argnames=("causal", "scale", "block_q", "block_k",
+                              "interpret", "normalize", "tile"))
+def _attend(q, k, v, q_offset, k_offset, causal: bool, scale: float,
+            block_q, block_k, interpret: bool, normalize: bool,
+            tile: Optional[int] = _FWD_TILE):
+    """The forward kernel over q/k/v ``[B, S, H, D]``: ``normalize`` ->
+    (o in the input dtype, lse ``[B, H, Sq]``), else (o unnormalized
+    float32, m, l)."""
+    b, s_q, h, d = q.shape
+    s_k = k.shape[1]
+    block_q, block_k = _blocks_or_raise(s_q, s_k, block_q, block_k,
+                                        "kernel")
+    qoff = jnp.asarray(q_offset, jnp.int32).reshape(1)
+    koff = jnp.asarray(k_offset, jnp.int32).reshape(1)
+    q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi, kb: (bh, qi, 0))
+    kv_spec = pl.BlockSpec((1, block_k, d), lambda bh, qi, kb: (bh, kb, 0))
+    stat_spec = pl.BlockSpec((1, 1, block_q), lambda bh, qi, kb: (bh, 0, qi))
+    stat = jax.ShapeDtypeStruct((b * h, 1, s_q), jnp.float32)
+    n_stats = 1 if normalize else 2               # lse, or m and l
+    o, *stats = pl.pallas_call(
+        functools.partial(_fwd_kernel, causal=causal, scale=float(scale),
+                          normalize=normalize, tile=tile),
+        name="hvd_flash_fwd",
+        grid=(b * h, s_q // block_q, s_k // block_k),
+        in_specs=[pl.BlockSpec(memory_space=_SMEM),
+                  pl.BlockSpec(memory_space=_SMEM),
+                  q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec] + [stat_spec] * n_stats,
+        out_shape=[jax.ShapeDtypeStruct(
+            (b * h, s_q, d), q.dtype if normalize else jnp.float32)]
+        + [stat] * n_stats,
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 1), jnp.float32),        # m
+            pltpu.VMEM((block_q, 1), jnp.float32),        # l
+            pltpu.VMEM((block_q, d), jnp.float32),        # acc
+        ],
+        interpret=interpret,
+        **_compiler_kwargs(interpret),
+    )(qoff, koff, _heads_first(q), _heads_first(k), _heads_first(v))
+    o = o.reshape(b, h, s_q, d).transpose(0, 2, 1, 3)     # [B, Sq, H, D]
+    return (o, *(x.reshape(b, h, s_q) for x in stats))
+
+
 def flash_block_attend(
     q: jax.Array, k: jax.Array, v: jax.Array,
     q_offset, k_offset,
@@ -158,192 +344,114 @@ def flash_block_attend(
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Flash form of ``_block_attend``: q/k/v ``[B, S, H, D]`` →
-    (o ``[B, Sq, H, D]`` unnormalized, m ``[B, H, Sq]``, l ``[B, H, Sq]``).
+    (o ``[B, Sq, H, D]`` unnormalized float32, m ``[B, H, Sq]``,
+    l ``[B, H, Sq]``) for the caller to merge.
     Shapes must divide the block sizes (``supports()`` gates dispatch)."""
-    b, s_q, h, d = q.shape
-    s_k = k.shape[1]
-    block_q, block_k = _resolve_blocks(s_q, s_k, block_q, block_k)
-    if block_q is None or block_k is None:
-        raise ValueError(
-            f"flash kernel cannot block shapes Sq={s_q}, Sk={s_k} "
-            f"(gate dispatch with supports())")
-    # [B, S, H, D] -> [B*H, S, D], native dtype: the layout change is one
-    # pass; no f32 upcast copies in HBM (casting happens per-tile in VMEM).
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, s_q, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, s_k, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, s_k, d)
-    qoff = jnp.asarray(q_offset, jnp.int32).reshape(1)
-    koff = jnp.asarray(k_offset, jnp.int32).reshape(1)
-
-    grid = (b * h, s_q // block_q, s_k // block_k)
-    kernel = functools.partial(_kernel, causal=causal, scale=float(scale))
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    o, m, l = pl.pallas_call(
-        kernel,
-        name="hvd_flash_fwd",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=_SMEM),
-            pl.BlockSpec(memory_space=_SMEM),
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, kb: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, kb: (bh, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, kb: (bh, kb, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, kb: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda bh, qi, kb: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda bh, qi, kb: (bh, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, s_q, d), jnp.float32),
-            jax.ShapeDtypeStruct((b * h, s_q, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((b * h, s_q, _LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),   # m
-            pltpu.VMEM((block_q, _LANES), jnp.float32),   # l
-            pltpu.VMEM((block_q, d), jnp.float32),        # acc
-        ],
-        interpret=interpret,
-        **kwargs,
-    )(qoff, koff, qf, kf, vf)
-
-    o = o.reshape(b, h, s_q, d).transpose(0, 2, 1, 3)     # [B, Sq, H, D]
-    m = m[:, :, 0].reshape(b, h, s_q)
-    l = l[:, :, 0].reshape(b, h, s_q)
-    return o, m, l
+    return _attend(q, k, v, q_offset, k_offset, causal=causal,
+                   scale=float(scale), block_q=block_q, block_k=block_k,
+                   interpret=interpret, normalize=False)
 
 
 # ---------------------------------------------------------------------------
 # Differentiable full attention (custom VJP with pallas backward kernels).
 #
-# The block-level API above is forward-only (pallas_call has no automatic
-# AD); training paths use `flash_attention`, whose backward pass runs two
-# pallas kernels implementing the standard flash-attention gradients:
+# pallas_call has no automatic AD; training paths use `flash_attention`,
+# whose backward pass runs two pallas kernels implementing the standard
+# flash-attention gradients:
 #   P_ij  = exp(S_ij - L_i)          (L = rowwise logsumexp, saved fwd)
 #   dv_j  = sum_i P_ij do_i
 #   dS_ij = P_ij (do_i . v_j - D_i)  (D = rowsum(do * o), computed outside)
 #   dq_i  = scale * sum_j dS_ij k_j
 #   dk_j  = scale * sum_i dS_ij q_i
 # Each backward kernel recomputes its S tile in VMEM — no O(Sq*Sk) HBM
-# residuals, same causal block-skip as the forward.
+# residuals, the same three kinds of tile as the forward. The dq
+# kernel sums over K blocks with L and D as columns; the dk/dv kernel sums
+# over Q blocks on the TRANSPOSED tile S^T = k q^T, where L and D broadcast
+# as the rows they arrive as and both sums are plain products (no
+# transposed operand).
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, l_ref,
-                   d_ref, dq_ref, dq_scr, *, causal: bool, scale: float):
-    blk_q, d = q_ref.shape[1], q_ref.shape[2]
-    blk_k = k_ref.shape[1]
-    qi = pl.program_id(1)
+def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                   dd_ref, dq_ref, dq_scr, lse_scr, dd_scr,
+                   *, causal: bool, scale: float, tile: Optional[int]):
+    blk_q, blk_k = q_ref.shape[1], k_ref.shape[1]
     kb = pl.program_id(2)
-    n_k = pl.num_programs(2)
 
     @pl.when(kb == 0)
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
+        lse_scr[...] = lse_ref[0].T           # [1, blk_q] -> [blk_q, 1]
+        dd_scr[...] = dd_ref[0].T
 
-    q_start = qoff_ref[0] + qi * blk_q
-    k_start = koff_ref[0] + kb * blk_k
-    should_run = (q_start + blk_q - 1 >= k_start) if causal else True
-
-    @pl.when(should_run)
-    def _run():
-        q = q_ref[0]                  # native dtype (bf16 MXU fast path)
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        reps = blk_k // _LANES
+    def step(visible, rows, cols):
+        k = k_ref[0, cols, :]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q_ref[0, rows, :], k, _NT,
             preferred_element_type=jnp.float32) * scale
-        p = jnp.exp(s - jnp.tile(l_ref[0], (1, reps)))
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_k), 0)
-            cols = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_k), 1)
-            p = jnp.where(rows >= cols, p, 0.0)
+        p = jnp.exp(s - lse_scr[rows, :])
+        if visible is not None:
+            p = jnp.where(visible(), p, 0.0)
         dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [blk_q, blk_k]
-        ds = p * (dp - jnp.tile(d_ref[0], (1, reps)))
-        dq_scr[...] += jax.lax.dot_general(
-            ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            do_ref[0, rows, :], v_ref[0, cols, :], _NT,
+            preferred_element_type=jnp.float32)       # [tile_q, tile_k]
+        ds = p * (dp - dd_scr[rows, :])
+        dq_scr[rows, :] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
 
-    @pl.when(kb == n_k - 1)
+    _causal_tiles(qoff_ref, koff_ref, pl.program_id(1), kb, blk_q, blk_k,
+                  causal, 0, step, tile)
+
+    @pl.when(kb == pl.num_programs(2) - 1)
     def _finalize():
-        dq_ref[0] = dq_scr[...] * scale
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, l_ref,
-                    d_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, causal: bool, scale: float):
-    blk_q = q_ref.shape[1]
-    blk_k = k_ref.shape[1]
-    kb = pl.program_id(1)
+def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                    dd_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+                    *, causal: bool, scale: float, tile: Optional[int]):
+    blk_q, blk_k = q_ref.shape[1], k_ref.shape[1]
     qi = pl.program_id(2)
-    n_q = pl.num_programs(2)
 
     @pl.when(qi == 0)
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    q_start = qoff_ref[0] + qi * blk_q
-    k_start = koff_ref[0] + kb * blk_k
-    should_run = (q_start + blk_q - 1 >= k_start) if causal else True
+    def step(visible, rows, cols):
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
+        st = jax.lax.dot_general(
+            k_ref[0, cols, :], q, _NT,
+            preferred_element_type=jnp.float32) * scale   # [tile_k, tile_q]
+        pt = jnp.exp(st - lse_ref[0, :, rows])            # row [1, tile_q]
+        if visible is not None:
+            pt = jnp.where(visible(), pt, 0.0)
+        dv_scr[cols, :] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, _NN,
+            preferred_element_type=jnp.float32)           # [tile_k, D]
+        dpt = jax.lax.dot_general(
+            v_ref[0, cols, :], do, _NT, preferred_element_type=jnp.float32)
+        dst = pt * (dpt - dd_ref[0, :, rows])
+        dk_scr[cols, :] += jax.lax.dot_general(
+            dst.astype(q.dtype), q, _NN,
+            preferred_element_type=jnp.float32)           # [tile_k, D]
 
-    @pl.when(should_run)
-    def _run():
-        q = q_ref[0]                  # native dtype (bf16 MXU fast path)
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        reps = blk_k // _LANES
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        p = jnp.exp(s - jnp.tile(l_ref[0], (1, reps)))
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_k), 0)
-            cols = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_k), 1)
-            p = jnp.where(rows >= cols, p, 0.0)
-        dv_scr[...] += jax.lax.dot_general(
-            p, do.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [blk_k, D]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - jnp.tile(d_ref[0], (1, reps)))
-        dk_scr[...] += jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [blk_k, D]
+    _causal_tiles(qoff_ref, koff_ref, qi, pl.program_id(1), blk_q, blk_k,
+                  causal, 1, step, tile)
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(qi == pl.num_programs(2) - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[...] * scale
-        dv_ref[0] = dv_scr[...]
-
-
-def _lane_pad(x: jax.Array) -> jax.Array:
-    """[BH, S] row stats -> [BH, S, LANES] broadcast for lane-aligned
-    pallas input blocks."""
-    return jnp.broadcast_to(x[:, :, None], x.shape + (_LANES,))
+        dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=True, scale=None,
                     block_q=None, block_k=None, interpret=False):
     """Differentiable normalized flash attention, full-sequence case
-    (q/k/v ``[B, S, H, D]`` -> ``[B, S, H, D]``). The training-path entry:
-    forward = flash kernel, backward = pallas dq/dkv kernels."""
+    (q/k/v ``[B, S, H, D]`` -> ``[B, S, H, D]`` in the input dtype). The
+    training-path entry: forward = flash kernel, backward = pallas dq/dkv
+    kernels, every result written by its kernel in its final form."""
     out, _ = _flash_attention_fwd(q, k, v, causal, scale, block_q, block_k,
                                   interpret)
     return out
@@ -353,18 +461,79 @@ def _flash_attention_fwd(q, k, v, causal, scale, block_q, block_k,
                          interpret):
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    o_un, m, l = flash_block_attend(q, k, v, 0, 0, causal=causal,
-                                    scale=float(scale), block_q=block_q,
-                                    block_k=block_k, interpret=interpret)
-    l_safe = jnp.maximum(l, 1e-30)
-    o = (o_un / jnp.moveaxis(l_safe, 1, -1)[..., None]).astype(q.dtype)
-    lse = m + jnp.log(l_safe)                    # [B, H, S]
+    o, lse = _attend(q, k, v, 0, 0, causal=causal, scale=float(scale),
+                     block_q=block_q, block_k=block_k, interpret=interpret,
+                     normalize=True)
     return o, (q, k, v, o, lse)
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("causal", "scale", "block_q", "block_k", "interpret"))
+    jax.jit, static_argnames=("causal", "scale", "block_q", "block_k",
+                              "interpret", "grad_dtype", "tile"))
+def _attend_bwd(q, k, v, do, lse, dD, q_offset, k_offset, causal: bool,
+                scale: float, block_q, block_k, interpret: bool, grad_dtype,
+                tile: Optional[int] = _BWD_TILE):
+    """The two backward kernels; dq, dk, dv ``[B, S, H, D]`` written in
+    ``grad_dtype``."""
+    b, s_q, h, d = q.shape
+    s_k = k.shape[1]
+    block_q, block_k = _blocks_or_raise(s_q, s_k, block_q, block_k,
+                                        "backward")
+    # Native dtype into the kernels (see fwd); statistics as rows.
+    operands = (jnp.asarray(q_offset, jnp.int32).reshape(1),
+                jnp.asarray(k_offset, jnp.int32).reshape(1),
+                _heads_first(q), _heads_first(k), _heads_first(v),
+                _heads_first(do.astype(q.dtype)),
+                lse.astype(jnp.float32).reshape(b * h, 1, s_q),
+                dD.astype(jnp.float32).reshape(b * h, 1, s_q))
+    smem = pl.BlockSpec(memory_space=_SMEM)
+
+    def specs(q_index, k_index, stat_index):
+        q_spec = pl.BlockSpec((1, block_q, d), q_index)
+        kv_spec = pl.BlockSpec((1, block_k, d), k_index)
+        stat_spec = pl.BlockSpec((1, 1, block_q), stat_index)
+        return q_spec, kv_spec, [smem, smem, q_spec, kv_spec, kv_spec,
+                                 q_spec, stat_spec, stat_spec]
+
+    q_spec, _, in_specs = specs(lambda bh, qi, kb: (bh, qi, 0),
+                                lambda bh, qi, kb: (bh, kb, 0),
+                                lambda bh, qi, kb: (bh, 0, qi))
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, causal=causal,
+                          scale=float(scale), tile=tile),
+        name="hvd_flash_bwd_dq",
+        grid=(b * h, s_q // block_q, s_k // block_k),
+        in_specs=in_specs,
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), grad_dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),   # lse
+                        pltpu.VMEM((block_q, 1), jnp.float32)],  # dD
+        interpret=interpret,
+        **_compiler_kwargs(interpret),
+    )(*operands)
+
+    _, kv_spec, in_specs = specs(lambda bh, kb, qi: (bh, qi, 0),
+                                 lambda bh, kb, qi: (bh, kb, 0),
+                                 lambda bh, kb, qi: (bh, 0, qi))
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, causal=causal,
+                          scale=float(scale), tile=tile),
+        name="hvd_flash_bwd_dkv",
+        grid=(b * h, s_k // block_k, s_q // block_q),
+        in_specs=in_specs,
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct((b * h, s_k, d), grad_dtype)] * 2,
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        interpret=interpret,
+        **_compiler_kwargs(interpret),
+    )(*operands)
+
+    unflat = lambda x, s: x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    return unflat(dq, s_q), unflat(dk, s_k), unflat(dv, s_k)
+
+
 def flash_bwd_block(q, k, v, do, lse, dD, q_offset, k_offset,
                     causal: bool, scale: float,
                     block_q: Optional[int] = None,
@@ -376,91 +545,12 @@ def flash_bwd_block(q, k, v, do, lse, dD, q_offset, k_offset,
     ``[B, H, Sq]``. Offsets are traced scalars, as in the forward —
     this is the building block of the ring-attention backward pass
     (each ring step differentiates its K/V block in place).
-    Returns (dq [B,Sq,H,D], dk [B,Sk,H,D], dv [B,Sk,H,D]) in f32."""
-    b, s_q, h, d = q.shape
-    s_k = k.shape[1]
-    block_q, block_k = _resolve_blocks(s_q, s_k, block_q, block_k)
-    if block_q is None or block_k is None:
-        raise ValueError(
-            f"flash backward cannot block shapes Sq={s_q}, Sk={s_k} "
-            f"(gate dispatch with supports())")
-
-    # Native dtype into the kernels (see fwd); casts happen per-tile.
-    do = do.astype(q.dtype)
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, s_q, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, s_k, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, s_k, d)
-    dof = do.transpose(0, 2, 1, 3).reshape(b * h, s_q, d)
-    lsef = lse.astype(jnp.float32).reshape(b * h, s_q)
-    dDf = dD.astype(jnp.float32).reshape(b * h, s_q)
-    l_pad = _lane_pad(lsef)
-    d_pad = _lane_pad(dDf)
-    qoff = jnp.asarray(q_offset, jnp.int32).reshape(1)
-    koff = jnp.asarray(k_offset, jnp.int32).reshape(1)
-
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal,
-                          scale=float(scale)),
-        name="hvd_flash_bwd_dq",
-        grid=(b * h, s_q // block_q, s_k // block_k),
-        in_specs=[
-            pl.BlockSpec(memory_space=_SMEM),
-            pl.BlockSpec(memory_space=_SMEM),
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, kb: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, kb: (bh, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, kb: (bh, kb, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, kb: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda bh, qi, kb: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda bh, qi, kb: (bh, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d),
-                               lambda bh, qi, kb: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-        **kwargs,
-    )(qoff, koff, qf, kf, vf, dof, l_pad, d_pad)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal=causal,
-                          scale=float(scale)),
-        name="hvd_flash_bwd_dkv",
-        grid=(b * h, s_k // block_k, s_q // block_q),
-        in_specs=[
-            pl.BlockSpec(memory_space=_SMEM),
-            pl.BlockSpec(memory_space=_SMEM),
-            pl.BlockSpec((1, block_q, d), lambda bh, kb, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, kb, qi: (bh, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, kb, qi: (bh, kb, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, kb, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda bh, kb, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda bh, kb, qi: (bh, qi, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, kb, qi: (bh, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, kb, qi: (bh, kb, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, s_k, d), jnp.float32),
-            jax.ShapeDtypeStruct((b * h, s_k, d), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=interpret,
-        **kwargs,
-    )(qoff, koff, qf, kf, vf, dof, l_pad, d_pad)
-
-    unflat = lambda x, s: x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
-    return unflat(dq, s_q), unflat(dk, s_k), unflat(dv, s_k)
+    Returns (dq [B,Sq,H,D], dk [B,Sk,H,D], dv [B,Sk,H,D]) in f32, for the
+    ring to sum over its steps."""
+    return _attend_bwd(q, k, v, do, lse, dD, q_offset, k_offset,
+                       causal=causal, scale=float(scale), block_q=block_q,
+                       block_k=block_k, interpret=interpret,
+                       grad_dtype=jnp.float32)
 
 
 def _flash_attention_bwd(causal, scale, block_q, block_k, interpret,
@@ -470,10 +560,9 @@ def _flash_attention_bwd(causal, scale, block_q, block_k, interpret,
         scale = q.shape[-1] ** -0.5
     dD = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                  axis=-1).transpose(0, 2, 1)            # [B, H, Sq]
-    dq, dk, dv = flash_bwd_block(
-        q, k, v, do, lse, dD, 0, 0, causal=causal, scale=float(scale),
-        block_q=block_q, block_k=block_k, interpret=interpret)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return _attend_bwd(q, k, v, do, lse, dD, 0, 0, causal=causal,
+                       scale=float(scale), block_q=block_q, block_k=block_k,
+                       interpret=interpret, grad_dtype=q.dtype)
 
 
 flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)

@@ -748,8 +748,7 @@ class ServeEngine:
         if tokens is not None:
             return self.drain("direct")
         if late is not None:
-            with trace.span("engine.decode.wait", cat=trace.CAT_SERVE):
-                np.asarray(late)
+            self._read(late)
         return nxt
 
     def drain(self, reason: str) -> Optional[np.ndarray]:
@@ -761,7 +760,21 @@ class ServeEngine:
         if late is None:
             return None
         self._count_drain(reason)
+        return self._read(late)
+
+    def _read(self, late: jax.Array) -> np.ndarray:
+        """A decode step's tokens on the host (``engine.decode.wait``).
+        While somebody reads the spans the two halves are told apart:
+        ``.ready`` is the device not done yet, ``.copy`` the copy to the
+        host and this thread's wake-up."""
         with trace.span("engine.decode.wait", cat=trace.CAT_SERVE):
+            if trace.active():
+                with trace.span("engine.decode.wait.ready",
+                                cat=trace.CAT_SERVE):
+                    late.block_until_ready()
+                with trace.span("engine.decode.wait.copy",
+                                cat=trace.CAT_SERVE):
+                    return np.asarray(late)
             return np.asarray(late)
 
     def _count_drain(self, reason: str) -> None:

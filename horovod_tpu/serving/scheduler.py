@@ -406,23 +406,26 @@ class ServeScheduler:
             return 0
         tokens, step, firsts = self._in_flight
         self._in_flight = None
-        for req in firsts:
-            self._see_first(req)
-        if tokens is None:
-            return 0
-        nxt = np.asarray(tokens)
-        t = time.perf_counter()
-        seen = 0
-        for slot, req in step:
-            if req.ended:
-                continue
-            dt = t - req._last_token_t
-            req.tokens.append(int(nxt[slot]))
-            req.tpot.append(dt)
-            req._last_token_t = t
-            self._m["tpot"].observe(dt)
-            self._m["tokens"].labels(kind="decode").inc()
-            seen += 1
+        attrs = _span_attrs()
+        with trace.span("serve.see", cat=trace.CAT_SERVE, attrs=attrs):
+            for req in firsts:
+                self._see_first(req)
+            seen = 0
+            if tokens is not None:
+                nxt = np.asarray(tokens)
+                t = time.perf_counter()
+                for slot, req in step:
+                    if req.ended:
+                        continue
+                    dt = t - req._last_token_t
+                    req.tokens.append(int(nxt[slot]))
+                    req.tpot.append(dt)
+                    req._last_token_t = t
+                    self._m["tpot"].observe(dt)
+                    self._m["tokens"].labels(kind="decode").inc()
+                    seen += 1
+            if attrs is not None:
+                attrs.update(tokens=seen + len(firsts), firsts=len(firsts))
         return seen
 
     def _decode(self) -> None:
@@ -529,12 +532,19 @@ class ServeScheduler:
                   "active": len(self.active)}
                  if trace.enabled() else None)
         with trace.span("serve.cycle", cat=trace.CAT_SERVE, attrs=attrs):
+            if attrs is not None:
+                cpu0, gc0 = time.thread_time(), trace.gc_us()
             self._retire(now)
             self._admit(now)
             self._prefill_cycle()
             self._retire(time.perf_counter())
             self._decode()
             self._retire(time.perf_counter())
+            if attrs is not None:
+                # wall less CPU less the waits beneath: the time this
+                # thread stood off its core or behind the interpreter lock
+                attrs["cpu_ms"] = (time.thread_time() - cpu0) * 1e3
+                attrs["gc_ms"] = (trace.gc_us() - gc0) * 1e-3
 
     def run(self, traffic=None) -> List[Request]:
         """Drive cycles until ``traffic`` is exhausted and every request

@@ -35,11 +35,13 @@ from horovod_tpu.tracing.spans import (  # noqa: F401
     CAT_COORDINATOR,
     CAT_DATA,
     CAT_ELASTIC,
+    CAT_HOST,
     CAT_PREEMPTION,
     CAT_SERVE,
     CAT_TIMELINE,
     CAT_TRAIN,
     CAT_WAIT,
+    active,
     begin_async,
     disable,
     dump_flight_recording,
@@ -47,7 +49,7 @@ from horovod_tpu.tracing.spans import (  # noqa: F401
     enabled,
     end_async,
     epoch_unix,
-    export_chrome_trace,
+    gc_us,
     init_from_env,
     instant,
     record,

@@ -16,6 +16,12 @@ active, ``span()`` hands back that bare annotation: no ring, no state,
 nothing exported at shutdown. This module is the one place that talks
 to the profiler.
 
+The interpreter's garbage collections are spans too (``host.gc.gen0`` /
+``gen1`` / ``gen2``, from one ``gc.callbacks`` hook that ``enable()`` and
+``init_from_env()`` put in once): a pause of the host thread, and an idle
+gap of the device over it, carries the collector's name on either sink.
+With both sinks off the hook returns at its first line.
+
 The OFF path is the contract: ``span()`` with ``HOROVOD_TRACE=0`` and no
 profiler session returns a module-level no-op context-manager singleton
 — no object, dict, or tuple is allocated, and the only cost is one
@@ -32,6 +38,7 @@ merge (tracing/merge.py) can shift hosts onto one timeline.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
@@ -59,6 +66,7 @@ CAT_DATA = "data"
 CAT_TRAIN = "train"
 CAT_TIMELINE = "timeline"
 CAT_SERVE = "serve"
+CAT_HOST = "host"
 
 # Prefix of every span's name on the profiler's host plane.
 PROFILER_PREFIX = "hvd."
@@ -72,7 +80,7 @@ class _State:
 
     __slots__ = ("enabled", "buffer", "capacity", "trace_id", "epoch_perf",
                  "epoch_unix", "lock", "open_async", "open_spans",
-                 "dropped")
+                 "dropped", "gc_open", "gc_us")
 
     def __init__(self):
         self.enabled = False
@@ -92,6 +100,11 @@ class _State:
         # has not exited yet (GIL-atomic dict set/pop, no lock).
         self.open_spans: Dict[int, Any] = {}
         self.dropped = 0
+        # the collection under way, (annotation, start_us): the
+        # interpreter runs one at a time and calls the hook under its
+        # lock; and the microseconds of all that ended with the recorder on
+        self.gc_open: Any = None
+        self.gc_us = 0.0
 
 
 _state = _State()
@@ -109,11 +122,19 @@ def enabled() -> bool:
     return _state.enabled
 
 
+def active() -> bool:
+    """Whether a :func:`span` opened now goes anywhere: the recorder or a
+    JAX profiler session is on. Guards work done only to be timed (the
+    engine's split of a readback into wait and copy)."""
+    return _state.enabled or _profiling()
+
+
 def enable(buffer_spans: Optional[int] = None,
            trace_id: Optional[str] = None) -> None:
     """Turn the recorder on (idempotent). A fresh trace id is minted
     unless one is passed (the launcher can export a shared id so every
     host's spans join one logical trace)."""
+    _install_gc_hook()
     with _state.lock:
         if _state.enabled:
             return
@@ -152,7 +173,10 @@ def init_from_env() -> None:
     """HOROVOD_TRACE=1 enables the recorder at hvd.init(), and where a
     ServeEngine is built in a process that never calls it. HVD_TRACE_ID
     (minted by `hvdrun --trace`) joins every host's spans into one
-    logical trace."""
+    logical trace. The collector's hook goes in either way, so that a
+    profiler session over a process with the recorder off names its
+    pauses too."""
+    _install_gc_hook()
     if knobs.get("HOROVOD_TRACE"):
         enable(trace_id=os.environ.get("HVD_TRACE_ID"))
 
@@ -304,6 +328,50 @@ def end_async(name: str, cat: str, attrs: Optional[Dict] = None) -> None:
            parent_id=parent)
 
 
+# -- the interpreter's garbage collections ----------------------------------
+
+_GC_SPANS = ("host.gc.gen0", "host.gc.gen1", "host.gc.gen2")
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """``gc.callbacks`` hook: every collection is a span
+    ``host.gc.gen<n>`` on the trace's clock, under the span open on the
+    thread that ran into it, so that a pause of the host (and an idle gap
+    of the device over it) carries the collector's name."""
+    if not _state.enabled and not _profiling():
+        return
+    name = _GC_SPANS[info["generation"]]
+    if phase == "start":
+        ann = annotation(name)
+        ann.__enter__()
+        _state.gc_open = (ann, _now_us())
+        return
+    opened, _state.gc_open = _state.gc_open, None
+    if opened is None:              # turned on in the middle of one
+        return
+    ann, t0 = opened
+    dur = _now_us() - t0
+    ann.__exit__(None, None, None)
+    if _state.enabled:
+        _state.gc_us += dur
+        record(name, CAT_HOST, t0, dur,
+               attrs={"collected": info["collected"],
+                      "uncollectable": info["uncollectable"]},
+               parent_id=getattr(_tls, "span_id", 0))
+
+
+def _install_gc_hook() -> None:
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_us() -> float:
+    """Microseconds of the collections that ended with the recorder on,
+    all told: a caller takes the difference across its own interval
+    (``serve.cycle``'s ``gc_ms``)."""
+    return _state.gc_us
+
+
 # -- reads / export ---------------------------------------------------------
 
 def _buffer_copy() -> List[Any]:
@@ -405,20 +473,6 @@ def write_chrome_trace(path: str,
         json.dump(payload, f)
     os.replace(tmp, path)
     return path
-
-
-def export_chrome_trace(path: str, process_index: int = 0) -> str:
-    """Export the local ring buffer as one Perfetto-loadable trace file
-    (process/track metadata included)."""
-    evs: List[Dict[str, Any]] = [
-        {"ph": "M", "name": "process_name", "pid": process_index,
-         "args": {"name": f"host{process_index} "
-                          f"({socket.gethostname()})"}}]
-    evs += chrome_events(snapshot(), pid=process_index,
-                         trace_id_=_state.trace_id)
-    return write_chrome_trace(
-        path, evs, metadata={"trace_id": _state.trace_id,
-                             "epoch_unix": _state.epoch_unix})
 
 
 def trace_dir() -> str:

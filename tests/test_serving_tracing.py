@@ -109,8 +109,9 @@ def test_one_cycle_is_one_span_with_its_phases_as_children():
     rows = _by_name(trace.snapshot())
     (cycle,) = rows["serve.cycle"]
     assert cycle["parent_id"] == 0 and cycle["cat"] == "serve"
-    assert cycle["attrs"] == {"cycle": 3, "queued": 0, "prefilling": 0,
-                              "active": 2}
+    counts = {k: v for k, v in cycle["attrs"].items()
+              if k not in ("cpu_ms", "gc_ms")}
+    assert counts == {"cycle": 3, "queued": 0, "prefilling": 0, "active": 2}
     children = [r for rs in rows.values() for r in rs
                 if r["parent_id"] == cycle["span_id"]]
     assert {c["name"] for c in children} == {
@@ -121,9 +122,14 @@ def test_one_cycle_is_one_span_with_its_phases_as_children():
     assert decode["attrs"] == {"active": 2, "tokens": 2}
     (dispatch,) = rows["engine.decode.dispatch"]
     (wait,) = rows["engine.decode.wait"]
-    for inner in (dispatch, wait):
+    (see,) = rows["serve.see"]
+    for inner in (dispatch, wait, see):
         assert inner["parent_id"] == decode["span_id"]
         assert _inside(inner, decode)
+    # the two prompts' first tokens (their last chunks ran a cycle ago) and
+    # the two tokens of the step behind them
+    assert see["attrs"] == {"tokens": 4, "firsts": 2}
+    assert wait["ts_us"] + wait["dur_us"] <= see["ts_us"] + 0.5
     assert dispatch["attrs"] == {"active": 2}
     # this cycle's step is enqueued before anything is waited for, and it
     # was enqueued with the step before it unread: the wait is for THAT
@@ -267,6 +273,68 @@ def test_off_records_nothing_and_the_tokens_are_the_same_on_and_off():
     assert on == off
 
 
+def test_see_counts_every_token_the_host_appends():
+    sched = _scheduler()
+    trace.enable(buffer_spans=4096)
+    requests = _requests(3)
+    _run(sched, requests)
+    rows = _by_name(trace.snapshot())
+    decodes = {d["span_id"] for d in rows["serve.decode"]}
+    sees = rows["serve.see"]
+    assert sees and all(s["parent_id"] in decodes for s in sees)
+    assert sum(s["attrs"]["tokens"] for s in sees) == \
+        sum(len(r.tokens) for r in requests)
+    assert sum(s["attrs"]["firsts"] for s in sees) == len(requests)
+    # a cycle with nothing in flight (a run's first step) reads nothing in
+    assert len(sees) == len(rows["serve.decode"]) - 1
+
+
+def test_the_wait_has_two_halves_on_and_is_one_read_off(monkeypatch):
+    calls = []
+    ready = type(jnp.zeros(1)).block_until_ready
+    monkeypatch.setattr(type(jnp.zeros(1)), "block_until_ready",
+                        lambda self: calls.append(1) or ready(self))
+    assert not trace.active()
+    off = _run(_scheduler(), _requests(2))
+    assert calls == []              # the off path: one np.asarray, no more
+    trace.enable(buffer_spans=4096)
+    sched = _scheduler()
+    on = _run(sched, _requests(2))
+    sched.engine.drain("idle")
+    assert on == off
+    rows = trace.snapshot()
+    waits = [r for r in rows if r["name"] == "engine.decode.wait"]
+    assert waits and len(calls) == len(waits)
+    for wait in waits:
+        halves = [r for r in rows if r["parent_id"] == wait["span_id"]]
+        assert [h["name"] for h in halves] == [
+            "engine.decode.wait.ready", "engine.decode.wait.copy"]
+        assert all(_inside(h, wait) for h in halves)
+        assert halves[0]["ts_us"] + halves[0]["dur_us"] <= \
+            halves[1]["ts_us"] + 0.5
+
+
+def test_a_cycle_carries_its_cpu_and_collection_time(monkeypatch):
+    import gc
+    sched = _scheduler()
+    admit = sched._admit
+    monkeypatch.setattr(sched, "_admit",
+                        lambda now: (gc.collect(), admit(now))[1])
+    trace.enable(buffer_spans=4096)
+    _run(sched, _requests(2))
+    rows = _by_name(trace.snapshot())
+    cycles = rows["serve.cycle"]
+    for cycle in cycles:
+        a = cycle["attrs"]
+        # the thread's own clock, read inside the span: never above its wall
+        assert 0.0 <= a["cpu_ms"] <= cycle["dur_us"] * 1e-3 + 1e-3
+        full = [g for g in rows["host.gc.gen2"]
+                if g["parent_id"] == cycle["span_id"]]
+        assert len(full) == 1       # the forced one, under the cycle
+        assert a["gc_ms"] >= full[0]["dur_us"] * 1e-3 - 1e-6 > 0
+        assert a["gc_ms"] <= cycle["dur_us"] * 1e-3
+
+
 def test_speculative_decode_has_the_same_boundary():
     sched = _scheduler(draft="truncate:1", spec_k=2)
     trace.enable(buffer_spans=4096)
@@ -340,6 +408,39 @@ def test_a_profiler_session_gets_the_spans_with_the_recorder_off(
             assert any(lo <= s and s + d <= hi for lo, hi in dispatches)
     # after the session the off path is the shared no-op again
     assert trace.span("a") is trace.span("b")
+
+
+def test_a_profiler_session_gets_the_read_in_and_the_collections(tmp_path):
+    """The spans an idle gap of the device is named by, with no knob set:
+    the read-in, the two halves of the readback, a collection."""
+    import gc
+    sched = _scheduler()            # its engine put the collector's hook in
+    assert not trace.enabled()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "profile"),
+                             profiler_options=options)
+    try:
+        assert trace.active()
+        for r in _requests(2):
+            sched.submit(r)
+        sched.step()
+        gc.collect()
+        _run(sched, [])
+        while len(sched.completed) < 2:
+            sched.step()
+    finally:
+        jax.profiler.stop_trace()
+    assert not trace.active() and trace.snapshot() == []
+    events = _host_events(str(tmp_path / "profile"))
+    names = {n for n, _, _ in events}
+    assert {"hvd.serve.see", "hvd.engine.decode.wait.ready",
+            "hvd.engine.decode.wait.copy", "hvd.host.gc.gen2"} <= names
+    waits = sorted((s, s + d) for n, s, d in events
+                   if n == "hvd.engine.decode.wait")
+    for n, s, d in events:
+        if n.startswith("hvd.engine.decode.wait."):
+            assert any(lo <= s and s + d <= hi for lo, hi in waits)
 
 
 def test_recorder_on_writes_ring_and_profiler_annotation(tmp_path):

@@ -185,20 +185,77 @@ class TestSpans:
         trace.end_async("never_opened", "queue")
         assert trace.snapshot() == []
 
-    def test_chrome_export_atomic_and_loadable(self, tmp_path):
+    def test_flight_recording_atomic_and_loadable(self, tmp_path):
         trace.enable()
         with trace.span("op", cat="t"):
             pass
-        path = str(tmp_path / "out.trace.json")
-        trace.export_chrome_trace(path, process_index=3)
+        path = trace.dump_flight_recording("why", str(tmp_path))
         assert not os.path.exists(path + ".tmp")
         data = json.loads(open(path).read())
         evs = data["traceEvents"]
         meta = [e for e in evs if e.get("ph") == "M"]
-        assert meta and meta[0]["pid"] == 3
+        assert meta and meta[0]["name"] == "process_name"
         xs = [e for e in evs if e.get("ph") == "X"]
         assert xs[0]["name"] == "op" and "dur" in xs[0]
         assert data["metadata"]["trace_id"] == trace.trace_id()
+        assert not hasattr(trace, "export_chrome_trace")
+
+    def test_a_forced_collection_is_one_gen2_span_under_the_open_span(self):
+        import gc
+        trace.enable(buffer_spans=256)
+        before = trace.gc_us()
+        with trace.span("outer", cat="t"):
+            gc.collect()
+        rows = trace.snapshot()
+        outer = rows[-1]
+        (full,) = [r for r in rows if r["name"] == "host.gc.gen2"]
+        assert outer["name"] == "outer"
+        assert full["parent_id"] == outer["span_id"]
+        assert full["cat"] == trace.CAT_HOST
+        assert set(full["attrs"]) == {"collected", "uncollectable"}
+        assert outer["ts_us"] <= full["ts_us"]
+        assert full["ts_us"] + full["dur_us"] <= \
+            outer["ts_us"] + outer["dur_us"]
+        # what a caller takes the difference of across its own interval
+        assert trace.gc_us() - before >= full["dur_us"] > 0
+
+    def test_the_gc_hook_off_appends_and_builds_nothing(self):
+        import gc
+        import tracemalloc
+        trace.enable()
+        trace.reset()                   # the hook is in, the recorder off
+        assert trace_spans._on_gc in gc.callbacks and not trace.active()
+        info = {"generation": 2, "collected": 0, "uncollectable": 0}
+        trace_spans._on_gc("start", info)       # warm any lazy caches
+        trace_spans._on_gc("stop", info)
+        tracemalloc.start()
+        before = tracemalloc.take_snapshot()
+        for _ in range(1000):
+            trace_spans._on_gc("start", info)
+            trace_spans._on_gc("stop", info)
+        gc.collect()
+        after = tracemalloc.take_snapshot()
+        tracemalloc.stop()
+        spans_py = os.path.join("tracing", "spans.py")
+        grown = [s for s in after.compare_to(before, "lineno")
+                 if s.size_diff > 0 and spans_py in str(s.traceback)]
+        assert grown == [], f"off-path hook allocated: {grown}"
+        assert trace.snapshot() == [] and trace_spans._state.gc_open is None
+
+    def test_the_gc_hook_is_installed_once(self, monkeypatch):
+        import gc
+        for _ in range(3):
+            trace.enable()
+            trace_spans.init_from_env()
+            trace.reset()
+        assert gc.callbacks.count(trace_spans._on_gc) == 1
+        # a process that never turns the recorder on gets it from
+        # hvd.init() / a ServeEngine, for a profiler session's sake
+        gc.callbacks.remove(trace_spans._on_gc)
+        monkeypatch.delenv("HOROVOD_TRACE", raising=False)
+        trace_spans.init_from_env()
+        assert gc.callbacks.count(trace_spans._on_gc) == 1
+        assert not trace.enabled()
 
     def test_init_from_env(self, monkeypatch):
         monkeypatch.setenv("HOROVOD_TRACE", "1")

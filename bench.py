@@ -1203,8 +1203,10 @@ def verify_report_main() -> int:
                        opt_state)
     toks = jax.ShapeDtypeStruct((2 * devs.size, 256), jnp.int32)
     grad_sizes = fusion.leaf_sizes(params)
-    # trainer.sync_gradients fuses each axes-group into one collective
-    # per dtype (no bucketing on this path): bucket_bytes=0 schedule.
+    # trainer.sync_gradients hands each leaf to psum as it is and the
+    # compiler's all-reduce combiner merges them (no bucketing on this
+    # path): the budget is the bucket_bytes=0 schedule's, one all-reduce
+    # of at most every gradient byte.
     tfm_manifest = fusion.expected_manifest(grad_sizes, 0)
     fs, report = verify_report(
         train_step, (state, toks, toks), mesh=mesh, expected=tfm_manifest,

@@ -432,7 +432,12 @@ knobs.register("HOROVOD_FLASH_BLOCK_K", 1024, int,
                     "HOROVOD_FLASH_BLOCK_Q).")
 knobs.register("HOROVOD_BATCH_D2D_MEMCOPIES", True, bool,
                help="Batch fusion-buffer pack/unpack into one fused kernel "
-                    "(ref cuda_kernels.cu; here: one jitted scatter/gather).")
+                    "(ref cuda_kernels.cu; here: one jitted scatter/gather). "
+                    "Read where a collective is a launch of its own: the eager "
+                    "coordinator's fused dispatch and DistributedOptimizer's "
+                    "unbucketed sync. The jitted trainer step "
+                    "(trainer.sync_gradients) packs nothing and does not read "
+                    "it: one psum a leaf, combined by the compiler.")
 knobs.register("HOROVOD_ENABLE_ASYNC_COMPLETION", True, bool,
                help="Do not host-sync after collectives; rely on XLA async dispatch "
                     "(ref gpu_operations.cc:93-115).")

@@ -280,7 +280,7 @@ def group_leaves_by_axes(tree, sync_axes):
     jax's usual tree-structure error at THIS boundary instead of
     surfacing as silent None leaves downstream.
 
-    Shared by the fused gradient-sync paths (parallel/distributed.py,
+    Shared by the gradient-sync paths (parallel/distributed.py,
     parallel/trainer.sync_gradients) so the grouping/alignment logic has
     one home.
     """
@@ -303,29 +303,3 @@ def group_leaves_by_axes(tree, sync_axes):
         a = a if isinstance(a, tuple) else (a,)
         groups.setdefault(tuple(x for x in a if x), []).append(i)
     return treedef, leaves, groups
-
-
-def apply_by_groups(tree, sync_axes, group_fn):
-    """Group a gradient tree's leaves with :func:`group_leaves_by_axes`,
-    run ``group_fn(leaves, axes) -> synced_leaves`` once per group, and
-    rebuild the tree — the one home for the group/scatter loop shared by
-    parallel/distributed.allreduce_gradients and
-    parallel/trainer.sync_gradients."""
-    treedef, leaves, groups = group_leaves_by_axes(tree, sync_axes)
-    out = [None] * len(leaves)
-    for axes, idxs in groups.items():
-        for i, s in zip(idxs, group_fn([leaves[i] for i in idxs], axes)):
-            out[i] = s
-    return jax.tree_util.tree_unflatten(treedef, out)
-
-
-def fused_group_apply(tree, sync_axes, make_fn):
-    """:func:`apply_by_groups` with ``make_fn(axes)`` — a buffer->buffer
-    reduce closure — applied as one :func:`fuse_apply` batch per group
-    (honoring HOROVOD_BATCH_D2D_MEMCOPIES like the coordinator's fused
-    dispatch)."""
-    from horovod_tpu.config import knobs
-    batch = bool(knobs.get("HOROVOD_BATCH_D2D_MEMCOPIES"))
-    return apply_by_groups(
-        tree, sync_axes,
-        lambda leaves, axes: fuse_apply(make_fn(axes), leaves, batch=batch))

@@ -3,10 +3,14 @@
 Reference analogue: one training step in horovod/torch/optimizer.py:36
 (backward hooks -> async allreduce -> synchronize -> step), SURVEY §3.2. Here
 the whole step — forward, backward, gradient sync over every replicated mesh
-axis, optimizer update — is ONE jitted program: XLA overlaps the gradient
-psums with remaining backward compute (the fusion/overlap the reference's
-background thread + fusion buffer exist to approximate) and keeps parameters,
-grads and optimizer state sharded on-device.
+axis, optimizer update — is ONE jitted program that keeps parameters, grads
+and optimizer state sharded on-device. The exchange FOLLOWS the backward: a
+layer stack's gradient is whole only when its first layer's backward ends,
+and the compiled step runs its all-reduces synchronously after the last
+product they need (none of the exchange hides behind compute: PERF.md §5).
+What the program form buys is that the sync is one ``psum`` a leaf, which
+the compiler's all-reduce combiner merges and whose ``1/world`` fuses into
+the optimizer's pass — no fusion buffer is packed or unpacked around it.
 
 Gradient sync uses the model's ``grad_sync_axes`` map (psum over exactly the
 axes each param's grads are partial over), which generalises Horovod's single
@@ -63,23 +67,27 @@ def sync_gradients(grads: Any, sync_axes: Any, world: int) -> Any:
     leaf's replicated axes then 1/world recovers the exact gradient of the
     replicated scalar loss.
 
-    Leaves sharing an axes tuple sync as ONE fused psum per dtype (the
-    in-graph fusion buffer, ref fusion_buffer_manager.h:31-47): per-step
-    collective count drops from O(params) to O(axes-groups x dtypes),
-    which is what keeps the launch/negotiation overhead flat at scale.
+    Each leaf goes to ``psum`` as it is. Inside one jitted program the
+    reference's fusion buffer (ravel, concatenate, one collective, slice,
+    reshape: ``ops.fusion.fuse_apply``, which the eager paths keep because
+    there a collective IS a launch) buys nothing and costs three passes over
+    the gradients: XLA combines neighbouring all-reduces itself and fuses
+    the scale into whatever reads the leaf next. ``world == 1`` has nothing
+    to exchange and traces no operation at all.
     """
-    from horovod_tpu.ops.fusion import fused_group_apply
+    if world == 1:
+        return grads
+    from horovod_tpu.ops.fusion import group_leaves_by_axes
+    treedef, leaves, groups = group_leaves_by_axes(grads, sync_axes)
     inv = jnp.float32(1.0 / world)
-
-    def make_fn(axes):
-        def one(buf):
-            for ax in axes:
-                buf = lax.psum(buf, ax)
-            return buf * inv.astype(buf.dtype) if world != 1 else buf
-        return one
-
     with jax.named_scope("hvd_grad_sync"):
-        return fused_group_apply(grads, sync_axes, make_fn)
+        for axes, idxs in groups.items():
+            for i in idxs:
+                g = leaves[i]
+                for ax in axes:
+                    g = lax.psum(g, ax)
+                leaves[i] = g * inv.astype(g.dtype)
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def make_transformer_train_step(

@@ -169,14 +169,18 @@ class Timeline:
                         # after ANY event — a mid-run process death never
                         # leaves an unparseable trace (and per-event
                         # flush means nothing is lost in buffers).
+                        # Record and bracket go out in ONE write: a
+                        # tell() between them flushed the record alone,
+                        # and a reader (or a death) just then found no
+                        # bracket. The record is ASCII (json.dumps
+                        # escapes the rest), so its length is its bytes.
+                        record = (",\n" if self._wrote_any else "") \
+                            + json.dumps(ev)
                         self._file.seek(self._tail)
-                        if self._wrote_any:
-                            self._file.write(",\n")
-                        self._file.write(json.dumps(ev))
-                        self._tail = self._file.tell()
-                        self._file.write("\n]")
+                        self._file.write(record + "\n]")
                         self._file.truncate()
                         self._file.flush()
+                        self._tail += len(record)
                         self._wrote_any = True
             except Exception:
                 # A dying writer thread must not be silent: the trace

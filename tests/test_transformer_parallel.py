@@ -177,3 +177,105 @@ def test_scan_unroll_equivalence():
             lambda p, t: tfm.loss_fn(cfg, p, t, t))(params, tokens)))
     np.testing.assert_allclose(losses[0], losses[1], rtol=1e-6)
     np.testing.assert_allclose(losses[0], losses[2], rtol=1e-6)
+
+
+# --- sync_gradients: one psum a leaf, no buffer around the exchange ---------
+
+def packed_sync(grads, sync_axes, world):
+    """What ``sync_gradients`` was until PR 38, kept as the reference: the
+    leaves of one axes tuple raveled into one buffer, one psum an axis on
+    it, the scale, and every leaf cut out and reshaped back."""
+    from horovod_tpu.ops.fusion import group_leaves_by_axes
+    treedef, leaves, groups = group_leaves_by_axes(grads, sync_axes)
+    for axes, idxs in groups.items():
+        buf = jnp.concatenate([jnp.ravel(leaves[i]) for i in idxs])
+        for ax in axes:
+            buf = jax.lax.psum(buf, ax)
+        buf = buf * jnp.float32(1.0 / world) if world != 1 else buf
+        offsets = np.cumsum([0] + [leaves[i].size for i in idxs])
+        for i, o in zip(idxs, offsets):
+            leaves[i] = buf[o:o + leaves[i].size].reshape(leaves[i].shape)
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+# mesh shape, axis names, and a coarse sync_axes tree over the gradient
+# tree below: a tuple at an interior node covers its whole subtree
+SYNC_CASES = {
+    "dp2": ((2,), ("dp",),
+            {"embed": ("dp",), "layers": ("dp",), "head": ("dp",)}),
+    "dp2_tp2_mixed_axes": (
+        (2, 2), ("dp", "tp"),
+        {"embed": ("dp", "tp"), "layers": {"wq": ("dp",), "norm": ("dp", "tp"),
+                                           "bias": ()},
+         "head": ("dp",)}),
+    "world1": ((1,), ("dp",),
+               {"embed": ("dp",), "layers": ("dp",), "head": ("dp",)}),
+}
+
+
+def _sync_case(name):
+    shape, names, sync = SYNC_CASES[name]
+    mesh = mesh_for(shape, names)
+    world = int(np.prod(shape))
+    rng = np.random.RandomState(3)
+    # a leading axis of one row a chip, so every chip holds other numbers
+    grads = {"embed": rng.randn(world, 7, 5), "head": rng.randn(world, 5, 3),
+             "layers": {"wq": rng.randn(world, 2, 5, 5),
+                        "norm": rng.randn(world, 2, 5),
+                        "bias": rng.randn(world, 3)}}
+    grads = jax.tree.map(lambda g: jnp.asarray(g, jnp.float32), grads)
+    return mesh, names, sync, world, grads
+
+
+@pytest.mark.parametrize("case", sorted(SYNC_CASES))
+def test_sync_gradients_gives_what_the_packed_form_gave(case):
+    mesh, names, sync, world, grads = _sync_case(case)
+    spec = jax.tree.map(lambda _: P(names), grads)
+
+    def run(fn):
+        return jax.jit(shard_map(
+            lambda g: fn(g, sync, world), mesh, in_specs=(spec,),
+            out_specs=spec))(grads)
+
+    got, want = run(trainer_lib.sync_gradients), run(packed_sync)
+    assert jax.tree.structure(got) == jax.tree.structure(grads)
+    for (path, w), g in zip(jax.tree.leaves_with_path(want),
+                            jax.tree.leaves(got)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(
+            np.asarray(g), np.asarray(w),
+            err_msg=f"leaf {jax.tree_util.keystr(path)}")
+    if world == 1:      # nothing to exchange: the very arrays come back
+        assert all(a is b for a, b in zip(
+            jax.tree.leaves(trainer_lib.sync_gradients(grads, sync, 1)),
+            jax.tree.leaves(grads)))
+
+
+def _dp_step_text(n_chips):
+    cfg = tfm.TransformerConfig(dp_axis="dp", **BASE)
+    opt = optax.sgd(0.01, momentum=0.9)
+    _, step = trainer_lib.make_transformer_train_step(
+        cfg, opt, mesh_for((n_chips,), ("dp",)))
+    params = jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
+    state = trainer_lib.TrainState(
+        jax.ShapeDtypeStruct((), jnp.int32), params,
+        jax.eval_shape(opt.init, params))
+    tokens = jax.ShapeDtypeStruct((B, S), jnp.int32)
+    text = step.lower(state, tokens, tokens).compile().as_text()
+    n_elements = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
+    return text, n_elements
+
+
+@pytest.mark.parametrize("n_chips", [2, 1])
+def test_dp_step_packs_no_gradient_buffer(n_chips):
+    """No array of every gradient element stands anywhere in the compiled
+    step, and on one chip nothing at all is traced under the sync's scope
+    (PR 38: it packed and unpacked a buffer nobody exchanged)."""
+    text, n_elements = _dp_step_text(n_chips)
+    assert f"f32[{n_elements}]" not in text
+    under_scope = [l for l in text.splitlines() if "hvd_grad_sync" in l]
+    if n_chips == 1:
+        assert not under_scope, under_scope[:3]
+    else:
+        assert any("all-reduce" in l for l in under_scope)

@@ -55,6 +55,14 @@ SECTIONS = [
      "experts plus a shared expert in each; served through `ServeEngine` "
      "on the hybrid stack's step with a matrix state a head and slot "
      "(`SolarOpen2Config.serve_model()`); see docs/serving.md."),
+    ("horovod_tpu.models.kimi_k2",
+     "Latent-attention expert stack (Kimi K2)",
+     "DeepSeek-V3's layer: multi-head latent attention under a "
+     "YaRN-stretched rotary (`transformer.RopeScaling`), a leading dense "
+     "SwiGLU layer, then sigmoid-routed experts plus a shared expert; "
+     "served through `ServeEngine` on the latent cache and step of "
+     "`models/mla.py` (`KimiK2Config.serve_model()`); see "
+     "docs/serving.md."),
     ("horovod_tpu.callbacks", "Callbacks",
      "Keras-style training callbacks (broadcast, metric averaging, LR "
      "schedules, best-model checkpoint)."),

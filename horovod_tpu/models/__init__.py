@@ -25,6 +25,12 @@ first-class package because the driver benchmarks the framework through them:
                     a head and slot) with one gated grouped-query attention
                     layer among every few, sigmoid-routed experts and a
                     shared expert; served on ``granite_hybrid``'s step.
+- ``mla``         — multi-head latent attention over a paged latent cache and
+                    the step the latent-attention models serve on.
+- ``kimi_k2``     — the DeepSeek-V3 stack (Kimi K2): latent attention under a
+                    YaRN-stretched rotary, a leading dense layer, then
+                    sigmoid-routed experts plus a shared expert, served as one
+                    chip's share of its experts.
 """
 
 from horovod_tpu.models.mlp import MLP, MnistCNN  # noqa: F401
@@ -41,3 +47,4 @@ from horovod_tpu.models.transformer import (  # noqa: F401
 from horovod_tpu.models.longcat_flash import LongCatFlashConfig  # noqa: F401
 from horovod_tpu.models.granite_hybrid import GraniteHybridConfig  # noqa: F401
 from horovod_tpu.models.solar_open2 import SolarOpen2Config  # noqa: F401
+from horovod_tpu.models.kimi_k2 import KimiK2Config  # noqa: F401

@@ -15,16 +15,13 @@ residual stream ``h`` is float32 whatever the weights' dtype: a product reads
 its input in the config's dtype and what it gives is added up in float32;
 the router reads the float32 ``u``.
 
-MLA (multi-head latent attention, low-rank q and kv, no biases):
-``cq = N(x Wqa)``; ``[q_nope | q_rope] = (cq Wqb) a_q`` per head;
-``[c | k_r] = x Wkva``; ``c = N(c) a_kv``; ``k_r = rope(k_r)`` (one rotary
-key shared by every head); ``[k_nope | v] = c Wkvb`` per head;
-``score = (q_nope k_nope + rope(q_rope) k_r) / sqrt(nope + rope)``, causal
-softmax in float32, ``o = concat(p v) Wo``. **The cache holds, per token and
-attention block, ``c`` (after norm and scale) and ``k_r`` (after rotary)**:
-``kv_lora_rank + rope`` numbers. A prefill chunk expands ``k_nope`` and ``v``
-from the cached ``c``; a decode step absorbs ``Wkvb`` (``q_lat = q_nope
-Wkvb_k^T``, ``out = (p c) Wkvb_v``) and reads only the latent rows.
+MLA (multi-head latent attention: :mod:`horovod_tpu.models.mla`, whose
+blocks and step this module runs): the latents scaled, ``a_q = sqrt(d_model
+/ q_lora_rank)`` on the query's and ``a_kv = sqrt(d_model / kv_lora_rank)``
+on the normed ``c``; plain rotary at ``rope_theta``; softmax scale ``1 /
+sqrt(nope + rope)``. The cache holds, per token and attention block, ``c``
+and ``k_r``; a prefill chunk expands keys and values from it, a decode step
+absorbs ``Wkvb``.
 
 MoE (:mod:`horovod_tpu.parallel.moe`): softmax router over every routed and
 zero-compute (identity) expert, top-k of ``p + bias``, weights ``scaling *
@@ -52,8 +49,11 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.models.transformer import (
-    _rmsnorm, rope, swiglu, visible_softmax)
+from horovod_tpu.models import mla
+from horovod_tpu.models.mla import (  # noqa: F401 - read here by tests
+    mla_attend_absorbed, mla_attend_expanded)
+from horovod_tpu.models.mla import norm as _norm
+from horovod_tpu.models.transformer import swiglu
 from horovod_tpu.parallel import moe as moe_lib
 
 Params = Dict[str, Any]
@@ -86,11 +86,22 @@ class LongCatFlashConfig:
     max_seq: int = 131072
     dtype: Any = jnp.bfloat16
     tp_axis: Optional[str] = None   # not offered: one chip's share is served
+    # the rotary at rope_theta, unstretched: a constant of the class
+    rope_scaling = None
 
     @property
     def held_experts(self) -> int:
         return (self.n_routed_experts if self.expert_count is None
                 else self.expert_count)
+
+    @property
+    def attention_blocks(self) -> int:
+        """Cached blocks: two attention blocks a layer."""
+        return 2 * self.n_layers
+
+    @property
+    def softmax_scale(self) -> float:
+        return (self.qk_nope_dim + self.qk_rope_dim) ** -0.5
 
     @property
     def router_width(self) -> int:
@@ -116,8 +127,8 @@ class LongCatFlashConfig:
         model (``serving.model.ServeModel``)."""
         from horovod_tpu.serving.model import ServeModel
         return ServeModel(
-            check=_check_serve, cache_rows=_cache_rows, decode=decode_body,
-            prefill=prefill_body, param_specs=param_specs,
+            check=_check_serve, cache_rows=mla.cache_rows,
+            decode=decode_body, prefill=prefill_body, param_specs=param_specs,
             state=_counter_state, stats=routing_stats)
 
 
@@ -183,88 +194,6 @@ def param_specs(cfg: LongCatFlashConfig) -> Params:
 # pieces of a layer
 # ---------------------------------------------------------------------------
 
-def _norm(cfg, x, scale):
-    """RMSNorm of the residual stream (float32) or of a latent, in float32;
-    the result in the dtype of ``x``."""
-    return _rmsnorm(x, scale, eps=cfg.norm_eps)
-
-
-def mla_project(cfg: LongCatFlashConfig, bp: Params, x: jax.Array,
-                pos: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """The low-rank projections of one attention block on normed rows x
-    ``[N, D]`` at positions ``pos``: (q_nope ``[N, H, nope]``, q_rope
-    ``[N, H, rope]`` rotated, the cache row ``[N, kv_lora_rank + rope]`` =
-    ``c`` after norm and scale beside ``k_r`` after rotary)."""
-    dt = cfg.dtype
-    n = x.shape[0]
-    cq = _norm(cfg, x @ bp["wq_a"].astype(dt), bp["q_norm"])
-    q = ((cq @ bp["wq_b"].astype(dt)) * cfg.a_q).astype(dt)
-    q = q.reshape(n, cfg.n_heads, cfg.qk_nope_dim + cfg.qk_rope_dim)
-    q_nope, q_rope = q[..., :cfg.qk_nope_dim], q[..., cfg.qk_nope_dim:]
-    kv = x @ bp["wkv_a"].astype(dt)
-    c = (_norm(cfg, kv[:, :cfg.kv_lora_rank], bp["kv_norm"])
-         * cfg.a_kv).astype(dt)
-    k_r = rope(kv[:, cfg.kv_lora_rank:], pos, cfg.rope_theta, heads=0)
-    q_rope = rope(q_rope, pos, cfg.rope_theta)
-    return q_nope, q_rope, jnp.concatenate([c, k_r], axis=-1)
-
-
-def _wkv_b(cfg, bp):
-    """``Wkvb`` by head: (``[rank, H, nope]`` to keys, ``[rank, H, v]`` to
-    values)."""
-    w = bp["wkv_b"].astype(cfg.dtype).reshape(
-        cfg.kv_lora_rank, cfg.n_heads, cfg.qk_nope_dim + cfg.v_dim)
-    return w[..., :cfg.qk_nope_dim], w[..., cfg.qk_nope_dim:]
-
-
-def mla_attend_absorbed(cfg: LongCatFlashConfig, bp: Params,
-                        q_nope: jax.Array, q_rope: jax.Array,
-                        rows: jax.Array, visible: jax.Array) -> jax.Array:
-    """Decode's attention: each query row n over ITS OWN cached rows
-    ``rows[n]`` ``[T, kv_lora_rank + rope]`` with ``Wkvb`` absorbed into the
-    query and the output, so what is read per cached token is the latent
-    row and never the heads' keys and values. Returns ``[N, H * v]``."""
-    dt = cfg.dtype
-    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
-    wk, wv = _wkv_b(cfg, bp)
-    c, k_r = rows[..., :cfg.kv_lora_rank], rows[..., cfg.kv_lora_rank:]
-    with jax.named_scope("hvd_mla_proj"):
-        q_lat = jnp.einsum("nhd,rhd->nhr", q_nope, wk).astype(dt)
-    with jax.named_scope("hvd_attention"):
-        s = (jnp.einsum("nhr,ntr->nht", q_lat, c,
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("nhd,ntd->nht", q_rope, k_r,
-                          preferred_element_type=jnp.float32)) * scale
-        p = visible_softmax(s, visible).astype(dt)
-        o_lat = jnp.einsum("nht,ntr->nhr", p, c).astype(dt)
-    with jax.named_scope("hvd_mla_proj"):
-        o = jnp.einsum("nhr,rhv->nhv", o_lat, wv)
-    return o.reshape(o.shape[0], -1).astype(dt)
-
-
-def mla_attend_expanded(cfg: LongCatFlashConfig, bp: Params,
-                        q_nope: jax.Array, q_rope: jax.Array,
-                        rows: jax.Array, visible: jax.Array) -> jax.Array:
-    """Prefill's attention: every query row over ONE sequence's cached rows
-    ``[T, kv_lora_rank + rope]``, the heads' keys and values expanded from
-    ``c``. Returns ``[N, H * v]``."""
-    dt = cfg.dtype
-    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
-    wk, wv = _wkv_b(cfg, bp)
-    c, k_r = rows[:, :cfg.kv_lora_rank], rows[:, cfg.kv_lora_rank:]
-    with jax.named_scope("hvd_mla_proj"):
-        k_nope = jnp.einsum("tr,rhd->thd", c, wk).astype(dt)
-        v = jnp.einsum("tr,rhv->thv", c, wv).astype(dt)
-    with jax.named_scope("hvd_attention"):
-        s = (jnp.einsum("nhd,thd->nht", q_nope, k_nope,
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("nhd,td->nht", q_rope, k_r,
-                          preferred_element_type=jnp.float32)) * scale
-        p = visible_softmax(s, visible).astype(dt)
-        o = jnp.einsum("nht,thv->nhv", p, v)
-    return o.reshape(o.shape[0], -1).astype(dt)
-
-
 def moe_share(cfg: LongCatFlashConfig, mp: Params, u: jax.Array,
               valid: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, jax.Array]:
@@ -311,13 +240,6 @@ def layer(cfg: LongCatFlashConfig, lp: Params, h: jax.Array, attend,
     return h + s, counts
 
 
-def logits_of(cfg: LongCatFlashConfig, params: Params, h: jax.Array
-              ) -> jax.Array:
-    x = _norm(cfg, h, params["final_norm"]).astype(cfg.dtype)
-    return jnp.dot(x, params["head"].astype(cfg.dtype),
-                   preferred_element_type=jnp.float32)
-
-
 # ---------------------------------------------------------------------------
 # the serving engine's step bodies (serving.model.ServeModel)
 # ---------------------------------------------------------------------------
@@ -339,11 +261,6 @@ def _check_serve(cfg: LongCatFlashConfig, draft_mode: str) -> None:
             f"in the {cfg.n_routed_experts} routed experts")
 
 
-def _cache_rows(cfg: LongCatFlashConfig):
-    from horovod_tpu.serving.kv_cache import CacheRows
-    return (CacheRows("latent", 2 * cfg.n_layers, (cfg.cache_row,)),)
-
-
 DECODE, PREFILL = moe_lib.DECODE, moe_lib.PREFILL
 
 
@@ -357,92 +274,34 @@ def routing_stats(cfg: LongCatFlashConfig, state: Tuple[jax.Array, ...]
                                        cfg.held_experts)
 
 
-def _serve_step(cfg: LongCatFlashConfig, params: Params, pool: jax.Array,
-                counters: jax.Array, block_tables: jax.Array,
-                tokens: jax.Array, pos: jax.Array, counted: jax.Array,
-                write, mla_attend, program: int,
-                out_row: Optional[jax.Array] = None):
-    """What a decode step and a prefill chunk share: embed ``tokens``
-    ``[N]``, the layers at positions ``pos`` ``[N]`` through the latent
-    cache (pool ``[2L, P+1, page, row]``: layer l's attention block i is
-    block ``2l + i``), each block writing its rows through ``write(pages,
-    new, block_tables, scratch)`` and attending with ``mla_attend`` over
-    the gathered pages, each row seeing the cached positions up to its
-    own; the head (of row ``out_row`` only, if given), argmax. The rows
-    ``counted`` go into ``program``'s routing counters."""
+def _stack(cfg: LongCatFlashConfig, layers: Params, h: jax.Array,
+           flat: jax.Array, total: jax.Array, attend, counted: jax.Array):
+    """The layers in one scan (``mla.Stack``): layer l's attention block i
+    is block ``2l + i`` of the pool."""
     from horovod_tpu.serving import kv_cache as kvc
-    n_ctx = block_tables.shape[-1] * pool.shape[2]
-    visible = jnp.arange(n_ctx, dtype=jnp.int32)[None, :] <= pos[:, None]
-    h = params["embed"][tokens].astype(jnp.float32)                 # [N, D]
-    flat, = kvc.flat_pool(pool)
 
     def body(carry, xs):
         h, flat, total = carry
         lp, li = xs
 
-        def attend(i, x):
+        def attend_block(i, x):
             nonlocal flat
-            bt, scratch = kvc.block_pages(pool.shape, 2 * li + i,
-                                          block_tables)
-            with jax.named_scope("hvd_mla_proj"):
-                q_nope, q_rope, row = mla_project(cfg, lp["mla"][i], x, pos)
-            with jax.named_scope("hvd_kv_write"):
-                flat, = write((flat,), (row,), bt, scratch)
-            with jax.named_scope("hvd_attention"):
-                rows = kvc.gather_pages(flat, bt)   # [(N,) n_ctx, row]
-            return mla_attend(cfg, lp["mla"][i], q_nope, q_rope, rows,
-                              visible)
+            o, flat = attend(flat, 2 * li + i, lp["mla"][i], x)
+            return o
 
-        h, counts = layer(cfg, lp, h, attend, valid=counted)
+        h, counts = layer(cfg, lp, h, attend_block, valid=counted)
         return (h, flat, total + counts), None
 
-    zero = jnp.zeros((counters.shape[-1],), jnp.int32)
-    (h, flat, total), _ = lax.scan(body, (h, flat, zero),
-                                   kvc.with_index(params["layers"]))
-    if out_row is not None:
-        h = jnp.take(h, out_row, axis=0)                            # [D]
-    logits = logits_of(cfg, params, h)
-    next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return (flat.reshape(pool.shape),
-            moe_lib.add_share_counts(counters, total, program),
-            next_tokens, logits)
+    (h, flat, total), _ = lax.scan(body, (h, flat, total),
+                                   kvc.with_index(layers))
+    return h, flat, total
 
 
-def decode_body(cfg: LongCatFlashConfig, params: Params, pool: jax.Array,
-                counters: jax.Array, block_tables: jax.Array,
-                lengths: jax.Array, tokens: jax.Array):
-    """One decode step over all slots through the latent cache, ``Wkvb``
-    absorbed (each slot over its own pages). Empty slots carry length 0
-    and scratch block tables; their rows sink into the scratch page and
-    are not counted (a served slot has its prompt cached)."""
-    from horovod_tpu.serving import kv_cache as kvc
-    valid = lengths < block_tables.shape[1] * pool.shape[2]
-
-    def write(pages, new, bt, scratch):
-        return kvc.write_token_rows(pages, new, bt, lengths, valid=valid,
-                                    scratch=scratch)
-
-    return _serve_step(cfg, params, pool, counters, block_tables, tokens,
-                       lengths, lengths > 0, write, mla_attend_absorbed,
-                       DECODE)
+def decode_body(cfg: LongCatFlashConfig, params: Params, *args):
+    """One decode step over all slots (``mla.decode_body``)."""
+    return mla.decode_body(cfg, params, *args, stack=_stack)
 
 
-def prefill_body(cfg: LongCatFlashConfig, params: Params, pool: jax.Array,
-                 counters: jax.Array, block_table: jax.Array,
-                 start: jax.Array, n_real: jax.Array, tokens: jax.Array):
-    """One prefill chunk of ONE sequence: tokens ``[C]`` (bucket-padded) at
-    positions ``start ..``, their latent rows written to the pages, causal
-    attention over the cached prefix + the chunk (keys and values expanded
-    from the cached rows), the last real token's logits out."""
-    from horovod_tpu.serving import kv_cache as kvc
-    c = tokens.shape[0]
-    pos = start + jnp.arange(c, dtype=jnp.int32)
-
-    def write(pages, new, bt, scratch):
-        return kvc.write_chunk_rows(pages, new, bt, start, n_real,
-                                    scratch=scratch)
-
-    return _serve_step(cfg, params, pool, counters, block_table, tokens,
-                       pos, jnp.arange(c) < n_real, write,
-                       mla_attend_expanded, PREFILL,
-                       out_row=jnp.maximum(n_real - 1, 0))
+def prefill_body(cfg: LongCatFlashConfig, params: Params, *args):
+    """One prefill chunk of ONE sequence (``mla.prefill_body``)."""
+    return mla.prefill_body(cfg, params, *args, stack=_stack)

@@ -24,10 +24,12 @@ train step; ``__graft_entry__`` uses that for the driver's compile checks.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
@@ -209,19 +211,98 @@ def _rmsnorm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (x32 * rms * scale).astype(x.dtype)
 
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: ``0.1 mscale ln(factor) + 1`` (1 where
+    the context is not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """A rotary stretched past the context it was trained at, by YaRN
+    (``rope_scaling`` of type ``yarn`` in a model's ``config.json``; the
+    DeepSeek-V3 form). With ``f_i = theta^(-2i/D)`` the frequency of pair
+    ``i`` of ``D/2``::
+
+        low  = floor(D ln(original / (2 pi beta_fast)) / (2 ln theta))
+        high = ceil (D ln(original / (2 pi beta_slow)) / (2 ln theta))
+        m_i  = 1 - clamp((i - low) / (high - low), 0, 1)
+        inv_freq_i = f_i / factor * (1 - m_i) + f_i * m_i
+
+    so the fast pairs (``i <= low``) keep their frequency, the slow ones
+    (``i >= high``) turn ``factor`` times slower and those between are
+    blended on a linear ramp; cos and sin are multiplied by
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``
+    (:attr:`cos_sin_scale`), the softmax scale by ``mscale(factor,
+    mscale_all_dim)^2`` (:attr:`softmax_factor`), ``mscale(s, a) = 0.1 a
+    ln s + 1``. Everything in float32, computed once from the record
+    (:meth:`inv_freq`), never inside a program's arithmetic."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def _correction(self, rotations: float, dim: int, theta: float
+                    ) -> float:
+        return (dim * math.log(self.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    def ramp(self, theta: float, dim: int) -> Tuple[int, int]:
+        """(low, high): the pairs up to ``low`` keep their frequency, those
+        from ``high`` on are divided by ``factor``."""
+        low = math.floor(self._correction(self.beta_fast, dim, theta))
+        high = math.ceil(self._correction(self.beta_slow, dim, theta))
+        return max(low, 0), min(high, dim - 1)
+
+    def inv_freq(self, theta: float, dim: int) -> np.ndarray:
+        """The ``dim / 2`` frequencies, float32."""
+        f32 = np.float32
+        extra = f32(1.0) / (f32(theta) ** (np.arange(0, dim, 2, dtype=f32)
+                                           / f32(dim)))
+        inter = f32(1.0) / (f32(self.factor) * f32(theta) ** (
+            np.arange(0, dim, 2, dtype=f32) / f32(dim)))
+        low, high = self.ramp(theta, dim)
+        ramp = np.clip((np.arange(dim // 2, dtype=f32) - f32(low))
+                       / f32(max(high - low, 1e-3)), 0, 1).astype(f32)
+        keep = f32(1.0) - ramp
+        return (inter * (f32(1.0) - keep) + extra * keep).astype(f32)
+
+    @property
+    def cos_sin_scale(self) -> float:
+        return (_yarn_mscale(self.factor, self.mscale)
+                / _yarn_mscale(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_factor(self) -> float:
+        return (_yarn_mscale(self.factor, self.mscale_all_dim) ** 2
+                if self.mscale_all_dim else 1.0)
+
+
 def rope(x: jax.Array, pos: jax.Array, theta: float = 10000.0,
-         heads: int = 1) -> jax.Array:
+         heads: int = 1, scaling: Optional[RopeScaling] = None
+         ) -> jax.Array:
     """Rotary embedding over the last axis of x, interleaved pairs, in
     float32; the result in the dtype of ``x``. x is ``[..., *pos.shape,
     *H, D]`` with ``heads`` axes ``H`` between the positions' and ``D``: a
     batch ``[B, S, H, D]`` with ``pos[S]``, rows ``[N, H, D]`` with
     ``pos[N]``, a key without heads ``[N, D]`` with ``pos[N]`` and
-    ``heads=0``. The one rotary of the package: a cached key is bitwise
-    the key training rotates."""
+    ``heads=0``. ``scaling``: the frequencies (and the factor on cos and
+    sin) of a stretched context (:class:`RopeScaling`); without one the
+    plain ``theta^(-2i/D)``, bit for bit what this function gave before it
+    took one. The one rotary of the package: a cached key is bitwise the
+    key training rotates."""
     d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if scaling is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    else:
+        freqs = jnp.asarray(scaling.inv_freq(theta, d))
     ang = pos[..., None].astype(jnp.float32) * freqs           # [*P, D/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None and scaling.cos_sin_scale != 1.0:
+        cos, sin = cos * scaling.cos_sin_scale, sin * scaling.cos_sin_scale
     x1, x2 = x[..., 0::2], x[..., 1::2]
     # onto x's axes: size 1 over the leading axes and over the heads
     lead = x.ndim - 1 - heads - pos.ndim

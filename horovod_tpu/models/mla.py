@@ -22,12 +22,15 @@ rotary).
 
 **The cache holds, per token and attention block, ``c`` (after norm and
 scale) and ``k_r`` (after rotary)**: ``kv_lora_rank + rope`` numbers, one
-pool array of one latent row a token (:func:`cache_rows`). A prefill chunk
-expands ``k_nope`` and ``v`` from the cached ``c`` (:func:`mla_attend_expanded`,
-scope ``hvd_mla_expand``); a decode step absorbs ``Wkvb`` (``q_lat = q_nope
-Wkvb_k^T``, ``out = (p c) Wkvb_v``: :func:`mla_attend_absorbed`) and reads
-only the latent rows. Both attend over the sequence's gathered pages, every
-one of its ``max_seq`` positions under a mask of those it may see.
+pool array of one latent row a token (:func:`cache_rows`). A decode step
+absorbs ``Wkvb`` (``q_lat = q_nope Wkvb_k^T``, ``out = (p c) Wkvb_v``:
+:func:`mla_attend_absorbed`) and reads only the latent rows, over each
+slot's gathered pages, every one of its ``max_seq`` positions under a mask
+of those it may see. A prefill chunk absorbs ``Wkvb`` too and reads the
+pages in place, only those the chunk can see, through the paged kernel
+``hvd_mla_prefill`` (:func:`mla_attend_paged`); where no kernel runs, it
+expands ``k_nope`` and ``v`` from the gathered ``c``
+(:func:`mla_attend_expanded`, scope ``hvd_mla_expand``: the kernel's spec).
 
 The step (:func:`decode_body`, :func:`prefill_body`): embed the tokens, the
 model's layers through ``stack`` (which calls back for each attention block
@@ -138,6 +141,45 @@ def mla_attend_expanded(cfg: Any, bp: Params, q_nope: jax.Array,
     return o.reshape(o.shape[0], -1).astype(dt)
 
 
+def mla_attend_paged(cfg: Any, bp: Params, q_nope: jax.Array,
+                     q_rope: jax.Array, flat: jax.Array, bt: jax.Array,
+                     start: jax.Array, n_real: jax.Array,
+                     interpret: bool = False) -> jax.Array:
+    """Prefill's attention through the paged kernel
+    (``ops/pallas/mla_prefill``): ``Wkvb`` absorbed as in decode, every
+    head of the chunk's rows against ONE sequence's cached rows, read in
+    place from the flat pool ``flat`` through its block table ``bt`` and
+    only as far as the chunk can see. What :func:`mla_attend_expanded`
+    computes over the gathered pages; returns ``[N, H * v]``."""
+    from horovod_tpu.ops.pallas import mla_prefill
+    dt = cfg.dtype
+    n, h = q_nope.shape[:2]
+    wk, wv = _wkv_b(cfg, bp)
+    with jax.named_scope("hvd_mla_proj"):
+        q_lat = jnp.einsum("nhd,rhd->nhr", q_nope, wk).astype(dt)
+        q = jnp.concatenate([q_lat, q_rope], axis=-1)
+    with jax.named_scope("hvd_attention"):
+        o_lat = mla_prefill.mla_prefill(
+            q.reshape(n * h, -1), flat, bt, start, n_real, heads=h,
+            rank=cfg.kv_lora_rank, scale=cfg.softmax_scale,
+            interpret=interpret)
+    with jax.named_scope("hvd_mla_proj"):
+        o = jnp.einsum("nhr,rhv->nhv", o_lat.reshape(n, h, -1), wv)
+    return o.reshape(n, -1).astype(dt)
+
+
+def _gathered(attend_rows: Callable) -> Callable:
+    """``attend_rows`` over each sequence's pages gathered whole (under
+    ``hvd_attention``), every position of its block table under the mask
+    ``visible``: the step's attention as ``_serve_step`` calls it."""
+    def attend(cfg, bp, q_nope, q_rope, flat, bt, visible):
+        from horovod_tpu.serving import kv_cache as kvc
+        with jax.named_scope("hvd_attention"):
+            rows = kvc.gather_pages(flat, bt)   # [(N,) n_ctx, row]
+        return attend_rows(cfg, bp, q_nope, q_rope, rows, visible)
+    return attend
+
+
 def logits_of(cfg: Any, params: Params, h: jax.Array) -> jax.Array:
     """``N(h) head`` over the rows of the vocabulary held here, float32."""
     x = norm(cfg, h, params["final_norm"]).astype(cfg.dtype)
@@ -166,9 +208,11 @@ def _serve_step(cfg: Any, params: Params, pool: jax.Array,
     ``[N]``, the layers at positions ``pos`` ``[N]`` through the latent
     cache (pool ``[blocks, P+1, page, row]``), each block writing its rows
     through ``write(pages, new, block_tables, scratch)`` and attending with
-    ``mla_attend`` over the gathered pages, each row seeing the cached
-    positions up to its own; the head (of row ``out_row`` only, if given),
-    argmax. The rows ``counted`` go into ``program``'s routing counters."""
+    ``mla_attend(cfg, bp, q_nope, q_rope, flat, block_tables, visible)``
+    over the flat pool, each row seeing the cached positions up to its own
+    (``visible``, over the block tables' positions); the head (of row
+    ``out_row`` only, if given), argmax. The rows ``counted`` go into
+    ``program``'s routing counters."""
     from horovod_tpu.serving import kv_cache as kvc
     n_ctx = block_tables.shape[-1] * pool.shape[2]
     visible = jnp.arange(n_ctx, dtype=jnp.int32)[None, :] <= pos[:, None]
@@ -181,9 +225,7 @@ def _serve_step(cfg: Any, params: Params, pool: jax.Array,
             q_nope, q_rope, row = mla_project(cfg, bp, x, pos)
         with jax.named_scope("hvd_kv_write"):
             flat, = write((flat,), (row,), bt, scratch)
-        with jax.named_scope("hvd_attention"):
-            rows = kvc.gather_pages(flat, bt)   # [(N,) n_ctx, row]
-        return mla_attend(cfg, bp, q_nope, q_rope, rows, visible), flat
+        return mla_attend(cfg, bp, q_nope, q_rope, flat, bt, visible), flat
 
     zero = jnp.zeros((counters.shape[-1],), jnp.int32)
     h, flat, total = stack(cfg, params["layers"], h, flat, zero, attend,
@@ -212,8 +254,8 @@ def decode_body(cfg: Any, params: Params, pool: jax.Array,
                                     scratch=scratch)
 
     return _serve_step(cfg, params, pool, counters, block_tables, tokens,
-                       lengths, lengths > 0, write, mla_attend_absorbed,
-                       moe_lib.DECODE, stack)
+                       lengths, lengths > 0, write,
+                       _gathered(mla_attend_absorbed), moe_lib.DECODE, stack)
 
 
 def prefill_body(cfg: Any, params: Params, pool: jax.Array,
@@ -222,8 +264,12 @@ def prefill_body(cfg: Any, params: Params, pool: jax.Array,
                  stack: Stack):
     """One prefill chunk of ONE sequence: tokens ``[C]`` (bucket-padded) at
     positions ``start ..``, their latent rows written to the pages, causal
-    attention over the cached prefix + the chunk (keys and values expanded
-    from the cached rows), the last real token's logits out."""
+    attention over the cached prefix + the chunk, the last real token's
+    logits out. The attention is the paged kernel's
+    (:func:`mla_attend_paged`) where ``flash_attention.enabled()`` and
+    ``mla_prefill.supports`` allow it, else keys and values expanded from
+    the gathered rows (:func:`mla_attend_expanded`, the kernel's spec)."""
+    from horovod_tpu.ops.pallas import flash_attention as fa, mla_prefill
     from horovod_tpu.serving import kv_cache as kvc
     c = tokens.shape[0]
     pos = start + jnp.arange(c, dtype=jnp.int32)
@@ -232,7 +278,15 @@ def prefill_body(cfg: Any, params: Params, pool: jax.Array,
         return kvc.write_chunk_rows(pages, new, bt, start, n_real,
                                     scratch=scratch)
 
+    mode = fa.enabled()
+    if mode and mla_prefill.supports(cfg.dtype, cfg.kv_lora_rank,
+                                     interpret=mode == "interpret"):
+        def attend(cfg, bp, q_nope, q_rope, flat, bt, visible):
+            return mla_attend_paged(cfg, bp, q_nope, q_rope, flat, bt, start,
+                                    n_real, interpret=mode == "interpret")
+    else:
+        attend = _gathered(mla_attend_expanded)
     return _serve_step(cfg, params, pool, counters, block_table, tokens,
-                       pos, jnp.arange(c) < n_real, write,
-                       mla_attend_expanded, moe_lib.PREFILL, stack,
+                       pos, jnp.arange(c) < n_real, write, attend,
+                       moe_lib.PREFILL, stack,
                        out_row=jnp.maximum(n_real - 1, 0))

@@ -384,6 +384,12 @@ class ServeEngine:
         # queued behind it, by what made it
         self.decode_counts: Dict[str, Any] = {
             "steps": 0, "dispatched_ahead": 0, "drained": {}}
+        # prefill chunks; the pages a chunk's attention can see (its cached
+        # prefix and itself: what the latent models' paged prefill kernel
+        # walks, ``ops/pallas/mla_prefill``) and its block table's width
+        # (what a gather over the table reads), summed over chunks
+        self.prefill_pages: Dict[str, int] = {
+            "chunks": 0, "walked": 0, "table": 0}
         with (contextlib.nullcontext() if self.reload_keeps_layout
               else compile_cache.uncached()):
             self._build()
@@ -664,6 +670,9 @@ class ServeEngine:
         n_real = min(prompt.size - start,
                      self.bucket_for(prompt.size - start))
         bucket = self.bucket_for(n_real)
+        self.prefill_pages["chunks"] += 1
+        self.prefill_pages["walked"] += -(-(start + n_real) // self.page)
+        self.prefill_pages["table"] += self.n_max_pages
         with trace.span(
                 "engine.prefill.dispatch", cat=trace.CAT_SERVE,
                 attrs=({"slot": slot, "start": start, "tokens": n_real,
@@ -940,6 +949,7 @@ class ServeEngine:
             "cow_copies": self.cow_copies,
             "decode": {**self.decode_counts,
                        "drained": dict(self.decode_counts["drained"])},
+            "prefill_pages": dict(self.prefill_pages),
             "draft": self.draft_spec,
             "spec_k": self.spec_k,
             "builds": self.builds,

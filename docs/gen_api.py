@@ -55,6 +55,19 @@ SECTIONS = [
      "experts plus a shared expert in each; served through `ServeEngine` "
      "on the hybrid stack's step with a matrix state a head and slot "
      "(`SolarOpen2Config.serve_model()`); see docs/serving.md."),
+    ("horovod_tpu.models.olmo_hybrid",
+     "Gated DeltaNet hybrid model",
+     "Gated DeltaNet layers (a delta rule with one decay a head and state "
+     "heads of 96 x 192) with one multi-head attention layer with q/k "
+     "norms among every four, a dense SwiGLU after each, every sublayer "
+     "normed after it; served through `ServeEngine` on the hybrid stack's "
+     "step (`OlmoHybridConfig.serve_model()`); see docs/serving.md."),
+    ("horovod_tpu.models.delta_rule",
+     "The gated delta rule",
+     "One implementation of the gated delta rule for the recurrent layers "
+     "that run it (a decay a key channel or one a head, keys and values "
+     "of their own widths): the one-step form, the chunked form, the "
+     "layouts of a slot's state."),
     ("horovod_tpu.models.kimi_k2",
      "Latent-attention expert stack (Kimi K2)",
      "DeepSeek-V3's layer: multi-head latent attention under a "

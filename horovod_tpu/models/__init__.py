@@ -25,6 +25,11 @@ first-class package because the driver benchmarks the framework through them:
                     a head and slot) with one gated grouped-query attention
                     layer among every few, sigmoid-routed experts and a
                     shared expert; served on ``granite_hybrid``'s step.
+- ``olmo_hybrid`` — gated DeltaNet layers (a delta rule with one decay a
+                    head and rectangular state heads) with one full attention
+                    layer among every four, a dense SwiGLU after each, every
+                    sublayer normed after it; served on ``granite_hybrid``'s
+                    step and ``delta_rule``'s rule.
 - ``mla``         — multi-head latent attention over a paged latent cache and
                     the step the latent-attention models serve on.
 - ``kimi_k2``     — the DeepSeek-V3 stack (Kimi K2): latent attention under a
@@ -48,3 +53,4 @@ from horovod_tpu.models.longcat_flash import LongCatFlashConfig  # noqa: F401
 from horovod_tpu.models.granite_hybrid import GraniteHybridConfig  # noqa: F401
 from horovod_tpu.models.solar_open2 import SolarOpen2Config  # noqa: F401
 from horovod_tpu.models.kimi_k2 import KimiK2Config  # noqa: F401
+from horovod_tpu.models.olmo_hybrid import OlmoHybridConfig  # noqa: F401

@@ -54,6 +54,7 @@ like layers. Serving only."""
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -77,11 +78,25 @@ class LayerStack:
     """What a config of a stack whose layers differ in kind answers, from
     its ``layer_types`` and its share of the routed experts: what
     :func:`_serve_step`, :func:`experts` and the engine's records ask of it
-    (Granite's here; ``models/solar_open2.py``'s too)."""
+    (Granite's here; ``models/solar_open2.py``'s and
+    ``models/olmo_hybrid.py``'s too).
+
+    ``post_norm``, a constant of the class: where a sublayer's norm stands
+    (:func:`residual`), before it (``h + r f(N(h))``, Granite's and Solar's)
+    or after it (``h + r N(f(h))``, Olmo 2's order)."""
+
+    post_norm = False
 
     @property
     def n_layers(self) -> int:
         return len(self.layer_types)
+
+    @property
+    def has_experts(self) -> bool:
+        """Routed experts and a shared one after every mixer
+        (``layers["moe"]``, with routing counters); else one dense SwiGLU
+        (``layers["mlp"]``) and no routing counters."""
+        return self.n_routed_experts > 0
 
     @property
     def held_experts(self) -> int:
@@ -459,12 +474,24 @@ def mamba_prefill(cfg: GraniteHybridConfig, mp: Params, u: jax.Array,
 # the attention mixer and the expert block
 # ---------------------------------------------------------------------------
 
-def attention_project(cfg: GraniteHybridConfig, ap: Params, u: jax.Array):
+def _projected(cfg, ap: Params, u: jax.Array, name: str) -> jax.Array:
+    """``u W`` in the config's dtype; where the layer holds a norm of that
+    projection (``q_norm`` / ``k_norm``), the float32 product normed over
+    its whole width first, before the heads split."""
+    dt, norm = cfg.dtype, name[1] + "_norm"
+    if norm not in ap:
+        return u @ ap[name].astype(dt)
+    x = jnp.dot(u, ap[name].astype(dt), preferred_element_type=jnp.float32)
+    return _norm(cfg, x, ap[norm]).astype(dt)
+
+
+def attention_project(cfg: LayerStack, ap: Params, u: jax.Array):
     """q ``[N, H, d]``, k and v ``[N, KVH*d]`` (the pool's row: a token's
-    KV heads side by side) of rows u ``[N, D]``; no rotary."""
-    dt, n = cfg.dtype, u.shape[0]
-    return ((u @ ap["wq"].astype(dt)).reshape(n, cfg.n_heads, cfg.head_dim),
-            u @ ap["wk"].astype(dt), u @ ap["wv"].astype(dt))
+    KV heads side by side) of rows u ``[N, D]``; no rotary. q and k normed
+    where the layer holds ``q_norm`` and ``k_norm``."""
+    n = u.shape[0]
+    q = _projected(cfg, ap, u, "wq").reshape(n, cfg.n_heads, cfg.head_dim)
+    return q, _projected(cfg, ap, u, "wk"), _projected(cfg, ap, u, "wv")
 
 
 def attend_gathered(cfg: GraniteHybridConfig, q: jax.Array,
@@ -510,6 +537,29 @@ def experts(cfg: LayerStack, ep: Params, h: jax.Array,
     with jax.named_scope("hvd_mlp"):
         s = s + swiglu(cfg, ep["shared"], v.astype(cfg.dtype))
     return h + cfg.residual_multiplier * s, counts
+
+
+def residual(cfg: LayerStack, h: jax.Array, scale: jax.Array, sublayer):
+    """One residual sublayer on the stream h ``[N, D]`` (float32) with its
+    norm where the stack puts it (``cfg.post_norm``, the one place it is
+    read): ``h + r f(N(h))`` before, ``h + r N(f(h))`` after. ``sublayer``
+    takes the rows in the config's dtype and returns (its float32 output,
+    whatever else it hands on); so does this, with h in the output's place."""
+    if cfg.post_norm:
+        o, *rest = sublayer(h.astype(cfg.dtype))
+        o = _norm(cfg, o, scale)
+    else:
+        o, *rest = sublayer(_norm(cfg, h, scale).astype(cfg.dtype))
+    return (h + cfg.residual_multiplier * o, *rest)
+
+
+def dense_mlp(cfg: LayerStack, fp: Params, h: jax.Array) -> jax.Array:
+    """The dense half of a layer of a stack without experts: the SwiGLU under
+    ``hvd_mlp``, its norm outside the scope (as the dense block's)."""
+    def mlp(u):
+        with jax.named_scope("hvd_mlp"):
+            return (swiglu(cfg, fp, u),)
+    return residual(cfg, h, fp["norm"], mlp)[0]
 
 
 def logits_of(cfg: LayerStack, params: Params, h: jax.Array) -> jax.Array:
@@ -563,8 +613,12 @@ RESETS, CARRIED, DECODE_ROWS = 0, 1, 2
 
 
 def _counter_state(cfg: LayerStack):
-    return (moe_lib.share_counter_state(cfg.held_experts),
-            jax.ShapeDtypeStruct((2, 1, 3), jnp.uint32))
+    """The routing counters (a stack with experts only), then the recurrent
+    layers'."""
+    recurrent = jax.ShapeDtypeStruct((2, 1, 3), jnp.uint32)
+    if not cfg.has_experts:
+        return (recurrent,)
+    return (moe_lib.share_counter_state(cfg.held_experts), recurrent)
 
 
 def slot_state(cfg: GraniteHybridConfig, slots: int):
@@ -582,13 +636,13 @@ def slot_state(cfg: GraniteHybridConfig, slots: int):
 
 def serve_stats(cfg: LayerStack, state: Tuple[jax.Array, ...]
                 ) -> Dict[str, Any]:
-    """``engine.stats()["moe"]`` and ``["ssm"]``: the counters read back
-    (the one place), published as ``hvd_serve_moe_*`` / ``hvd_serve_ssm_*``
-    gauges. ``state``: the routing counters, the recurrent layers' counters,
-    the convolution tails ``[L, K-1, slots, C]`` and the recurrent state
-    ``[L, slots, ...]``."""
+    """``engine.stats()["moe"]`` (a stack with experts) and ``["ssm"]``:
+    the counters read back (the one place), published as ``hvd_serve_moe_*``
+    / ``hvd_serve_ssm_*`` gauges. ``state``: the routing counters (a stack
+    with experts), the recurrent layers' counters, the convolution tails
+    ``[L, K-1, slots, C]`` and the recurrent state ``[L, slots, ...]``."""
     from horovod_tpu import metrics as M
-    routing, counted, conv, ssm = state
+    *routing, counted, conv, ssm = state
     totals = [int(v) for v in moe_lib.counter_totals(counted)[0]]
     out = {"state_bytes": int(conv.nbytes) + int(ssm.nbytes),
            "slots": int(ssm.shape[1]), "layers": int(ssm.shape[0]),
@@ -605,8 +659,26 @@ def serve_stats(cfg: LayerStack, state: Tuple[jax.Array, ...]
              "state the chunk before stored"),
             ("decode_rows", "Slot states a decode step advanced")):
         M.gauge(f"hvd_serve_ssm_{key}", what).set(out[key])
-    return {**moe_lib.share_routing_stats(routing, cfg.expert_first,
+    if not routing:
+        return {"ssm": out}
+    return {**moe_lib.share_routing_stats(routing[0], cfg.expert_first,
                                           cfg.held_experts), "ssm": out}
+
+
+def resident_bytes(*arrays: jax.Array) -> int:
+    """Bytes the arrays take where they live: each one's minor dimensions
+    padded to its layout's tile (the TPU's ``(8, 128)`` for float32), its
+    own bytes on a device that tiles nothing."""
+    total = 0
+    for a in arrays:
+        layout = a.format.layout
+        dims = list(a.shape)
+        tile = layout.tiling[0] if layout.tiling else ()
+        for axis, t in zip(layout.major_to_minor[-len(tile):] if tile
+                           else (), tile):
+            dims[axis] = -(-dims[axis] // t) * t
+        total += math.prod(dims) * a.dtype.itemsize
+    return total
 
 
 def _serve_step(cfg: LayerStack, params: Params, held: Tuple,
@@ -617,13 +689,15 @@ def _serve_step(cfg: LayerStack, params: Params, held: Tuple,
     ``[N]``, every run of like layers in a scan of its own — an attention
     layer through ``attention(ap, u, flat pool, block tables, scratch)``, a
     layer of the other kind (Mamba-2 here) through ``recurrent(mp, u, conv,
-    ssm, i)``, ``i`` the layer's index among its kind — each followed by the
-    expert block, then the head (of row ``out_row`` only, if given) and
-    argmax. ``held``: the K and V pools, the routing and state-space
-    counters, the slot state."""
+    ssm, i)``, ``i`` the layer's index among its kind — each a residual
+    sublayer (:func:`residual`) followed by the expert block, or the dense
+    SwiGLU where the stack has no experts, then the head (of row ``out_row``
+    only, if given) and argmax. ``held``: the K and V pools, the routing
+    counters (a stack with experts), the state-space counters, the slot
+    state."""
     from horovod_tpu.serving import kv_cache as kvc
-    k_pages, v_pages, routing, ssm_counters, conv, ssm = held
-    dt, layers = cfg.dtype, params["layers"]
+    k_pages, v_pages, *routing, ssm_counters, conv, ssm = held
+    layers = params["layers"]
     h = params["embed"][tokens].astype(jnp.float32) \
         * cfg.embedding_multiplier                              # [N, D]
 
@@ -632,14 +706,21 @@ def _serve_step(cfg: LayerStack, params: Params, held: Tuple,
             h, flat, conv, ssm, total = carry
             li, ki = index
             mp = jax.tree.map(lambda a: a[ki], layers[kind])
-            u = _norm(cfg, h, mp["norm"]).astype(dt)
-            if kind == ATTENTION:
-                bt, scratch = kvc.block_pages(k_pages.shape, ki,
-                                              block_tables)
-                o, flat = attention(mp, u, flat, bt, scratch)
-            else:
-                o, conv, ssm = recurrent(mp, u, conv, ssm, ki)
-            h = h + cfg.residual_multiplier * o
+
+            def mixer(u):
+                if kind == ATTENTION:
+                    bt, scratch = kvc.block_pages(k_pages.shape, ki,
+                                                  block_tables)
+                    o, pool = attention(mp, u, flat, bt, scratch)
+                    return o, pool, conv, ssm
+                o, state_conv, state = recurrent(mp, u, conv, ssm, ki)
+                return o, flat, state_conv, state
+
+            h, flat, conv, ssm = residual(cfg, h, mp["norm"], mixer)
+            if not cfg.has_experts:
+                h = dense_mlp(cfg, jax.tree.map(lambda a: a[li],
+                                                layers["mlp"]), h)
+                return (h, flat, conv, ssm, total), None
             h, counts = experts(
                 cfg, jax.tree.map(lambda a: a[li], layers["moe"]), h,
                 counted)
@@ -647,7 +728,8 @@ def _serve_step(cfg: LayerStack, params: Params, held: Tuple,
         return body
 
     carry = (h, kvc.flat_pool(k_pages, v_pages), conv, ssm,
-             jnp.zeros((routing.shape[-1],), jnp.int32))
+             jnp.zeros((routing[0].shape[-1],), jnp.int32) if routing
+             else ())
     for kind, first, first_of_kind, n in cfg.runs():
         steps = jnp.arange(n, dtype=jnp.int32)
         carry, _ = lax.scan(run_of(kind), carry,
@@ -658,7 +740,7 @@ def _serve_step(cfg: LayerStack, params: Params, held: Tuple,
     logits = logits_of(cfg, params, h)
     next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return (*(p.reshape(k_pages.shape) for p in flat),
-            moe_lib.add_share_counts(routing, total, program),
+            *(moe_lib.add_share_counts(r, total, program) for r in routing),
             moe_lib.add_share_counts(ssm_counters, ssm_counts, 0),
             conv, ssm, next_tokens, logits)
 
@@ -682,12 +764,13 @@ def _attention_out(cfg, ap, o):
 
 def decode_body(cfg: LayerStack, params: Params, *args, recurrent=None):
     """One decode step over all slots: ``(k_pages, v_pages, routing
-    counters, state-space counters, conv, ssm, block_tables, lengths,
-    tokens)``. The slots with ``lengths > 0`` decode: their K and V rows go
-    to their pages, their recurrent state advances by one token. Every
-    other slot (empty, or mid-prefill) writes to the scratch page and keeps
-    its state bit for bit. ``recurrent``: the other kind of layer's decode
-    (``mamba_decode``'s signature; that by default)."""
+    counters (a stack with experts), state-space counters, conv, ssm,
+    block_tables, lengths, tokens)``. The slots with ``lengths > 0``
+    decode: their K and V rows go to their pages, their recurrent state
+    advances by one token. Every other slot (empty, or mid-prefill) writes
+    to the scratch page and keeps its state bit for bit. ``recurrent``: the
+    other kind of layer's decode (``mamba_decode``'s signature; that by
+    default)."""
     from horovod_tpu.serving import kv_cache as kvc
     *held, block_tables, lengths, tokens = args
     live = lengths > 0
@@ -715,8 +798,8 @@ def decode_body(cfg: LayerStack, params: Params, *args, recurrent=None):
 
 def prefill_body(cfg: LayerStack, params: Params, *args, recurrent=None):
     """One prefill chunk of ONE sequence, told its slot: ``(k_pages,
-    v_pages, routing counters, state-space counters, conv, ssm,
-    block_table, slot, start, n_real, tokens)``; tokens ``[C]``
+    v_pages, routing counters (a stack with experts), state-space counters,
+    conv, ssm, block_table, slot, start, n_real, tokens)``; tokens ``[C]``
     (bucket-padded) at positions ``start ..``. The attention layers write
     the chunk's K and V rows to the pages and attend over the cached prefix
     and the chunk; the recurrent layers (``recurrent``: ``mamba_prefill``'s

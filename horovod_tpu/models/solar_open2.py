@@ -39,18 +39,13 @@ and v side by side), both float32 (``ServeModel.slot_state``). Padding never
 moves the state: a row past ``n_real`` (or an idle slot) takes ``a = 0`` and
 ``b = 0``, so its decay is 1 and it adds nothing.
 
-**Decode is the one-step recurrence** (:func:`kda_step`), written so that
-the stored states are read once for both ``S'^T k^`` and ``S'^T q^`` and
-once more for the rank-one write. **Prefill is the chunked rule**
-(:func:`kda_chunk_scan`): inside a chunk of ``kda_chunk`` rows, with ``G``
-the running sum of ``a``, the unit lower-triangular system ``I +
-strict_tril(b_i (k^_i e^{G_i}) . (k^_j e^{-G_j}))`` is solved once for ``W``
-(right-hand side ``b k^ e^G``) and ``U`` (``b v``); then ``o = (q^ e^G) S +
-tril((q^ e^G)(k^ e^{-G})^T)(U - W S)`` and ``S' = e^{G_C} S + (k^ e^{G_C -
-G})^T (U - W S)``. ``e^{-G}`` alone overflows where the decay is strong, so
-the two triangular matrices are built from differences ``G_i - G_j`` in
-sub-blocks of 16 rows: pair by pair inside a sub-block, through the
-sub-block's first row across sub-blocks. All float32 at ``highest``.
+**The delta rule is** :mod:`~horovod_tpu.models.delta_rule`'s, the one the
+gated DeltaNet layer of ``olmo_hybrid`` runs too: decode its one-step form
+(``kda_step``) on the states as stored, ``[H, d, d]`` a slot; prefill its
+chunked form (:func:`kda_chunk_scan`, chunks of ``kda_chunk`` rows) with the
+triangular matrices of a decay a key channel, built from differences ``G_i
+- G_j`` in sub-blocks of 16 rows (``e^{-G}`` alone overflows where the decay
+is strong). All float32 at ``highest``.
 
 The share of the routed experts held here is ``expert_first`` /
 ``expert_count`` (:mod:`horovod_tpu.parallel.moe`). Serving only."""
@@ -65,7 +60,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.models import granite_hybrid as stack
+from horovod_tpu.models import delta_rule, granite_hybrid as stack
+from horovod_tpu.models.delta_rule import beta_of, step as kda_step, unit
 from horovod_tpu.models.granite_hybrid import (
     ATTENTION, STATE_DTYPE, LayerStack)
 from horovod_tpu.models.transformer import _rmsnorm
@@ -74,8 +70,6 @@ from horovod_tpu.parallel import moe as moe_lib
 Params = Dict[str, Any]
 
 KDA = "kda"
-SUB_BLOCK = 16                  # rows of a sub-block of a chunk
-L2_EPS = 1e-6                   # under the root of q's and k's norms
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,12 +202,6 @@ def kda_project(cfg: SolarOpen2Config, mp: Params, u: jax.Array):
     return qkv, f, beta, gate
 
 
-def beta_of(raw: jax.Array) -> jax.Array:
-    """``2 sigmoid``: in (0, 2), so a transition's eigenvalue along ``k^``
-    may be negative (``kda_allow_neg_eigval``)."""
-    return 2.0 * jax.nn.sigmoid(raw)
-
-
 def kda_inputs(cfg: SolarOpen2Config, mp: Params, qkv: jax.Array,
                f: jax.Array, beta: jax.Array, live: jax.Array):
     """From the convolved rows qkv ``[N, 3 H d]`` and the raw gates: q^ (unit
@@ -223,10 +211,6 @@ def kda_inputs(cfg: SolarOpen2Config, mp: Params, qkv: jax.Array,
     adds nothing)."""
     h, d = cfg.kda_n_heads, cfg.kda_head_dim
     q, k, v = (x.reshape(-1, h, d) for x in jnp.split(qkv, 3, axis=-1))
-
-    def unit(x):
-        return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
-
     rate = jnp.exp(mp["A_log"].astype(jnp.float32))[None, :, None]
     a = -rate * jax.nn.softplus(
         (f + mp["dt_bias"].astype(jnp.float32)).reshape(-1, h, d))
@@ -234,109 +218,11 @@ def kda_inputs(cfg: SolarOpen2Config, mp: Params, qkv: jax.Array,
             beta_of(beta) * live[:, None])
 
 
-def kda_step(q, k, v, a, b, s):
-    """The one-step recurrence on one row a slot: q, k, v, a ``[T, H, d]``,
-    b ``[T, H]``, the states s ``[T, H, d, d]`` (a key channel's row of
-    values on the lanes). Returns (o ``[T, H, d]``, the new states).
-    float32, elementwise: no product's rounding touches the state. The
-    states are read ONCE for both sums with the old state, ``S'^T k^`` and
-    ``S'^T q^`` (``o = S_t^T q^ = S'^T q^ + b (k^ . q^) (v - S'^T k^)``),
-    and once more for the write."""
-    decay = jnp.exp(a)
-    from_k = jnp.sum(s * (k * decay)[..., None], axis=2)    # S'^T k^
-    from_q = jnp.sum(s * (q * decay)[..., None], axis=2)    # S'^T q^
-    u = b[..., None] * (v - from_k)
-    o = from_q + jnp.sum(k * q, axis=-1, keepdims=True) * u
-    s = decay[..., None] * s + k[..., None] * u[:, :, None, :]
-    return o, s
-
-
-def _chunk_matrices(q, k, g):
-    """The two lower-triangular matrices of chunks ``[N, C, ...]``: ``sum_c
-    r_ic k_jc exp(G_ic - G_jc)`` for ``j <= i`` with r = k and r = q, ``[2,
-    H, N, C, C]``, from q, k and the running log-decay G ``[N, C, H, d]``.
-    No exponent is positive: inside a sub-block of ``SUB_BLOCK`` rows the
-    differences are taken pair by pair; across sub-blocks both factors are
-    taken relative to the later sub-block's first row, ``exp(G_i - G_n)
-    exp(G_n - G_j)`` with ``j < n <= i``, and the sum over the channels is a
-    product on the matrix unit."""
-    hi = lax.Precision.HIGHEST
-    n, c, h, d = k.shape
-    sub = min(SUB_BLOCK, c)
-    nb = c // sub
-
-    def blocks(x):
-        return x.reshape(n, nb, sub, h, d)
-
-    gb, kb = blocks(g), blocks(k)
-    kq = jnp.stack([kb, blocks(q)])                     # [2, N, nb, sub, ..]
-    first = gb[:, :, 0]                                 # [N, nb, H, d]
-    rows = kq * jnp.exp(gb - first[:, :, None])
-    keys = k[:, None] * jnp.exp(jnp.minimum(
-        first[:, :, None] - g[:, None], 0.0))           # [N, nb, C, H, d]
-    across = jnp.einsum("xnIbhd,nIjhd->xhnIbj", rows, keys, precision=hi)
-    earlier = (jnp.arange(c)[None, :] // sub) < jnp.arange(nb)[:, None]
-    across = jnp.where(earlier[:, None, :], across, 0.0).reshape(
-        2, h, n, c, c)
-    i = jnp.arange(sub)
-    seen = (i[:, None] >= i[None, :])[:, :, None, None]     # j <= i
-    pair = jnp.where(seen, jnp.exp(jnp.where(
-        seen, gb[:, :, :, None] - gb[:, :, None, :], 0.0)), 0.0)
-    within = jnp.sum(                                   # [2, N, nb, i, j, H]
-        kq[:, :, :, :, None] * pair[None] * kb[None, :, :, None], axis=-1)
-    within = jnp.einsum("xnIbjh,IJ->xhnIbJj", within,
-                        jnp.eye(nb, dtype=within.dtype))
-    return across + within.reshape(2, h, n, c, c)
-
-
 def kda_chunk_scan(q, k, v, a, b, s, chunk: int):
-    """The chunked delta rule over the rows of ONE sequence (q, k, v, a
-    ``[R, H, d]``, b ``[R, H]``; R a multiple of ``chunk`` or at most it)
-    from the state s ``[H, d, d]``: the recurrence of :func:`kda_step` row
-    after row, computed a chunk at a time. What does not depend on the state
-    (the triangular system and its solution) is computed for all chunks at
-    once; the state is carried from chunk to chunk. Returns (o ``[R, H,
-    d]``, the state after the last row)."""
-    hi = lax.Precision.HIGHEST
-    rows, h, d = k.shape
-    c = min(chunk, rows)
-    if rows % c or c % min(SUB_BLOCK, c):
-        raise ValueError(
-            f"{rows} rows are no whole number of chunks of {chunk} rows in "
-            f"sub-blocks of {SUB_BLOCK}")
-    n = rows // c
-    q, k, v, a = (x.reshape(n, c, h, d) for x in (q, k, v, a))
-    g = jnp.cumsum(a, axis=1)                           # [N, C, H, d], <= 0
-    kk, qk = _chunk_matrices(q, k, g)                   # [H, N, C, C] each
-
-    def per_head(x):                                    # [N, C, H, ...]
-        return jnp.moveaxis(x, 2, 0)                    # [H, N, C, ...]
-
-    bh = per_head(b.reshape(n, c, h))
-    system = bh[..., None] * jnp.tril(kk, -1)
-    grown = jnp.exp(g)
-
-    rhs = bh[..., None] * jnp.concatenate(
-        [per_head(k * grown), per_head(v)], axis=-1)
-    wu = lax.linalg.triangular_solve(
-        system, rhs, left_side=True, lower=True, unit_diagonal=True)
-    to_end = per_head(k * jnp.exp(g[:, -1:] - g))       # k^ e^{G_C - G}
-    last = jnp.moveaxis(grown[:, -1], 1, 0)             # [H, N, d]
-
-    def one(s, xs):
-        w, u, q_in, qk, to_end, last = xs
-        delta = u - jnp.einsum("hck,hkv->hcv", w, s, precision=hi)
-        o = jnp.einsum("hck,hkv->hcv", q_in, s, precision=hi) \
-            + jnp.einsum("hcj,hjv->hcv", qk, delta, precision=hi)
-        s = last[..., None] * s + jnp.einsum(
-            "hck,hcv->hkv", to_end, delta, precision=hi)
-        return s, o
-
-    by_chunk = jax.tree.map(
-        lambda x: jnp.moveaxis(x, 1, 0),
-        (wu[..., :d], wu[..., d:], per_head(q * grown), qk, to_end, last))
-    s, o = lax.scan(one, s, by_chunk)                   # o [N, H, C, d]
-    return jnp.moveaxis(o, 1, 2).reshape(rows, h, d), s
+    """The shared chunked rule (``delta_rule.chunk_scan``) with a decay a
+    key channel: the triangular matrices from sub-blocks."""
+    return delta_rule.chunk_scan(q, k, v, a, b, s, chunk,
+                                 delta_rule.per_channel_matrices)
 
 
 def kda_gate_out(cfg: SolarOpen2Config, mp: Params, o: jax.Array,

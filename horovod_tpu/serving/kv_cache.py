@@ -583,7 +583,7 @@ def write_chunk_pages(pages: Sequence[jax.Array], new: Sequence[jax.Array],
         placed = jax.lax.dynamic_update_slice(
             jnp.zeros(lead, rows.dtype), rows,
             (start - first * page,) + (0,) * (rows.ndim - 1))
-        held = jnp.take(p, phys, axis=0)              # [n, page, *row]
+        held = take_pages(p, phys)                    # [n, page, *row]
         merged = jnp.where(real.reshape((-1,) + (1,) * (rows.ndim - 1)),
                            placed, held.reshape(lead))
         return p.at[phys].set(merged.reshape(held.shape))
@@ -603,6 +603,30 @@ def copy_page(*pages_src_dst: jax.Array) -> Tuple[jax.Array, ...]:
     return tuple(p.at[:, dst].set(p[:, src]) for p in pages)
 
 
+# the largest slice the TPU compiler gathers in place: a larger one it splits
+# into gathers over slices of the WHOLE operand, each slice a copy of its
+# share of the pool, every call (a page of 128 rows of 3840 bfloat16 numbers
+# is 960 KiB: compile-only for a v5e)
+GATHER_SLICE_BYTES = 512 * 1024
+
+
+def take_pages(pages: jax.Array, table: jax.Array) -> jax.Array:
+    """``pages[table]`` along the first axis (``[..., n, page, *row]``), read
+    in place. A page over ``GATHER_SLICE_BYTES`` is taken as ``k`` pieces of
+    ``page / k`` rows from the pool seen as ``[P * k, page / k, *row]`` (the
+    same bytes in the pool's row-major layout)."""
+    k, page = 1, pages.shape[1]
+    page_bytes = int(np.prod(pages.shape[1:])) * pages.dtype.itemsize
+    while page_bytes > k * GATHER_SLICE_BYTES and page % (2 * k) == 0:
+        k *= 2
+    if k == 1:
+        return jnp.take(pages, table, axis=0)
+    pieces = pages.reshape((pages.shape[0] * k, page // k) + pages.shape[2:])
+    index = (table[..., None] * k + jnp.arange(k, dtype=table.dtype))
+    taken = jnp.take(pieces, index.reshape(table.shape[:-1] + (-1,)), axis=0)
+    return taken.reshape(table.shape + pages.shape[1:])
+
+
 def gather_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
     """Contiguous ``[n_max*page, *row]`` view of one sequence's pages
     (one block of the pool; the dense block's row is ``KVH*D``) in
@@ -610,7 +634,7 @@ def gather_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
     compute-bound; the gather copy is irrelevant there, unlike at decode
     where the kernel follows the table in place). With block tables
     ``[B, n_max]``: ``[B, n_max*page, *row]``, one view per sequence."""
-    g = jnp.take(pages, block_table, axis=0)      # [.., n_max, page, *row]
+    g = take_pages(pages, block_table)            # [.., n_max, page, *row]
     lead = block_table.shape[:-1]
     return g.reshape(lead + (-1,) + g.shape[len(lead) + 2:])
 

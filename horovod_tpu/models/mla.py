@@ -24,13 +24,16 @@ rotary).
 scale) and ``k_r`` (after rotary)**: ``kv_lora_rank + rope`` numbers, one
 pool array of one latent row a token (:func:`cache_rows`). A decode step
 absorbs ``Wkvb`` (``q_lat = q_nope Wkvb_k^T``, ``out = (p c) Wkvb_v``:
-:func:`mla_attend_absorbed`) and reads only the latent rows, over each
-slot's gathered pages, every one of its ``max_seq`` positions under a mask
-of those it may see. A prefill chunk absorbs ``Wkvb`` too and reads the
-pages in place, only those the chunk can see, through the paged kernel
-``hvd_mla_prefill`` (:func:`mla_attend_paged`); where no kernel runs, it
-expands ``k_nope`` and ``v`` from the gathered ``c``
-(:func:`mla_attend_expanded`, scope ``hvd_mla_expand``: the kernel's spec).
+:func:`mla_attend_absorbed`) and reads only the latent rows, each slot's in
+place and only the pages up to its length, through the paged kernel
+``hvd_mla_decode`` (:func:`mla_attend_paged_decode`); where no kernel runs,
+over each slot's gathered pages, every one of its ``max_seq`` positions
+under a mask of those it may see (the kernel's spec). A prefill chunk
+absorbs ``Wkvb`` too and reads the pages in place, only those the chunk can
+see, through the paged kernel ``hvd_mla_prefill`` (:func:`mla_attend_paged`);
+where no kernel runs, it expands ``k_nope`` and ``v`` from the gathered
+``c`` (:func:`mla_attend_expanded`, scope ``hvd_mla_expand``: the kernel's
+spec).
 
 The step (:func:`decode_body`, :func:`prefill_body`): embed the tokens, the
 model's layers through ``stack`` (which calls back for each attention block
@@ -141,6 +144,24 @@ def mla_attend_expanded(cfg: Any, bp: Params, q_nope: jax.Array,
     return o.reshape(o.shape[0], -1).astype(dt)
 
 
+def _absorbed_through(cfg: Any, bp: Params, q_nope: jax.Array,
+                      q_rope: jax.Array, kernel: Callable) -> jax.Array:
+    """``Wkvb`` absorbed into the query rows ``[N*H, rank + rope]`` that a
+    paged latent kernel takes (``kernel(q)`` -> ``o_lat`` ``[N*H, rank]``,
+    under ``hvd_attention``) and into its output: returns ``[N, H * v]``."""
+    dt = cfg.dtype
+    n, h = q_nope.shape[:2]
+    wk, wv = _wkv_b(cfg, bp)
+    with jax.named_scope("hvd_mla_proj"):
+        q_lat = jnp.einsum("nhd,rhd->nhr", q_nope, wk).astype(dt)
+        q = jnp.concatenate([q_lat, q_rope], axis=-1)
+    with jax.named_scope("hvd_attention"):
+        o_lat = kernel(q.reshape(n * h, -1))
+    with jax.named_scope("hvd_mla_proj"):
+        o = jnp.einsum("nhr,rhv->nhv", o_lat.reshape(n, h, -1), wv)
+    return o.reshape(n, -1).astype(dt)
+
+
 def mla_attend_paged(cfg: Any, bp: Params, q_nope: jax.Array,
                      q_rope: jax.Array, flat: jax.Array, bt: jax.Array,
                      start: jax.Array, n_real: jax.Array,
@@ -152,20 +173,29 @@ def mla_attend_paged(cfg: Any, bp: Params, q_nope: jax.Array,
     only as far as the chunk can see. What :func:`mla_attend_expanded`
     computes over the gathered pages; returns ``[N, H * v]``."""
     from horovod_tpu.ops.pallas import mla_prefill
-    dt = cfg.dtype
-    n, h = q_nope.shape[:2]
-    wk, wv = _wkv_b(cfg, bp)
-    with jax.named_scope("hvd_mla_proj"):
-        q_lat = jnp.einsum("nhd,rhd->nhr", q_nope, wk).astype(dt)
-        q = jnp.concatenate([q_lat, q_rope], axis=-1)
-    with jax.named_scope("hvd_attention"):
-        o_lat = mla_prefill.mla_prefill(
-            q.reshape(n * h, -1), flat, bt, start, n_real, heads=h,
+    return _absorbed_through(
+        cfg, bp, q_nope, q_rope, lambda q: mla_prefill.mla_prefill(
+            q, flat, bt, start, n_real, heads=q_nope.shape[1],
             rank=cfg.kv_lora_rank, scale=cfg.softmax_scale,
-            interpret=interpret)
-    with jax.named_scope("hvd_mla_proj"):
-        o = jnp.einsum("nhr,rhv->nhv", o_lat.reshape(n, h, -1), wv)
-    return o.reshape(n, -1).astype(dt)
+            interpret=interpret))
+
+
+def mla_attend_paged_decode(cfg: Any, bp: Params, q_nope: jax.Array,
+                            q_rope: jax.Array, flat: jax.Array,
+                            bt: jax.Array, lengths: jax.Array,
+                            interpret: bool = False) -> jax.Array:
+    """Decode's attention through the paged kernel
+    (``ops/pallas/mla_decode``): each slot's heads against its own cached
+    rows, read in place from the flat pool through its block table ``bt``
+    ``[N, n_max]``, positions ``0 .. lengths[n]`` only. What
+    :func:`mla_attend_absorbed` computes over the gathered pages; returns
+    ``[N, H * v]``."""
+    from horovod_tpu.ops.pallas import mla_decode
+    return _absorbed_through(
+        cfg, bp, q_nope, q_rope, lambda q: mla_decode.mla_decode(
+            q, flat, bt, lengths, rank=cfg.kv_lora_rank,
+            scale=cfg.softmax_scale,
+            interpret=interpret))
 
 
 def _gathered(attend_rows: Callable) -> Callable:
@@ -239,13 +269,28 @@ def _serve_step(cfg: Any, params: Params, pool: jax.Array,
             next_tokens, logits)
 
 
+def _kernel_mode(cfg: Any) -> Optional[bool]:
+    """Whether the step bodies attend through the paged kernels: None where
+    ``flash_attention.enabled()`` or the kernels' ``supports`` say no, else
+    whether they run interpreted."""
+    from horovod_tpu.ops.pallas import flash_attention as fa, mla_prefill
+    mode = fa.enabled()
+    if mode and mla_prefill.supports(cfg.dtype, cfg.kv_lora_rank,
+                                     interpret=mode == "interpret"):
+        return mode == "interpret"
+    return None
+
+
 def decode_body(cfg: Any, params: Params, pool: jax.Array,
                 counters: jax.Array, block_tables: jax.Array,
                 lengths: jax.Array, tokens: jax.Array, *, stack: Stack):
     """One decode step over all slots through the latent cache, ``Wkvb``
     absorbed (each slot over its own pages). Empty slots carry length 0
     and scratch block tables; their rows sink into the scratch page and
-    are not counted (a served slot has its prompt cached)."""
+    are not counted (a served slot has its prompt cached). The attention is
+    the paged kernel's (:func:`mla_attend_paged_decode`) where
+    :func:`_kernel_mode` allows it, else the gathered pages under the mask
+    (:func:`mla_attend_absorbed`, the kernel's spec)."""
     from horovod_tpu.serving import kv_cache as kvc
     valid = lengths < block_tables.shape[1] * pool.shape[2]
 
@@ -253,9 +298,16 @@ def decode_body(cfg: Any, params: Params, pool: jax.Array,
         return kvc.write_token_rows(pages, new, bt, lengths, valid=valid,
                                     scratch=scratch)
 
+    interpret = _kernel_mode(cfg)
+    if interpret is not None:
+        def attend(cfg, bp, q_nope, q_rope, flat, bt, visible):
+            return mla_attend_paged_decode(cfg, bp, q_nope, q_rope, flat, bt,
+                                           lengths, interpret=interpret)
+    else:
+        attend = _gathered(mla_attend_absorbed)
     return _serve_step(cfg, params, pool, counters, block_tables, tokens,
-                       lengths, lengths > 0, write,
-                       _gathered(mla_attend_absorbed), moe_lib.DECODE, stack)
+                       lengths, lengths > 0, write, attend, moe_lib.DECODE,
+                       stack)
 
 
 def prefill_body(cfg: Any, params: Params, pool: jax.Array,
@@ -266,10 +318,9 @@ def prefill_body(cfg: Any, params: Params, pool: jax.Array,
     positions ``start ..``, their latent rows written to the pages, causal
     attention over the cached prefix + the chunk, the last real token's
     logits out. The attention is the paged kernel's
-    (:func:`mla_attend_paged`) where ``flash_attention.enabled()`` and
-    ``mla_prefill.supports`` allow it, else keys and values expanded from
-    the gathered rows (:func:`mla_attend_expanded`, the kernel's spec)."""
-    from horovod_tpu.ops.pallas import flash_attention as fa, mla_prefill
+    (:func:`mla_attend_paged`) where :func:`_kernel_mode` allows it, else
+    keys and values expanded from the gathered rows
+    (:func:`mla_attend_expanded`, the kernel's spec)."""
     from horovod_tpu.serving import kv_cache as kvc
     c = tokens.shape[0]
     pos = start + jnp.arange(c, dtype=jnp.int32)
@@ -278,12 +329,11 @@ def prefill_body(cfg: Any, params: Params, pool: jax.Array,
         return kvc.write_chunk_rows(pages, new, bt, start, n_real,
                                     scratch=scratch)
 
-    mode = fa.enabled()
-    if mode and mla_prefill.supports(cfg.dtype, cfg.kv_lora_rank,
-                                     interpret=mode == "interpret"):
+    interpret = _kernel_mode(cfg)
+    if interpret is not None:
         def attend(cfg, bp, q_nope, q_rope, flat, bt, visible):
             return mla_attend_paged(cfg, bp, q_nope, q_rope, flat, bt, start,
-                                    n_real, interpret=mode == "interpret")
+                                    n_real, interpret=interpret)
     else:
         attend = _gathered(mla_attend_expanded)
     return _serve_step(cfg, params, pool, counters, block_table, tokens,

@@ -27,9 +27,11 @@ Grid ``(query blocks, steps)``: a query block is ``block_pos`` chunk
 positions, every head of each (``block_pos * H`` rows, row ``r`` at position
 ``start + r // H``); a step takes ``pages_per_step`` pages of the table, one
 under another in VMEM. A block walks its pages ``0 .. last`` where ``last``
-holds the last position any of its rows may see; the index maps clamp later
-pages to ``last``, whose block index then does not change, so nothing more
-is fetched, and a step with no page to see does not run. Every step that
+holds the last position any of its rows may see; past it each of a step's
+pages repeats the last live page it read (:func:`page_index`), whose block
+index then does not change, so nothing more is fetched, and a step with no
+page to see does not run. The walk (:func:`walk`) is the latent decode
+kernel's too (``mla_decode``, ``hvd_mla_decode``). Every step that
 runs applies the causal mask (a compare and a select over its scores: on the
 chip as fast as a second, unmasked body for the steps under the diagonal,
 and half the code for the compiler). Positions at or past ``start +
@@ -77,15 +79,21 @@ def _last_seen(qi, start, n_real, block_pos: int):
     return start + jnp.minimum((qi + 1) * block_pos, n_real) - 1
 
 
-def _prefill_kernel(bt_ref, pos_ref, q_ref, *refs, scale: float, heads: int,
-                    rank: int, block_pos: int, precision):
-    *page_refs, o_ref, m_scr, l_scr, acc_scr = refs
-    qi, j = pl.program_id(0), pl.program_id(1)
-    start, n_real = pos_ref[0], pos_ref[1]
+def walk(q_ref, page_refs, o_ref, m_scr, l_scr, acc_scr, *, seen, visible,
+         scale: float, rank: int, precision) -> None:
+    """The masked online-softmax walk of both latent kernels (this one and
+    ``mla_decode``'s): grid axis 1 steps over a table's pages, ``len(
+    page_refs)`` a step, one under another in VMEM. A step whose first key
+    is past ``seen``, the last key any row of the block sees, does not run;
+    a step that runs masks its scores to the keys at or before ``seen`` (a
+    page past it is the clamped index map's repeat of a live one) for which
+    ``visible(k)`` holds (``None``: all of them), then takes one product for
+    the scores and one for the sums, with one rescale of the float32
+    accumulator. The last step writes the normalised result."""
+    j = pl.program_id(1)
     tq = q_ref.shape[0]
     keys = len(page_refs) * page_refs[0].shape[1]       # a step's keys
     k0 = j * keys
-    seen = _last_seen(qi, start, n_real, block_pos)
 
     @pl.when(j == 0)
     def _init():
@@ -95,20 +103,16 @@ def _prefill_kernel(bt_ref, pos_ref, q_ref, *refs, scale: float, heads: int,
 
     @pl.when(k0 <= seen)
     def _step():
-        # the step's pages one under another: one product for the scores
-        # and one for the sums, one rescale of the accumulator
         rows = jnp.concatenate([r[0] for r in page_refs], axis=0) \
             if len(page_refs) > 1 else page_refs[0][0]  # [keys, rank+rope]
         s = jax.lax.dot_general(
             q_ref[...], rows, _NT, precision=precision,
             preferred_element_type=jnp.float32) * scale   # [tq, keys]
         k = k0 + jax.lax.broadcasted_iota(jnp.int32, (tq, keys), 1)
-        r = qi * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, keys), 0)
-        # row r's position start + r // heads sees key k <= it, which is
-        # r >= (k - start) * heads; padding rows see what the last real row
-        # sees; a page past the last one seen (the clamped index map's
-        # repeat of it) is seen by none
-        s = jnp.where((r >= (k - start) * heads) & (k <= seen), s, NEG_INF)
+        mask = k <= seen
+        if visible is not None:
+            mask = visible(k) & mask
+        s = jnp.where(mask, s, NEG_INF)
         m_prev = m_scr[...]                             # [tq, 1]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_next)          # page 0's first key: m is finite
@@ -123,6 +127,45 @@ def _prefill_kernel(bt_ref, pos_ref, q_ref, *refs, scale: float, heads: int,
     def _finalize():
         o_ref[...] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
                       ).astype(o_ref.dtype)
+
+
+def page_index(j, i: int, g: int, last):
+    """The table entry the ``i``-th of a step's ``g`` pages reads at step
+    ``j`` of a walk that sees pages ``0 .. last``: page ``j*g + i`` while
+    that is live; past it, the last live page that same input read (or
+    ``last``, where it read none), so its block index repeats and nothing
+    more is fetched."""
+    return jnp.where(i <= last, jnp.minimum(j, (last - i) // g) * g + i,
+                     last)
+
+
+def compiler_kwargs(interpret: bool) -> dict:
+    """Both latent kernels' compiler parameters: the first grid axis
+    independent blocks, the second the walk; VMEM for the largest tiling."""
+    if interpret:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT)}
+
+
+def _prefill_kernel(bt_ref, pos_ref, q_ref, *refs, scale: float, heads: int,
+                    rank: int, block_pos: int, precision):
+    *page_refs, o_ref, m_scr, l_scr, acc_scr = refs
+    qi = pl.program_id(0)
+    start, n_real = pos_ref[0], pos_ref[1]
+    tq = q_ref.shape[0]
+
+    def visible(k):
+        # row r's position start + r // heads sees key k <= it, which is
+        # r >= (k - start) * heads; padding rows see what the last real row
+        # sees (``seen``)
+        r = qi * tq + jax.lax.broadcasted_iota(jnp.int32, k.shape, 0)
+        return r >= (k - start) * heads
+
+    walk(q_ref, page_refs, o_ref, m_scr, l_scr, acc_scr,
+         seen=_last_seen(qi, start, n_real, block_pos), visible=visible,
+         scale=scale, rank=rank, precision=precision)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -158,15 +201,10 @@ def mla_prefill(q: jax.Array, pages: jax.Array, block_table: jax.Array,
         def index(qi, j, bt, pos):
             last = jnp.clip(_last_seen(qi, pos[0], pos[1], block_pos) // page,
                             0, n_max - 1)
-            return bt[jnp.minimum(j * g + i, last)], 0, 0
+            return bt[page_index(j, i, g, last)], 0, 0
         return pl.BlockSpec((1, page, width), index)
 
     rows_spec = lambda w: pl.BlockSpec((tq, w), lambda qi, j, bt, pos: (qi, 0))
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT)
     return pl.pallas_call(
         kernel,
         name="hvd_mla_prefill",
@@ -183,7 +221,7 @@ def mla_prefill(q: jax.Array, pages: jax.Array, block_table: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((rows, rank), q.dtype),
         interpret=interpret,
-        **kwargs,
+        **compiler_kwargs(interpret),
     )(block_table.astype(jnp.int32),
       jnp.stack([jnp.asarray(start, jnp.int32),
                  jnp.asarray(n_real, jnp.int32)]),

@@ -390,6 +390,12 @@ class ServeEngine:
         # (what a gather over the table reads), summed over chunks
         self.prefill_pages: Dict[str, int] = {
             "chunks": 0, "walked": 0, "table": 0}
+        # decode steps; the pages each live slot's attention can see (its
+        # cached tokens and the new one: what the latent models' paged decode
+        # kernel walks, ``ops/pallas/mla_decode``) and every slot's block
+        # table (what a gather over the tables reads), summed over steps
+        self.decode_pages: Dict[str, int] = {
+            "steps": 0, "walked": 0, "table": 0}
         with (contextlib.nullcontext() if self.reload_keeps_layout
               else compile_cache.uncached()):
             self._build()
@@ -740,6 +746,11 @@ class ServeEngine:
             active = (np.array([p is not None for p in self.slot_pages])
                       & (self.tables.lengths > 0))
         late = self._unread
+        walked = -(-(self.tables.lengths[active] + 1) // self.page)
+        self.decode_pages["steps"] += 1
+        self.decode_pages["walked"] += int(
+            np.minimum(walked, self.n_max_pages).sum())
+        self.decode_pages["table"] += self.slots * self.n_max_pages
         with trace.span(
                 "engine.decode.dispatch", cat=trace.CAT_SERVE,
                 attrs=({"active": int(active.sum())} if trace.enabled()
@@ -950,6 +961,7 @@ class ServeEngine:
             "decode": {**self.decode_counts,
                        "drained": dict(self.decode_counts["drained"])},
             "prefill_pages": dict(self.prefill_pages),
+            "decode_pages": dict(self.decode_pages),
             "draft": self.draft_spec,
             "spec_k": self.spec_k,
             "builds": self.builds,
